@@ -4,8 +4,8 @@ The reference ships *examples*, not a model zoo — users hand
 ``SparkModel`` an arbitrary compiled Keras model, and the example scripts
 (``[U] elephas examples/``: MNIST MLP, CIFAR-style convnets, IMDB LSTM)
 build those models inline. Here the same architectures are first-class
-builders so the benchmark suite (BASELINE.md configs 1–5) and the examples
-share one definition. All builders return *compiled* Keras-3 (jax backend)
+builders so the benchmark suite (the five configurations listed in
+BASELINE.json) and the examples share one definition. All builders return *compiled* Keras-3 (jax backend)
 models ready to wrap in ``SparkModel``.
 """
 

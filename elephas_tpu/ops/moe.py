@@ -24,8 +24,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from elephas_tpu.parallel.mesh import axis_size_compat
-
 
 def _topk_dispatch(x, gate_w, num_experts: int, capacity: int, k: int = 1):
     """Token → expert routing tensors (top-k, capacity-bounded).
@@ -127,7 +125,7 @@ def expert_parallel_ffn(
     ``return_aux`` also returns the load-balance loss (this shard's —
     ``pmean`` it across the axis if training on it).
     """
-    w = axis_size_compat(axis_name)
+    w = jax.lax.axis_size(axis_name)
     t_local, d = x.shape
     e_local = w1.shape[0]
     e_total = w * e_local

@@ -37,12 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from elephas_tpu.parallel.mesh import (
-    axis_size_compat,
-    host_read,
-    put_global,
-    shard_map_compat,
-)
+from elephas_tpu.parallel.mesh import host_read, put_global
 
 logger = logging.getLogger(__name__)
 
@@ -60,7 +55,7 @@ def gpipe(stage_fn, stage_params, x_microbatches, axis_name: str):
     ONLY (zeros elsewhere) — the caller slices the last stage's shard
     out instead of paying an all-reduce broadcast of whole activations.
     """
-    s = axis_size_compat(axis_name)
+    s = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)
     m = x_microbatches.shape[0]
     ticks = m + s - 1
@@ -112,12 +107,12 @@ def gpipe_sharded(
         out = gpipe(stage_fn, params, xm, axis_name)
         return out[None]  # leading per-stage axis
 
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P(axis_name), P()),
         out_specs=P(axis_name),
-        check=False,
+        check_vma=False,
     )
     out = sharded(stacked_params, xm)[s - 1]
     return out.reshape((b,) + out.shape[2:])
@@ -525,12 +520,12 @@ class GPipeTrainer:
         param_spec = (
             P(self.axis, self.model_axis) if self.mp > 1 else P(self.axis)
         )
-        return shard_map_compat(
+        return jax.shard_map(
             per_device,
             mesh=self.mesh,
             in_specs=(param_spec, P(self.axis), self._mb_spec, self._mb_spec),
             out_specs=(P(), out_mb_spec, P(self.axis)),
-            check=False,
+            check_vma=False,
         )
 
     def _build_train_step(self, metric_update=None, mvs_example=None):

@@ -36,7 +36,6 @@ import functools
 import jax
 
 from elephas_tpu.ops.flash_attention import flash_attention
-from elephas_tpu.parallel.mesh import axis_size_compat, shard_map_compat
 
 
 def ulysses_attention(
@@ -54,7 +53,7 @@ def ulysses_attention(
     sequence axis sharded over ``axis_name``; heads NOT sharded —
     ``H % axis_size == 0`` required). Returns ``[B, H, S_local, D]``.
     """
-    w = axis_size_compat(axis_name)
+    w = jax.lax.axis_size(axis_name)
     b, h, s_local, d = q.shape
     if h % w:
         raise ValueError(
@@ -104,8 +103,8 @@ def ulysses_attention_sharded(
         interpret=interpret,
     )
     spec = P(None, None, axis_name, None)
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check=False,
+        check_vma=False,
     )
     return sharded(q, k, v)

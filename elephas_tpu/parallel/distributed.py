@@ -64,16 +64,6 @@ def initialize(
     if coordinator_address is None and not on_tpu_pod:
         logger.info("no coordinator configured; staying single-host")
         return False
-    if not on_tpu_pod:
-        # CPU gangs (the test/sim topology): jaxlibs in the 0.4.3x line
-        # ship cross-process CPU collectives only behind the gloo
-        # implementation knob — without it every collective dies with
-        # "Multiprocess computations aren't implemented on the CPU
-        # backend". Newer jax defaults to gloo and drops the knob.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except AttributeError:
-            pass
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,

@@ -25,34 +25,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 logger = logging.getLogger(__name__)
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, check: bool = False):
-    """``shard_map`` across jax versions: newer jax exposes
-    ``jax.shard_map`` (replication checking spelled ``check_vma``),
-    older ones only ``jax.experimental.shard_map`` (spelled
-    ``check_rep``). Every shard_map in this codebase routes through
-    here so a jax upgrade/downgrade is a one-line event."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check,
-    )
-
-
-def axis_size_compat(axis_name: str) -> int:
-    """Mapped-axis size inside ``shard_map``, across jax versions:
-    newer jax spells it ``jax.lax.axis_size``; older ones resolve
-    ``psum(1, axis)`` to the same concrete int at trace time."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def put_global(arr, sharding: NamedSharding):
     """Host→device under an arbitrary sharding, multi-process safe.
 

@@ -36,7 +36,6 @@ import threading
 import jax
 import numpy as np
 
-from elephas_tpu.parallel.mesh import shard_map_compat
 from jax.sharding import Mesh, PartitionSpec as P
 
 from elephas_tpu.parallel.tensor import ShardedTrainer, TensorParallelRunner
@@ -182,17 +181,17 @@ def ring_mha(q, k, v, causal: bool = False, scale: float | None = None,
             ulysses_attention, axis_name=scope.seq_axis, causal=causal,
             scale=scale,
         )
-        return shard_map_compat(
+        return jax.shard_map(
             fn4, mesh=scope.mesh, in_specs=(spec4,) * 3, out_specs=spec4,
-            check=False,
+            check_vma=False,
         )(q, k, v)
     # batch shards over 'data' and heads over 'model' when they tile.
     # The q/k/v stay 4-D [B, H, S, D] through the shard_map boundary
     # and merge batch·heads LOCALLY inside: a global reshape merging a
     # data-sharded B with a model-sharded H produced an unsplittable
     # merged sharding whose backward cotangent hit XLA's "involuntary
-    # full rematerialization" path (spmd_partitioner.cc:652 in
-    # MULTICHIP_r04 — VERDICT r4 weak #1). When B alone does not tile
+    # full rematerialization" path (spmd_partitioner.cc:652, seen in
+    # an 8-device dry run of 2026-07). When B alone does not tile
     # over 'data' (1-row predicts, tiny introspection batches) the
     # head dim absorbs the data axis too — the old merged layout's
     # joint tiling, expressed per-axis; only when neither dim tiles do
@@ -219,9 +218,9 @@ def ring_mha(q, k, v, causal: bool = False, scale: float | None = None,
             ring_attention, axis_name=scope.seq_axis, causal=causal,
             scale=scale,
         )
-        sharded3 = shard_map_compat(
+        sharded3 = jax.shard_map(
             fn3, mesh=scope.mesh, in_specs=(spec,) * 3, out_specs=spec,
-            check=False,
+            check_vma=False,
         )
         out = sharded3(
             q.reshape(b * h, s, d), k.reshape(b * h, s, d),
@@ -259,9 +258,9 @@ def ring_mha(q, k, v, causal: bool = False, scale: float | None = None,
         )
         return out.reshape(bl, hl, sl, dl)
 
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         fn, mesh=scope.mesh, in_specs=(spec,) * 3, out_specs=spec,
-        check=False,
+        check_vma=False,
     )
     return sharded(q, k, v)
 

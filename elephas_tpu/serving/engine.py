@@ -3460,8 +3460,6 @@ AdmissionRejected` at submit), policy-derived preemption priority, and
                     if self.paged else None
                 ),
             }
-        from elephas_tpu.utils import backend_guard
-
         out = {
             "engine": self.telemetry_label,
             "steps": sched._steps,
@@ -3469,10 +3467,6 @@ AdmissionRejected` at submit), policy-derived preemption priority, and
             "attention": self.attention,
             "kv_dtype": self.kv_dtype,
             "weight_version": self.weight_version,
-            # the BENCH_r05 lesson at the serving surface: if backend
-            # discovery fell back to CPU, say so HERE, not only in
-            # bench JSON
-            "backend_fallback": backend_guard.last_fallback(),
             "slots": slots,
             "waiting": sched.queue_snapshot(),
             "queued_tokens": sched.queued_tokens,

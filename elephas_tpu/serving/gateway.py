@@ -931,8 +931,6 @@ class Gateway:
             "driver-dead" if not alive
             else "stalled" if stalled else "ok"
         )
-        from elephas_tpu.utils import backend_guard
-
         body = {
             "status": status,
             "steps": steps,
@@ -943,11 +941,6 @@ class Gateway:
             # from health probes alone (report-only, never flips the
             # verdict: an old generation is stale, not dead)
             "weight_version": self.engine.weight_version,
-            # ISSUE 19 satellite: if jax backend discovery fell back
-            # to CPU (the BENCH_r05 driver-box TPU init crash), every
-            # health probe says so — report-only, never flips the
-            # 200/503 verdict (a CPU engine is slow, not dead)
-            "backend_fallback": backend_guard.last_fallback(),
         }
         if self.watchdog is not None:
             # anomaly detail (ISSUE 13): evaluated HERE, at probe

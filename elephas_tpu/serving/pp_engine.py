@@ -487,16 +487,14 @@ class PPEngine:
         )
 
         def _init_pools():
-            from elephas_tpu.parallel.mesh import shard_map_compat
-
             def per_device():
                 z = jnp.zeros(local_shape, jnp.float32)
                 return z, jnp.zeros(local_shape, jnp.float32)
 
-            return shard_map_compat(
+            return jax.shard_map(
                 per_device, mesh=mesh, in_specs=(),
                 out_specs=(self._pool_spec, self._pool_spec),
-                check=False,
+                check_vma=False,
             )()
 
         self._pk, self._pv = jax.jit(_init_pools)()
@@ -883,7 +881,6 @@ class PPEngine:
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
-        from elephas_tpu.parallel.mesh import shard_map_compat
         from elephas_tpu.serving.engine import _sample_dynamic
 
         S, ws, k = self.num_stages, self.wave_slots, self.steps_per_wave
@@ -1115,14 +1112,14 @@ class PPEngine:
                 )
                 return pk[None], pv[None], outputs[None], key
 
-            return shard_map_compat(
+            return jax.shard_map(
                 per_device,
                 mesh=mesh,
                 in_specs=(param_spec, pool_spec, pool_spec,
                           P(), P(), P(), P(), P(), P(), P(), P(),
                           P(), P()),
                 out_specs=(pool_spec, pool_spec, P("stages"), P()),
-                check=False,
+                check_vma=False,
             )(wflat, pk, pv, tables, lengths0, last0, temps, active,
               fill_wave, fill_offs, fill_tokens, fill_plens, key)
 
@@ -1247,13 +1244,13 @@ class PPEngine:
                 )
                 return pk[None], pv[None], firsts[None], key
 
-            return shard_map_compat(
+            return jax.shard_map(
                 per_device,
                 mesh=mesh,
                 in_specs=(param_spec, pool_spec, pool_spec, P(), P(),
                           P(), P(), P(), P(), P(), P()),
                 out_specs=(pool_spec, pool_spec, P("stages"), P()),
-                check=False,
+                check_vma=False,
             )(wflat, pk, pv, tables, tokens, offs, clens, act,
               p_lens, temps, key)
 
@@ -1270,11 +1267,11 @@ class PPEngine:
                 gv = jnp.take(pv, ids, axis=1, mode="clip")
                 return gk[None], gv[None]
 
-            return shard_map_compat(
+            return jax.shard_map(
                 per_device, mesh=mesh,
                 in_specs=(pool_spec, pool_spec, P()),
                 out_specs=(pool_spec, pool_spec),
-                check=False,
+                check_vma=False,
             )(pk, pv, ids)
 
         def scatter_rows(pk, pv, ids, rk, rv):
@@ -1284,12 +1281,12 @@ class PPEngine:
                 pv = pv.at[:, ids].set(rv, mode="drop")
                 return pk[None], pv[None]
 
-            return shard_map_compat(
+            return jax.shard_map(
                 per_device, mesh=mesh,
                 in_specs=(pool_spec, pool_spec, P(), pool_spec,
                           pool_spec),
                 out_specs=(pool_spec, pool_spec),
-                check=False,
+                check_vma=False,
             )(pk, pv, ids, rk, rv)
 
         self._gather_jit = jax.jit(gather_rows)

@@ -50,14 +50,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from elephas_tpu import telemetry
-from elephas_tpu.parallel.mesh import shard_map_compat
 from elephas_tpu.utils import sockets
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-    return shard_map_compat(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check=check_rep
-    )
 
 logger = logging.getLogger(__name__)
 
@@ -508,13 +501,13 @@ class MeshRunner(KerasIntrospection):
                 loss,
             )
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             per_worker,
             mesh=self.mesh,
             in_specs=(P("workers"), P("workers"), P("workers"), P(),
                       P("workers"), P("workers")),
             out_specs=(P("workers"), P("workers"), P("workers"), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(sharded, donate_argnums=(0, 1, 2))
 
@@ -730,13 +723,13 @@ class MeshRunner(KerasIntrospection):
             mvs = jax.tree.map(lambda a: jax.lax.psum(a, "workers"), mvs)
             return loss_sums, weight_sum, mvs
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             per_worker,
             mesh=self.mesh,
             in_specs=(P("workers"), P("workers"), P(), P("workers"),
                       P("workers"), P("workers")),
             out_specs=(P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(sharded)
 
@@ -846,12 +839,12 @@ class MeshRunner(KerasIntrospection):
             _, preds = jax.lax.scan(step, None, xb)
             return preds[None]
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             per_worker,
             mesh=self.mesh,
             in_specs=(P("workers"), P("workers"), P("workers")),
             out_specs=P("workers"),
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(sharded)
 

@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from elephas_tpu.parallel.mesh import shard_map_compat
 from jax.sharding import Mesh, PartitionSpec as P
 
 from elephas_tpu.ops.moe import (
@@ -36,12 +35,12 @@ def _run_ep(x, params, mesh, e_local, capacity_factor=1.25):
             capacity_factor=capacity_factor,
         )
 
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P("ep"), P(), P("ep"), P("ep"), P("ep"), P("ep")),
         out_specs=P("ep"),
-        check=False,
+        check_vma=False,
     )
     return sharded(x, gate_w, w1, b1, w2, b2)
 
@@ -131,12 +130,12 @@ def _run_ep_topk(x, params, mesh, e_local, k, capacity_factor=1.5):
         )
         return out, jax.lax.pmean(aux, "ep")
 
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P("ep"), P(), P("ep"), P("ep"), P("ep"), P("ep")),
         out_specs=(P("ep"), P()),
-        check=False,
+        check_vma=False,
     )
     return sharded(x, gate_w, w1, b1, w2, b2)
 

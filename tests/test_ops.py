@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from elephas_tpu.parallel.mesh import shard_map_compat
 
 from elephas_tpu.ops import flash_attention, ring_attention
 from elephas_tpu.ops.flash_attention import attention_reference
@@ -89,8 +88,8 @@ def test_ring_attention_inside_user_shard_map():
         return ring_attention(q, k, v, axis_name="workers", causal=True)
 
     out = jax.jit(
-        shard_map_compat(
-            fn, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check=False
+        jax.shard_map(
+            fn, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check_vma=False
         )
     )(q, k, v)
     ref = attention_reference(q, k, v, causal=True)
@@ -110,8 +109,8 @@ def test_ring_attention_gradients_match(causal):
         fn = lambda q, k, v: ring_attention(  # noqa: E731
             q, k, v, axis_name="workers", causal=causal
         )
-        out = shard_map_compat(
-            fn, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check=False
+        out = jax.shard_map(
+            fn, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check_vma=False
         )(q, k, v)
         return jnp.sum(out**2)
 
@@ -238,9 +237,9 @@ def test_ulysses_gradients_match():
         fn = lambda q, k, v: ulysses_attention(  # noqa: E731
             q, k, v, axis_name="seq", causal=True
         )
-        out = shard_map_compat(
+        out = jax.shard_map(
             fn, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
-            check=False,
+            check_vma=False,
         )(q, k, v)
         return jnp.sum(out**2)
 

@@ -133,7 +133,6 @@ def test_noncausal_ring_jit_lowering_pinned():
     import jax.numpy as jnp
 
     from elephas_tpu.ops.flash_attention import attention_reference
-    from elephas_tpu.parallel.mesh import shard_map_compat
     from jax.sharding import PartitionSpec as P
 
     mesh = dp_sp_mesh(sequence_parallel=4)
@@ -148,9 +147,9 @@ def test_noncausal_ring_jit_lowering_pinned():
         fn = lambda a, b, c: ring_attention(  # noqa: E731
             a, b, c, axis_name="seq", causal=causal
         )
-        sharded = shard_map_compat(
+        sharded = jax.shard_map(
             fn, mesh=mesh, in_specs=(P(None, "seq", None),) * 3,
-            out_specs=P(None, "seq", None), check=False,
+            out_specs=P(None, "seq", None), check_vma=False,
         )
         out = jax.jit(lambda a, b, c: sharded(a, b, c))(q, k, v)
         ref = attention_reference(q, k, v, causal=causal)

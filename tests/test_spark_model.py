@@ -56,7 +56,7 @@ def test_predict_accepts_rdd(spark_context, blobs):
 
 def test_evaluate_matches_keras(spark_context, blobs):
     """Distributed evaluate must agree with single-process keras evaluate
-    (padding masked exactly) — the parity gate from BASELINE.md."""
+    (padding masked exactly) — the parity gate."""
     x, y, d, k = blobs
     model = make_mlp(d, k)
     spark_model = SparkModel(model, num_workers=8)

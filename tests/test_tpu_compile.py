@@ -1,0 +1,214 @@
+"""The kernels of the main path through the chip's compiler, without the
+chip: each is lowered at a real width for a described ``v5e:2x2`` and
+must come out as a Mosaic kernel (``tpu_custom_call``), not interpreted
+and not refused. Interpret-mode tests cannot see what this sees — a
+slice off the tiling, too much fast memory, a kernel that cannot be
+partitioned. A compile that passes here is not a chip run.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from elephas_tpu.ops.flash_attention import (
+    flash_attention,
+    flash_attention_qkv,
+    packed_layout_supported,
+)
+from elephas_tpu.ops.layer_norm import layer_norm
+from elephas_tpu.ops.ring_attention import ring_attention_sharded
+
+try:
+    from jax.experimental import topologies
+
+    TOPO = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2"
+    )
+except Exception as e:  # noqa: BLE001 — no TPU compiler on this machine
+    TOPO, WHY = None, f"cannot describe a v5e:2x2 here: {e}"
+else:
+    WHY = ""
+
+pytestmark = pytest.mark.skipif(TOPO is None, reason=WHY)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to JAX's persistent
+    cache but cannot be read back without the chip (the next run warns
+    and compiles again), so the cache stays off around these."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def on_chip(shape, dtype):
+    return jax.ShapeDtypeStruct(
+        shape, dtype, sharding=SingleDeviceSharding(TOPO.devices[0])
+    )
+
+
+def kernels_in(fn, *args) -> int:
+    """Compile ``fn`` for the described chip; count its Mosaic calls."""
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
+    return compiled.as_text().count("tpu_custom_call")
+
+
+DTYPES = [jnp.bfloat16, jnp.float32]
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("head_dim", [128, 64])
+def test_flash_forward_transposed_layout(head_dim, dtype):
+    q = on_chip((4, 8, 1024, head_dim), dtype)
+    assert kernels_in(
+        lambda q, k, v: flash_attention(
+            q, k, v, causal=True, interpret=False
+        ),
+        q, q, q,
+    ) == 1
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_flash_forward_packed_layout(dtype):
+    assert packed_layout_supported(128, 8)
+    qkv = on_chip((4, 256, 3, 8, 128), dtype)
+    assert kernels_in(
+        lambda t: flash_attention_qkv(t, causal=True, interpret=False),
+        qkv,
+    ) == 1
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_flash_forward_lane_grouped_layout_gpt2_small(dtype):
+    """H=12 D=64 S=1024: two heads share a 128-lane block."""
+    assert packed_layout_supported(64, 12)
+    qkv = on_chip((2, 1024, 3, 12, 64), dtype)
+    assert kernels_in(
+        lambda t: flash_attention_qkv(t, causal=True, interpret=False),
+        qkv,
+    ) == 1
+
+
+def test_grad_through_the_packed_op():
+    """What the LM's fit epoch differentiates: Pallas forward, scanned
+    XLA backward."""
+    qkv = on_chip((2, 1024, 3, 12, 64), jnp.bfloat16)
+
+    def loss(t):
+        out = flash_attention_qkv(t, causal=True, interpret=False)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    assert kernels_in(jax.grad(loss), qkv) >= 1
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_layer_norm_forward(dtype):
+    x = on_chip((32768, 1024), dtype)
+    g = on_chip((1024,), jnp.float32)
+    assert kernels_in(
+        lambda x, g, b: layer_norm(x, g, b, interpret=False), x, g, g
+    ) == 1
+
+
+def test_layer_norm_backward():
+    x = on_chip((32768, 1024), jnp.bfloat16)
+    g = on_chip((1024,), jnp.float32)
+
+    def loss(x, g, b):
+        y = layer_norm(x, g, b, interpret=False)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    # forward (for the saved statistics) and the one-pass backward
+    assert kernels_in(jax.grad(loss, argnums=(0, 1, 2)), x, g, g) == 2
+
+
+def test_ring_attention_over_the_four_described_chips():
+    """The sequence-parallel kernel inside ``shard_map`` on a mesh
+    built from the topology's devices: Mosaic calls around the ring's
+    collective-permutes."""
+    mesh = Mesh(np.array(TOPO.devices), ("workers",))
+    q = jax.ShapeDtypeStruct(
+        (16, 4096, 128), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P(None, "workers", None)),
+    )
+    compiled = jax.jit(
+        lambda q, k, v: ring_attention_sharded(
+            q, k, v, mesh, causal=True, interpret=False
+        )
+    ).lower(q, q, q).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "collective-permute" in text
+
+
+@pytest.mark.parametrize("meshed", [False, True])
+def test_serving_decode_step_at_gpt2_small_width(meshed):
+    """The engine's own paged decode program (width of GPT-2-small,
+    depth cut to two layers, a full 64-block table) for the described
+    chip. Unmeshed it takes native gather/scatter; on the one-device
+    mesh ``SparkModel.serve`` builds, the one-hot contractions. The
+    engine places arrays as it is built, which a described device
+    cannot hold, so the meshed case stops construction at the first
+    staging call: by then every program exists."""
+    from elephas_tpu.models import transformer_lm
+    from elephas_tpu.serving import InferenceEngine
+
+    model = transformer_lm(
+        vocab_size=50257, maxlen=1024, d_model=768, num_heads=12,
+        num_layers=2, dropout=0.0, seed=0,
+    )
+    slots, blocks, table = 8, 512, 64
+    kw = dict(num_slots=slots, paged=True, block_size=16,
+              num_blocks=blocks, prefix_cache=True)
+    if meshed:
+        class Built(Exception):
+            pass
+
+        class ProgramsOnly(InferenceEngine):
+            def refresh_weights(self, version=None):
+                raise Built
+
+        mesh = Mesh(np.array(TOPO.devices[:1]), ("workers",))
+        engine = ProgramsOnly.__new__(ProgramsOnly)
+        with pytest.raises(Built):
+            engine.__init__(model, mesh=mesh, batch_axes=("workers",), **kw)
+        whole = NamedSharding(mesh, P())
+        by_slot = NamedSharding(mesh, P("workers"))
+    else:
+        engine = InferenceEngine(model, **kw)
+        whole = by_slot = SingleDeviceSharding(TOPO.devices[0])
+
+    def shape(dims, dtype, sharding=whole):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    weights = {
+        v.path: shape(tuple(v.shape), jnp.float32) for v in model.variables
+    }
+    pool = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype), jax.eval_shape(engine.arena.init)
+    )
+    compiled = engine._paged_decode_jit.lower(
+        weights, pool, shape((slots, table), jnp.int32, by_slot),
+        shape((slots,), jnp.int32, by_slot),
+        shape((slots,), jnp.int32, by_slot),
+        shape((slots,), jnp.float32, by_slot),
+        shape((slots,), jnp.bool_, by_slot),
+        shape((2,), jnp.uint32),
+    ).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes + memory.argument_size_in_bytes < 2**34
+    engine.release_telemetry()
