@@ -24,6 +24,7 @@ here; ``ElephasEstimator``/``ElephasTransformer`` in
 ``elephas_tpu.hyperparam``.
 """
 
+import importlib
 import os
 import sys
 
@@ -49,48 +50,34 @@ if _backend != "jax":
 
 __version__ = "0.6.0"
 
-from elephas_tpu.spark_model import (  # noqa: E402,F401
-    SparkModel,
-    SparkMLlibModel,
-    load_spark_model,
-)
-from elephas_tpu.ml_model import (  # noqa: E402,F401
-    ElephasEstimator,
-    ElephasTransformer,
-    load_ml_estimator,
-    load_ml_transformer,
-)
-from elephas_tpu.hyperparam import HyperParamModel  # noqa: E402,F401
+# The public names resolve on first use (PEP 562), so that importing a
+# light subpackage (``elephas_tpu.telemetry``: a load generator, the
+# trace-merge CLI) imports neither JAX nor Keras.
+_LAZY = {
+    "SparkModel": "elephas_tpu.spark_model",
+    "SparkMLlibModel": "elephas_tpu.spark_model",
+    "load_spark_model": "elephas_tpu.spark_model",
+    "ElephasEstimator": "elephas_tpu.ml_model",
+    "ElephasTransformer": "elephas_tpu.ml_model",
+    "load_ml_estimator": "elephas_tpu.ml_model",
+    "load_ml_transformer": "elephas_tpu.ml_model",
+    "HyperParamModel": "elephas_tpu.hyperparam",
+    "ShardedTrainer": "elephas_tpu.parallel.tensor",
+    "GPipeTrainer": "elephas_tpu.ops.pipeline",
+    "SequenceShardedTrainer": "elephas_tpu.parallel.sequence",
+}
 
-__all__ = [
-    "SparkModel",
-    "SparkMLlibModel",
-    "load_spark_model",
-    "ElephasEstimator",
-    "ElephasTransformer",
-    "load_ml_estimator",
-    "load_ml_transformer",
-    "HyperParamModel",
-    "ShardedTrainer",
-    "GPipeTrainer",
-    "SequenceShardedTrainer",
-    "__version__",
-]
+__all__ = [*_LAZY, "__version__"]
 
 
 def __getattr__(name):
-    # heavier TPU-native extensions resolve lazily so the parity surface
-    # stays import-light
-    if name == "ShardedTrainer":
-        from elephas_tpu.parallel.tensor import ShardedTrainer
+    module = _LAZY.get(name)
+    if module is None:
+        raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+    value = getattr(importlib.import_module(module), name)
+    globals()[name] = value
+    return value
 
-        return ShardedTrainer
-    if name == "GPipeTrainer":
-        from elephas_tpu.ops.pipeline import GPipeTrainer
 
-        return GPipeTrainer
-    if name == "SequenceShardedTrainer":
-        from elephas_tpu.parallel.sequence import SequenceShardedTrainer
-
-        return SequenceShardedTrainer
-    raise AttributeError(name)
+def __dir__():
+    return sorted({*globals(), *__all__})

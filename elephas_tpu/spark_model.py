@@ -513,6 +513,45 @@ class SparkModel:
           :mod:`elephas_tpu.data.streaming`).
         """
         batch_size = batch_size or self.batch_size
+        with telemetry.trace_span(
+            "fit.call", epochs=int(epochs), batch_size=int(batch_size),
+            workers=self.num_workers,
+        ) as call:
+            start_epoch = self._resume(checkpoint_dir, resume)
+            # cross-process trace context minted at the training edge
+            # (ISSUE 13): every event this fit records — the fit.* spans
+            # of stage-in and of each epoch, fit.epoch boundaries, weight
+            # publications, and any PS round-trips on this thread —
+            # carries one deterministic run id, and the PS clients
+            # forward it over the wire so server-side applies/journal
+            # writes join the same trace. The id is a process-monotonic
+            # run count + start epoch: no pids, no wall time (gang
+            # processes mint identical ids).
+            trace_id = f"fit-r{next(_fit_trace_ids)}e{start_epoch}"
+            call.set(trace=trace_id)  # the root closes after the scope
+            with telemetry.trace_scope(trace_id):
+                return self._fit_scoped(
+                    rdd,
+                    epochs,
+                    batch_size,
+                    verbose,
+                    validation_split,
+                    profile_dir=profile_dir,
+                    checkpoint_dir=checkpoint_dir,
+                    checkpoint_every=checkpoint_every,
+                    resume=resume,
+                    start_epoch=start_epoch,
+                    steps_per_epoch=steps_per_epoch,
+                    stream_block_steps=stream_block_steps,
+                    history_log=history_log,
+                )
+
+    def _fit_scoped(
+        self, rdd, epochs, batch_size, verbose, validation_split,
+        steps_per_epoch=None, stream_block_steps=None, **fit_kwargs,
+    ) -> dict:
+        """``fit`` inside its ``fit.call`` span and trace scope: route
+        the input to the staged or the streamed path."""
         if not isinstance(rdd, Rdd):
             x, y = rdd
             return self._fit_arrays(
@@ -522,13 +561,9 @@ class SparkModel:
                 batch_size,
                 verbose,
                 validation_split,
-                profile_dir=profile_dir,
-                checkpoint_dir=checkpoint_dir,
-                checkpoint_every=checkpoint_every,
-                resume=resume,
                 steps_per_epoch=steps_per_epoch,
                 stream_block_steps=stream_block_steps,
-                history_log=history_log,
+                **fit_kwargs,
             )
         if rdd.is_lazy() and self.frequency != "fit":
             # partitions are row-range views of backing stores — stream
@@ -548,38 +583,65 @@ class SparkModel:
                 batch_size,
                 verbose,
                 validation_split,
-                profile_dir=profile_dir,
-                checkpoint_dir=checkpoint_dir,
-                checkpoint_every=checkpoint_every,
-                resume=resume,
                 steps_per_epoch=steps_per_epoch,
                 stream_block_steps=stream_block_steps,
-                history_log=history_log,
+                **fit_kwargs,
             )
-        if (
-            not rdd.is_lazy()
-            and self.pipeline_parallel <= 1
-            and rdd.getNumPartitions() != self.num_workers
-        ):
-            # lazy RDDs skip the element-wise repartition (it would
-            # materialize row-by-row); the runner's partition shaping
-            # re-splits the ranged reads to the mesh instead. Pipeline
-            # stages are depth shards, not data shards — repartitioning
-            # for them would just shuffle rows to re-concatenate.
-            rdd = rdd.repartition(self.num_workers)
-        partitions = rdd_utils.partition_arrays(rdd)
+        with telemetry.trace_span("fit.partition_arrays") as sp:
+            if (
+                not rdd.is_lazy()
+                and self.pipeline_parallel <= 1
+                and rdd.getNumPartitions() != self.num_workers
+            ):
+                # lazy RDDs skip the element-wise repartition (it would
+                # materialize row-by-row); the runner's partition shaping
+                # re-splits the ranged reads to the mesh instead. Pipeline
+                # stages are depth shards, not data shards — repartitioning
+                # for them would just shuffle rows to re-concatenate.
+                rdd = rdd.repartition(self.num_workers)
+            partitions = rdd_utils.partition_arrays(rdd)
+            sp.set(
+                rows=sum(len(x) for x, _ in partitions),
+                bytes=sum(x.nbytes + y.nbytes for x, y in partitions),
+            )
         return self._fit_partitions(
             partitions,
             epochs,
             batch_size,
             verbose,
             validation_split,
-            profile_dir=profile_dir,
-            checkpoint_dir=checkpoint_dir,
-            checkpoint_every=checkpoint_every,
-            resume=resume,
-            history_log=history_log,
+            **fit_kwargs,
         )
+
+    def _resume(self, checkpoint_dir, resume) -> int:
+        """Restore what ``fit(resume=True)`` resumes from, before any
+        data is staged; returns the epoch to start at (0 for a fresh
+        fit)."""
+        start_epoch = 0
+        if checkpoint_dir and resume:
+            meta = self._get_runner().restore_checkpoint(
+                checkpoint_dir, self.custom_objects
+            )
+            if meta is not None:
+                start_epoch = int(meta["epoch"])
+                logger.info(
+                    "resuming from %s at epoch %d", checkpoint_dir, start_epoch
+                )
+        if resume and self.ps_journal_dir:
+            # fit(resume=True) end-to-end (ISSUE 3): the PS journal may
+            # carry sub-epoch updates newer than the epoch-granular
+            # checkpoint restored above — adopt the journaled weights as
+            # the master state, and start_server in _fit_partitions
+            # re-seeds the PS from the same journal, so neither the
+            # workers nor external pollers regress past the last snapshot
+            journaled = self._load_ps_journal_weights()
+            if journaled is not None:
+                self._master_network.set_weights(journaled)
+                logger.info(
+                    "resume: adopted journaled parameter-server state "
+                    "from %s", self.ps_journal_dir,
+                )
+        return start_epoch
 
     def _fit_arrays(
         self,
@@ -685,6 +747,7 @@ class SparkModel:
         checkpoint_dir=None,
         checkpoint_every=1,
         resume=False,
+        start_epoch=0,
         stream=None,
         val_partitions=None,
         val_spec=None,
@@ -692,29 +755,6 @@ class SparkModel:
         history_log=None,
     ) -> dict:
         runner = self._get_runner()
-
-        start_epoch = 0
-        if checkpoint_dir and resume:
-            meta = runner.restore_checkpoint(checkpoint_dir, self.custom_objects)
-            if meta is not None:
-                start_epoch = int(meta["epoch"])
-                logger.info(
-                    "resuming from %s at epoch %d", checkpoint_dir, start_epoch
-                )
-        if resume and self.ps_journal_dir:
-            # fit(resume=True) end-to-end (ISSUE 3): the PS journal may
-            # carry sub-epoch updates newer than the epoch-granular
-            # checkpoint restored above — adopt the journaled weights as
-            # the master state, and start_server below re-seeds the PS
-            # from the same journal, so neither the workers nor external
-            # pollers regress past the last snapshot
-            journaled = self._load_ps_journal_weights()
-            if journaled is not None:
-                self._master_network.set_weights(journaled)
-                logger.info(
-                    "resume: adopted journaled parameter-server state "
-                    "from %s", self.ps_journal_dir,
-                )
         if start_epoch >= epochs:
             history = {"loss": []}
             self.training_histories.append(history)
@@ -751,7 +791,8 @@ class SparkModel:
             partitions = train_parts
             val_partitions = val_parts
         if partitions is not None:
-            partitions = runner._fit_partitions_to_mesh(partitions)
+            with telemetry.trace_span("fit.to_mesh"):
+                partitions = runner._fit_partitions_to_mesh(partitions)
 
         self.start_server(restore_journal=bool(resume))
         try:
@@ -822,17 +863,7 @@ class SparkModel:
                 import contextlib
 
                 trace_ctx = contextlib.nullcontext()
-            # cross-process trace context minted at the training edge
-            # (ISSUE 13): every event this fit records — fit.epoch
-            # boundaries, weight publications, and any PS round-trips
-            # on this thread — carries one deterministic run id, and
-            # the PS clients forward it over the wire so server-side
-            # applies/journal writes join the same trace. The id is a
-            # process-monotonic run count + start epoch: no pids, no
-            # wall time (gang processes mint identical ids).
-            with trace_ctx, telemetry.trace_scope(
-                f"fit-r{next(_fit_trace_ids)}e{start_epoch}"
-            ):
+            with trace_ctx:
                 if stream is not None:
                     history = runner.run_epochs_stream(
                         stream, epochs, verbose, callbacks=callbacks

@@ -1,10 +1,13 @@
 """Logical-clock event tracing (ISSUE 5 tentpole, part 2).
 
 A bounded ring buffer of events, each stamped with a monotonic
-**logical sequence number** (the ordering authority) plus a wall-clock
-capture that exists ONLY for export — Chrome-trace timelines and
-recovery-window measurement. Nothing in the runtime reads an event's
-wall time to make a decision; this preserves the gang/SPMD determinism
+**logical sequence number** (the ordering authority) plus two clock
+captures that exist ONLY for export: ``ts``, a ``time.time()`` wall
+reading (Chrome-trace timelines, merging across processes), and
+``mono_ns``, a ``time.monotonic_ns()`` reading of the same instant (the
+event's start), which no clock step can move and from which a span
+takes its ``dur``. Nothing in the runtime reads either to make a
+decision or to order events; this preserves the gang/SPMD determinism
 contract the serving scheduler and prefix cache already carry (their
 logical clocks stay the only clocks on control paths).
 
@@ -28,6 +31,19 @@ timeline.
 Null mode (:func:`~elephas_tpu.telemetry.registry.set_null`) swaps
 :func:`tracer` for a no-op tracer, same as the metrics registry.
 
+**Mirror into the device trace.** While a ``jax.profiler`` session
+records, every span also opens a ``jax.profiler.TraceAnnotation`` of
+its own name carrying ``seq=<begin sequence number>``, and every
+instant a zero-length one carrying its ``seq``: they land on the host
+plane of the same ``.xplane.pb`` as the device's operations, so an idle
+gap of the device can be put down to the span the host was in, and the
+ring's record and the trace's record of one span join on
+``(name, seq)``. This module never imports JAX: the mirror exists only
+once something else has (``"jax.profiler" in sys.modules``), with no
+session it costs one flag check, and under null mode nothing is
+opened. :meth:`EventTracer.complete` is not mirrored (its start lies in
+the past, which an annotation cannot express).
+
 **Cross-process trace context (ISSUE 13).** A *trace id* is a plain
 string minted once at the edge of a causal story — the gateway derives
 one from the request id, ``SparkModel.fit`` mints one per run, the
@@ -50,6 +66,7 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -98,11 +115,24 @@ def trace_scope(trace_id: str | None):
         set_trace(previous)
 
 
-class _Span:
-    """Reusable span context manager: captures begin seq/wall on enter,
-    appends one complete event on exit."""
+def _recording_annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler session
+    records, else None. JAX is never imported from here: a process
+    that has not imported it (the gateway's load generator) pays one
+    dict lookup; one that has, a flag check."""
+    profiler = sys.modules.get("jax.profiler")
+    annotation = getattr(profiler, "TraceAnnotation", None)
+    if annotation is not None and annotation.is_enabled():
+        return annotation
+    return None
 
-    __slots__ = ("_tracer", "_name", "_args", "_seq0", "_t0")
+
+class _Span:
+    """Reusable span context manager: captures begin seq and clocks on
+    enter, appends one complete event on exit."""
+
+    __slots__ = ("_tracer", "_name", "_args", "_seq0", "_t0", "_m0",
+                 "_mirror")
 
     def __init__(self, tracer, name, args):
         self._tracer = tracer
@@ -110,11 +140,18 @@ class _Span:
         self._args = args
         self._seq0 = 0
         self._t0 = 0.0
+        self._m0 = 0
+        self._mirror = None
 
     def __enter__(self):
         self._seq0 = self._tracer._next_seq()
-        # wall time: EXPORT-ONLY (never control flow) — see module doc
+        # clock readings: EXPORT-ONLY (never control flow) — see module doc
         self._t0 = time.time()
+        self._m0 = time.monotonic_ns()
+        annotation = _recording_annotation()
+        if annotation is not None:
+            self._mirror = annotation(self._name, seq=self._seq0)
+            self._mirror.__enter__()
         return self
 
     @property
@@ -129,13 +166,17 @@ class _Span:
         self._args.update(kw)
 
     def __exit__(self, *exc):
+        if self._mirror is not None:
+            self._mirror.__exit__(*exc)
+            self._mirror = None
         self._tracer._append(
             name=self._name,
             ph="X",
             seq=self._tracer._next_seq(),
             seq_begin=self._seq0,
             ts=self._t0,
-            dur=time.time() - self._t0,
+            mono_ns=self._m0,
+            dur=(time.monotonic_ns() - self._m0) / 1e9,
             args=dict(self._args),
         )
         return False
@@ -166,7 +207,7 @@ class EventTracer:
         with self._seq_lock:
             return self._seq_next
 
-    def _append(self, *, name, ph, seq, ts, args, dur=None,
+    def _append(self, *, name, ph, seq, ts, mono_ns, args, dur=None,
                 seq_begin=None):
         # cross-process trace context (ISSUE 13): an active scope
         # stamps every event appended by this thread — call sites that
@@ -180,6 +221,7 @@ class EventTracer:
             "ph": ph,
             "seq": seq,
             "ts": ts,
+            "mono_ns": mono_ns,
             "tid": threading.get_ident(),
             "args": args,
         }
@@ -193,27 +235,34 @@ class EventTracer:
         number (callers may correlate on it — it is the only ordering
         a consumer should trust)."""
         seq = self._next_seq()
-        self._append(name=name, ph="i", seq=seq, ts=time.time(), args=args)
+        self._append(name=name, ph="i", seq=seq, ts=time.time(),
+                     mono_ns=time.monotonic_ns(), args=args)
+        annotation = _recording_annotation()
+        if annotation is not None:
+            with annotation(name, seq=seq):
+                pass
         return seq
 
     def span(self, name: str, **args) -> _Span:
         """``with tracer.span("prefill", req=rid): ...`` — records one
-        complete event at exit with begin/end sequence numbers and the
-        wall duration."""
+        complete event at exit with begin/end sequence numbers, both
+        clock readings of its start and its monotonic duration."""
         return _Span(self, name, args)
 
     def complete(self, name: str, dur: float, **args) -> int:
         """Record one already-measured span: the caller timed the work
         and only afterwards learned it deserved an event — the shape of
         a jit dispatch that turned out to compile (ISSUE 12). Appends a
-        single ``ph="X"`` event whose wall start is reconstructed as
-        now − ``dur`` (export-only, like all wall time here); returns
-        its end sequence number."""
+        single ``ph="X"`` event whose start is reconstructed on both
+        clocks as now − ``dur`` (export-only, like every clock reading
+        here); returns its end sequence number."""
         seq0 = self._next_seq()
         seq = self._next_seq()
         self._append(
             name=name, ph="X", seq=seq, seq_begin=seq0,
-            ts=time.time() - dur, dur=float(dur), args=args,
+            ts=time.time() - dur,
+            mono_ns=time.monotonic_ns() - int(dur * 1e9),
+            dur=float(dur), args=args,
         )
         return seq
 

@@ -38,6 +38,7 @@ Mode semantics (see SURVEY.md §2a):
 from __future__ import annotations
 
 import collections
+import itertools
 import logging
 import queue
 import threading
@@ -402,7 +403,10 @@ class MeshRunner(KerasIntrospection):
 
     def _shard_data(self, arr: np.ndarray):
         """Worker-shard a GLOBAL ``[W, ...]`` host array (multi-host:
-        slice out this process's workers first)."""
+        slice out this process's workers first). This dispatches the
+        ``device_put`` and does not wait for the copy: the
+        ``fit.shard_data`` span around it times the dispatch alone, and
+        the copy's tail lands in whatever first waits for the device."""
         if jax.process_count() > 1:
             arr = arr[np.asarray(self._local_worker_indices())]
         return self._shard_local_data(arr)
@@ -437,6 +441,40 @@ class MeshRunner(KerasIntrospection):
         if ov is not None:
             for var, leaf in zip(self.model.optimizer.variables, ov):
                 var.assign(self._worker_slice(leaf))
+
+    def _traced_device_state(self):
+        """:meth:`_device_state` under its ``fit.device_state`` span.
+        Returns the state and its size (``variables``, and one
+        replica's ``bytes``, from shapes alone): the args that carry
+        the per-variable detail, which must never become ring events."""
+        with telemetry.trace_span("fit.device_state") as sp:
+            state = self._device_state()
+            leaves = [leaf for part in state for leaf in part]
+            size = {
+                "variables": len(leaves),
+                "bytes": sum(l.nbytes for l in leaves) // self.num_workers,
+            }
+            sp.set(**size)
+        return state, size
+
+    def _traced_write_back(self, state, size, epoch, final=False):
+        with telemetry.trace_span(
+            "fit.write_back", epoch=epoch, final=final, **size
+        ):
+            self._write_back(*state)
+
+    def _end_epoch(self, epoch, epoch_loss, state, size, callbacks):
+        """The tail that the staged and the streamed epoch loops share:
+        sync the master model, so callbacks (e.g. parameter-server
+        publication) observe live weights, then invoke them."""
+        if not callbacks:
+            return
+        self._traced_write_back(state, size, epoch)
+        with telemetry.trace_span(
+            "fit.callbacks", epoch=epoch, count=len(callbacks)
+        ):
+            for cb in callbacks:
+                cb(epoch, epoch_loss)
 
     # -- loss helpers --------------------------------------------------
 
@@ -532,10 +570,14 @@ class MeshRunner(KerasIntrospection):
             raise ValueError(
                 f"got {len(partitions)} partitions for {self.num_workers} workers"
             )
-        xs, ys, counts, nb = stack_worker_batches(partitions, batch_size)
-        xb = self._shard_data(xs)
-        yb = self._shard_data(ys)
-        tv, ntv, ov = self._device_state()
+        span = telemetry.trace_span
+        with span("fit.stack_batches") as sp:
+            xs, ys, counts, nb = stack_worker_batches(partitions, batch_size)
+            sp.set(bytes=xs.nbytes + ys.nbytes)
+        with span("fit.shard_data", bytes=xs.nbytes + ys.nbytes):
+            xb = self._shard_data(xs)
+            yb = self._shard_data(ys)
+        (tv, ntv, ov), size = self._traced_device_state()
         metric_objects = self._unwrapped_metrics(partitions[0][0], partitions[0][1])
         if self._epoch_fn is None:
             self._epoch_fn = self._build_epoch_fn(metric_objects)
@@ -543,18 +585,15 @@ class MeshRunner(KerasIntrospection):
         history: dict[str, list[float]] = {"loss": []}
         for epoch in range(epochs):
             mvs = self._zero_metric_state(metric_objects)
-            tv, ntv, ov, mvs, loss = self._epoch_fn(tv, ntv, ov, mvs, xb, yb)
-            epoch_loss = float(np.asarray(loss))  # replicated: direct read
+            with span("fit.epoch_dispatch", epoch=epoch):
+                tv, ntv, ov, mvs, loss = self._epoch_fn(tv, ntv, ov, mvs, xb, yb)
+            with span("fit.loss_wait", epoch=epoch):
+                epoch_loss = float(np.asarray(loss))  # replicated: direct read
             history["loss"].append(epoch_loss)
             self._history_from_metrics(history, metric_objects, mvs)
             if verbose:
                 logger.info("epoch %d/%d - loss: %.4f", epoch + 1, epochs, epoch_loss)
-            if callbacks:
-                # sync master model before invoking, so callbacks (e.g.
-                # parameter-server publication) observe live weights
-                self._write_back(tv, ntv, ov)
-                for cb in callbacks:
-                    cb(epoch, epoch_loss)
+            self._end_epoch(epoch, epoch_loss, (tv, ntv, ov), size, callbacks)
 
         # 'fit' frequency (reference-parity synchronous): average once at end.
         if self.frequency == "fit":
@@ -572,7 +611,7 @@ class MeshRunner(KerasIntrospection):
                 else self._gather(l)
                 for l in ntv
             ]
-        self._write_back(tv, ntv, ov)
+        self._traced_write_back((tv, ntv, ov), size, epochs - 1, final=True)
         return history
 
     def run_epochs_stream(
@@ -604,7 +643,8 @@ class MeshRunner(KerasIntrospection):
         )
         if self._epoch_fn is None:
             self._epoch_fn = self._build_epoch_fn(metric_objects)
-        tv, ntv, ov = self._device_state()
+        span = telemetry.trace_span
+        (tv, ntv, ov), size = self._traced_device_state()
 
         # multi-host: gather only this process's workers' rows from the
         # backing store (VERDICT r2 weak #3 — full-block gathers multiply
@@ -620,14 +660,21 @@ class MeshRunner(KerasIntrospection):
             losses: list[tuple] = []
             # background reader keeps blocks ahead of the device (gathers
             # overlap compute beyond async-dispatch depth)
-            for xs, ys, steps in prefetch_blocks(
-                stream.blocks(worker_indices=local_idx)
-            ):
+            blocks = prefetch_blocks(stream.blocks(worker_indices=local_idx))
+            for block in itertools.count():
+                # the host blocked on the reader; the last wait of an
+                # epoch is the one that finds the stream exhausted
+                with span("fit.input_wait", epoch=epoch, block=block):
+                    got = next(blocks, None)
+                if got is None:
+                    break
+                xs, ys, steps = got
                 xb, yb = self._shard_local_data(xs), self._shard_local_data(ys)
                 zero_mvs = self._zero_metric_state(metric_objects)
-                tv, ntv, ov, block_mvs, loss = self._epoch_fn(
-                    tv, ntv, ov, zero_mvs, xb, yb
-                )
+                with span("fit.epoch_dispatch", epoch=epoch, block=block):
+                    tv, ntv, ov, block_mvs, loss = self._epoch_fn(
+                        tv, ntv, ov, zero_mvs, xb, yb
+                    )
                 mvs = (
                     block_mvs
                     if mvs is None
@@ -635,9 +682,11 @@ class MeshRunner(KerasIntrospection):
                 )
                 losses.append((loss, steps))
             total_steps = sum(s for _, s in losses)
-            epoch_loss = (
-                sum(float(np.asarray(l)) * s for l, s in losses) / total_steps
-            )
+            with span("fit.loss_wait", epoch=epoch):
+                epoch_loss = (
+                    sum(float(np.asarray(l)) * s for l, s in losses)
+                    / total_steps
+                )
             history["loss"].append(epoch_loss)
             self._history_from_metrics(history, metric_objects, mvs)
             if verbose:
@@ -645,11 +694,8 @@ class MeshRunner(KerasIntrospection):
                     "epoch %d/%d - loss: %.4f (%d blocks streamed)",
                     epoch + 1, epochs, epoch_loss, len(losses),
                 )
-            if callbacks:
-                self._write_back(tv, ntv, ov)
-                for cb in callbacks:
-                    cb(epoch, epoch_loss)
-        self._write_back(tv, ntv, ov)
+            self._end_epoch(epoch, epoch_loss, (tv, ntv, ov), size, callbacks)
+        self._traced_write_back((tv, ntv, ov), size, epochs - 1, final=True)
         return history
 
     @staticmethod

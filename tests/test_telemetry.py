@@ -159,11 +159,11 @@ class TestNullMode:
         try:
             assert was is False
             reg = telemetry.registry()
-            c = reg.counter("n_total", "x")
+            c = reg.counter("nullmode_probe_total", "x")
             c.inc(100)
             assert c.value == 0
-            reg.histogram("n_seconds", "x").observe(1.0)
-            reg.gauge("n_g", "x").set(5)
+            reg.histogram("nullmode_probe_seconds", "x").observe(1.0)
+            reg.gauge("nullmode_probe_gauge", "x").set(5)
             assert reg.render() == ""
             tr = telemetry.tracer()
             assert tr.emit("never") == -1
@@ -172,8 +172,10 @@ class TestNullMode:
             assert tr.events() == []
         finally:
             telemetry.set_null(False)
-        # the REAL registry never saw the null-mode names
-        assert "n_total" not in telemetry.scrape_text()
+        # the REAL registry never saw the null-mode names (which no
+        # real series' name can contain: "n_total" was a substring of
+        # elephas_serving_migrated_in_total)
+        assert "nullmode_probe" not in telemetry.scrape_text()
 
     def test_null_engine_pays_no_registry_series(self, not_null, serving_lm):
         """An engine built under null mode records nothing and scrapes
@@ -648,3 +650,333 @@ class TestPrefillStallSemantics:
         assert len(out) == 2
         assert engine._m_prefill_stalls.value > 0
         engine.release_telemetry()
+
+
+# -- one clock, fit's spans, the mirror into the device trace (ISSUE 24) --
+
+
+class TestOneClock:
+    def test_every_event_carries_mono_ns_in_seq_order(self):
+        tr = telemetry.EventTracer(capacity=64)
+        before = time.monotonic_ns()
+        tr.emit("a")
+        with tr.span("b"):
+            tr.emit("inside")
+        tr.complete("c", 0.0)
+        tr.emit("d")
+        evs = sorted(
+            tr.events(), key=lambda e: e.get("seq_begin", e["seq"])
+        )
+        assert [e["name"] for e in evs] == ["a", "b", "inside", "c", "d"]
+        stamps = [e["mono_ns"] for e in evs]
+        assert all(isinstance(m, int) for m in stamps)
+        assert stamps == sorted(stamps)
+        assert before <= stamps[0] and stamps[-1] <= time.monotonic_ns()
+
+    def test_span_dur_is_monotonic_and_ts_stays_wall(self, monkeypatch):
+        from elephas_tpu.telemetry import events as events_mod
+
+        class Clock:
+            """A wall clock that steps back an hour inside the span."""
+
+            def __init__(self):
+                self.wall = iter([1_000_000.0, 1_000_000.0 - 3600.0])
+                self.mono = iter([5_000_000_000, 5_250_000_000])
+
+            def time(self):
+                return next(self.wall)
+
+            def monotonic_ns(self):
+                return next(self.mono)
+
+        monkeypatch.setattr(events_mod, "time", Clock())
+        tr = telemetry.EventTracer(capacity=8)
+        with tr.span("stepped"):
+            pass
+        monkeypatch.undo()
+        (e,) = tr.events()
+        assert e["ts"] == 1_000_000.0  # the wall reading at the start
+        assert e["mono_ns"] == 5_000_000_000
+        assert e["dur"] == 0.25  # the monotonic difference, not -3600
+
+    def test_complete_backdates_both_clocks(self):
+        tr = telemetry.EventTracer(capacity=8)
+        tr.complete("measured", 0.5)
+        (e,) = tr.events()
+        now_wall, now_mono = time.time(), time.monotonic_ns()
+        assert e["dur"] == 0.5
+        assert 0.5 <= now_wall - e["ts"] < 5.0
+        assert 0.5e9 <= now_mono - e["mono_ns"] < 5e9
+
+
+FIT_EPOCHS = 17
+# a span's name -> the args it must carry (ISSUE 24's table)
+PER_CALL_SPANS = {
+    "fit.call": {"epochs", "batch_size", "workers"},
+    "fit.partition_arrays": {"rows", "bytes"},
+    "fit.to_mesh": set(),
+    "fit.stack_batches": {"bytes"},
+    "fit.shard_data": {"bytes"},
+    "fit.device_state": {"variables", "bytes"},
+}
+PER_EPOCH_SPANS = {
+    "fit.epoch_dispatch": {"epoch"},
+    "fit.loss_wait": {"epoch"},
+    "fit.write_back": {"epoch", "variables", "bytes", "final"},
+    "fit.callbacks": {"epoch", "count"},
+}
+
+
+def _toy_spark_model(num_workers=2):
+    from tests.conftest import make_mlp
+    from elephas_tpu import SparkModel
+
+    return SparkModel(make_mlp(8, 2), num_workers=num_workers)
+
+
+def _own_events(tracer, since):
+    """This thread's events (another test's leftover serving thread
+    may be recording into the same ring)."""
+    me = threading.get_ident()
+    return [e for e in tracer.events(since) if e["tid"] == me]
+
+
+def _toy_rows(n=64):
+    rng = np.random.default_rng(3)
+    x = rng.standard_normal((n, 8)).astype(np.float32)
+    return x, (x.sum(axis=1) > 0).astype(np.int32)
+
+
+@pytest.fixture(scope="module")
+def staged_fit_events(spark_context):
+    """The default ring's events of one staged 17-epoch ``fit`` call
+    over an RDD (the path the benchmark's fit cell drives)."""
+    from elephas_tpu.utils import rdd_utils
+
+    assert not telemetry.null_mode()
+    x, y = _toy_rows()
+    rdd = rdd_utils.to_simple_rdd(spark_context, x, y, num_partitions=2)
+    tracer = telemetry.default_tracer()
+    since = tracer.seq
+    _toy_spark_model().fit(rdd, epochs=FIT_EPOCHS, batch_size=8)
+    return _own_events(tracer, since)
+
+
+class TestFitSpans:
+    def test_ring_keeps_every_epoch_event_inside_the_budget(
+        self, staged_fit_events
+    ):
+        """At most 12 ring events an epoch and 12 a call outside the
+        epoch loop, so that a 17-epoch call of a model with hundreds of
+        variables cannot push its first ``fit.epoch`` out of the default
+        ring (the benchmark's window opens on it)."""
+        evs = staged_fit_events
+        assert telemetry.events.DEFAULT_CAPACITY == 8192
+        epochs = [e for e in evs if e["name"] == "fit.epoch"]
+        assert [e["args"]["epoch"] for e in epochs] == list(range(FIT_EPOCHS))
+        per_epoch = [
+            e for e in evs
+            if "epoch" in e["args"] and not e["args"].get("final")
+        ]
+        assert len(per_epoch) <= 12 * FIT_EPOCHS
+        assert len(evs) - len(per_epoch) <= 12
+
+    def test_span_tree_of_a_staged_call(self, staged_fit_events):
+        evs = staged_fit_events
+        spans = [e for e in evs if e["ph"] == "X"]
+        (root,) = [e for e in spans if e["name"] == "fit.call"]
+        assert root["args"]["epochs"] == FIT_EPOCHS
+        assert root["args"]["batch_size"] == 8
+        assert root["args"]["workers"] == 2
+        trace = root["args"]["trace"]
+        assert trace.startswith("fit-r") and trace.endswith("e0")
+        for e in evs:  # everything is inside the root, under its scope
+            assert e["name"].startswith("fit.")
+            assert e["args"]["trace"] == trace
+            if e is not root:
+                assert root["seq_begin"] < e.get("seq_begin", e["seq"])
+                assert e["seq"] < root["seq"]
+        for name, keys in PER_CALL_SPANS.items():
+            (e,) = [s for s in spans if s["name"] == name]
+            assert keys <= set(e["args"]), name
+        for name, keys in PER_EPOCH_SPANS.items():
+            found = [
+                s for s in spans
+                if s["name"] == name and not s["args"].get("final")
+            ]
+            assert [s["args"]["epoch"] for s in found] == list(
+                range(FIT_EPOCHS)
+            ), name
+            assert all(keys <= set(s["args"]) for s in found), name
+        (last,) = [s for s in spans if s["args"].get("final")]
+        assert last["name"] == "fit.write_back"
+        assert last["args"]["epoch"] == FIT_EPOCHS - 1
+        # stage-in comes before the first epoch, in the table's order
+        order = [s["name"] for s in sorted(spans, key=lambda s: s["seq_begin"])]
+        assert order[:7] == [
+            "fit.call", "fit.partition_arrays", "fit.to_mesh",
+            "fit.stack_batches", "fit.shard_data", "fit.device_state",
+            "fit.epoch_dispatch",
+        ]
+        sizes = {s["name"]: s["args"] for s in spans}
+        assert sizes["fit.partition_arrays"]["rows"] == 64
+        assert sizes["fit.partition_arrays"]["bytes"] == 64 * 8 * 4 + 64 * 4
+        assert sizes["fit.device_state"]["variables"] > 4
+        assert (
+            sizes["fit.write_back"]["bytes"]
+            == sizes["fit.device_state"]["bytes"] > 0
+        )
+
+    def test_streamed_fit_records_input_wait(self):
+        x, y = _toy_rows()
+        tracer = telemetry.default_tracer()
+        since = tracer.seq
+        _toy_spark_model().fit(
+            (x, y), epochs=2, batch_size=8, stream_block_steps=2
+        )
+        evs = _own_events(tracer, since)
+        names = {e["name"] for e in evs}
+        assert names >= {
+            "fit.call", "fit.device_state", "fit.input_wait",
+            "fit.epoch_dispatch", "fit.loss_wait", "fit.write_back",
+            "fit.callbacks", "fit.epoch",
+        }
+        assert "fit.stack_batches" not in names  # nothing staged whole
+        for epoch in range(2):
+            waits = [
+                e["args"]["block"] for e in evs
+                if e["name"] == "fit.input_wait"
+                and e["args"]["epoch"] == epoch
+            ]
+            runs = [
+                e["args"]["block"] for e in evs
+                if e["name"] == "fit.epoch_dispatch"
+                and e["args"]["epoch"] == epoch
+            ]
+            # one wait for each block and one that finds the end
+            assert runs and waits == list(range(len(runs) + 1))
+
+
+def _host_annotations(trace_dir, prefixes):
+    """``[(name, seq)]`` of the recorded host plane's events whose name
+    starts with one of ``prefixes``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(
+        os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")
+    )
+    found = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(prefixes):
+                    found.append((e.name, dict(e.stats).get("seq")))
+    return found
+
+
+class TestProfilerMirror:
+    def test_spans_reach_the_profiler_trace_and_join_on_seq(
+        self, not_null, serving_lm, spark_context, tmp_path
+    ):
+        """One ``jax.profiler`` session over a ``fit`` and a serving
+        run: the host plane holds the ring's ``fit.*`` and ``serve.*``
+        spans as annotations carrying their begin sequence number."""
+        import jax
+
+        from elephas_tpu.serving import InferenceEngine
+        from elephas_tpu.utils import rdd_utils
+
+        x, y = _toy_rows()
+        rdd = rdd_utils.to_simple_rdd(spark_context, x, y, num_partitions=2)
+        sm = _toy_spark_model()
+        engine = InferenceEngine(serving_lm, num_slots=2)
+        tracer = telemetry.default_tracer()
+        since = tracer.seq
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            sm.fit(rdd, epochs=2, batch_size=8)
+            engine.run([([2, 3, 4], 4)])
+        finally:
+            jax.profiler.stop_trace()
+        ring = {
+            (e["name"], e.get("seq_begin", e["seq"]))
+            for e in tracer.events(since)
+        }
+        mirrored = _host_annotations(str(tmp_path), ("fit.", "serve."))
+        names = {name for name, _seq in mirrored}
+        assert {"fit.write_back", "fit.epoch", "fit.call",
+                "serve.decode_window"} <= names
+        for name, seq in mirrored:
+            assert (name, seq) in ring, (name, seq)
+        # the other way: every span this thread recorded in the session
+        # was mirrored (another test's leftover thread may hold a span
+        # that opened before the session did)
+        spans = {
+            (e["name"], e["seq_begin"]) for e in tracer.events(since)
+            if e["ph"] == "X" and e["name"] != "jit.compile"
+            and e["tid"] == threading.get_ident()
+        }
+        assert spans <= set(mirrored)
+
+    def test_no_session_and_null_mode_open_no_annotation(
+        self, not_null, monkeypatch
+    ):
+        import jax
+
+        opened = []
+
+        class Recording(jax.profiler.TraceAnnotation):
+            def __init__(self, name, **kw):
+                opened.append(name)
+                super().__init__(name, **kw)
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recording)
+        tr = telemetry.EventTracer(capacity=8)
+        with tr.span("quiet"):  # no session: a flag check, nothing opened
+            tr.emit("quiet.instant")
+        assert opened == []
+        monkeypatch.setattr(
+            Recording, "is_enabled", staticmethod(lambda: True)
+        )
+        with tr.span("loud"):
+            tr.emit("loud.instant")
+        assert opened == ["loud", "loud.instant"]
+        del opened[:]
+        telemetry.set_null(True)
+        try:
+            with telemetry.trace_span("never"):
+                telemetry.emit("never.instant")
+        finally:
+            telemetry.set_null(False)
+        assert opened == []
+
+
+def test_importing_telemetry_leaves_jax_out():
+    """A process that only records or merges telemetry (a load
+    generator, ``python -m elephas_tpu.telemetry.merge``) never pays
+    for JAX: neither the package nor ``telemetry`` imports it."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "import elephas_tpu.telemetry as t\n"
+        "with t.trace_span('s'):\n"
+        "    t.emit('i')\n"
+        "assert len(t.default_tracer().events()) == 2\n"
+        "print(sorted(m for m in ('jax', 'jaxlib', 'keras', 'numpy') "
+        "if m in sys.modules))\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=60, cwd=os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))),
+    )
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "[]"
