@@ -33,7 +33,7 @@ from elephas_tpu import telemetry
 from elephas_tpu.data.rdd import Rdd
 from elephas_tpu.parallel.mesh import worker_mesh
 from elephas_tpu.utils import rdd_utils
-from elephas_tpu.worker import MeshRunner, MODES, FREQUENCIES
+from elephas_tpu.worker import MeshRunner, MODES, FREQUENCIES, reads_model
 
 logger = logging.getLogger(__name__)
 
@@ -493,6 +493,12 @@ class SparkModel:
         Returns the Keras-style history dict (also appended to
         ``training_histories``).
 
+        The master model holds the trained weights after the call,
+        always. While the call runs it is synced at an epoch boundary
+        only ahead of something that reads it there (parameter-server
+        publication, a checkpoint that is due, per-epoch validation):
+        a plain ``fit`` moves the state to the host once, at its end.
+
         Beyond the reference's surface (SURVEY.md §5):
 
         - ``profile_dir``: capture a ``jax.profiler`` trace of the compiled
@@ -796,24 +802,37 @@ class SparkModel:
 
         self.start_server(restore_journal=bool(resume))
         try:
-            # epoch boundaries land on the shared trace timeline
+            # Each callback declares whether it reads the master model:
+            # the runner syncs it at an epoch boundary only ahead of one
+            # that does, so telemetry alone moves no state to the host.
+            # First, epoch boundaries land on the shared trace timeline
             # (ISSUE 5) so training cadence can be correlated with PS
             # round-trips and chaos events in one Chrome trace
-            callbacks = [
-                lambda epoch, loss: telemetry.emit(
-                    "fit.epoch", epoch=int(epoch), loss=float(loss)
-                )
-            ]
+            @reads_model(False)
+            def emit_epoch(epoch, loss):
+                telemetry.emit("fit.epoch", epoch=int(epoch), loss=float(loss))
+
+            callbacks = [emit_epoch]
             if self._parameter_server is not None:
                 # keep the external weight store live at epoch boundaries
-                # (run_epochs syncs the master model before each callback)
-                callbacks.append(lambda *_: self._publish_weights())
+                # (the runner syncs the master model ahead of a callback
+                # that reads it, as this one does at every epoch)
+                @reads_model(True)
+                def publish(_epoch, _loss):
+                    self._publish_weights()
+
+                callbacks.append(publish)
             if checkpoint_dir:
 
+                def ckpt_due(epoch):
+                    return (start_epoch + epoch + 1) % checkpoint_every == 0
+
+                @reads_model(ckpt_due)
                 def save_ckpt(epoch, _loss):
-                    done = start_epoch + epoch + 1
-                    if done % checkpoint_every == 0:
-                        runner.save_checkpoint(checkpoint_dir, done)
+                    if ckpt_due(epoch):
+                        runner.save_checkpoint(
+                            checkpoint_dir, start_epoch + epoch + 1
+                        )
 
                 callbacks.append(save_ckpt)
             if history_log:
@@ -827,6 +846,7 @@ class SparkModel:
                 t_start = _time.time()
                 if is_coordinator():
 
+                    @reads_model(False)
                     def log_epoch(epoch, loss):
                         with open(history_log, "a") as f:
                             f.write(
@@ -849,6 +869,8 @@ class SparkModel:
             )
             if val_evaluate is not None and self.frequency != "fit":
                 # per-epoch validation, like keras.fit's val_* history
+                # (evaluate stages its weights from the master model)
+                @reads_model(True)
                 def eval_cb(_epoch, _loss):
                     for k, v in val_evaluate().items():
                         val_history.setdefault(f"val_{k}", []).append(v)

@@ -59,6 +59,27 @@ MODES = ("synchronous", "asynchronous", "hogwild")
 FREQUENCIES = ("epoch", "batch", "fit")
 
 
+def reads_model(when):
+    """Decorator: declare whether an epoch callback ``cb(epoch, loss)``
+    reads the master model. ``when`` is a bool, or a predicate of the
+    epoch number for a callback that reads it at some epochs only (a
+    checkpoint every tenth). :meth:`MeshRunner._end_epoch` syncs the
+    master model at an epoch boundary only ahead of a callback that
+    reads it there. A callable that declares nothing is taken to read
+    the model at every epoch."""
+
+    def declare(cb):
+        cb.reads_model = when
+        return cb
+
+    return declare
+
+
+def _reads_model_at(cb, epoch: int) -> bool:
+    when = getattr(cb, "reads_model", True)
+    return bool(when(epoch) if callable(when) else when)
+
+
 def _pmean_floats(tree, axis_name: str):
     """pmean float leaves; pass integer leaves (counters, seeds) through."""
     return jax.tree.map(
@@ -457,19 +478,31 @@ class MeshRunner(KerasIntrospection):
             sp.set(**size)
         return state, size
 
-    def _traced_write_back(self, state, size, epoch, final=False):
+    def _traced_write_back(self, state, size, epoch, final=False, sync=True):
+        """:meth:`_write_back` under its ``fit.write_back`` span, whose
+        ``variables`` and ``bytes`` say what crossed to the host:
+        nothing where ``sync`` is false, and the span stays to say so."""
+        if not sync:
+            size = dict.fromkeys(size, 0)
         with telemetry.trace_span(
             "fit.write_back", epoch=epoch, final=final, **size
         ):
-            self._write_back(*state)
+            if sync:
+                self._write_back(*state)
 
     def _end_epoch(self, epoch, epoch_loss, state, size, callbacks):
         """The tail that the staged and the streamed epoch loops share:
-        sync the master model, so callbacks (e.g. parameter-server
-        publication) observe live weights, then invoke them."""
+        sync the master model if a callback reads it at this epoch
+        (:func:`reads_model`; e.g. parameter-server publication, which
+        must observe live weights), then invoke the callbacks. With no
+        such callback the master model stays as the call found it until
+        the call's ``final`` write-back. The decision rests on the list
+        and the epoch number alone, so every process of a gang decides
+        alike."""
         if not callbacks:
             return
-        self._traced_write_back(state, size, epoch)
+        sync = any(_reads_model_at(cb, epoch) for cb in callbacks)
+        self._traced_write_back(state, size, epoch, sync=sync)
         with telemetry.trace_span(
             "fit.callbacks", epoch=epoch, count=len(callbacks)
         ):
@@ -560,6 +593,13 @@ class MeshRunner(KerasIntrospection):
         """Run ``epochs`` compiled epochs; returns a Keras-style history dict
         (loss + every compiled metric, like ``keras.Model.fit``) and leaves
         trained weights on the master model.
+
+        ``callbacks`` are ``cb(epoch, loss)``, invoked at each epoch
+        boundary. The master model is live after the call, always; at
+        an epoch boundary only ahead of a callback that reads it there
+        (:func:`reads_model`; a callable that declares nothing is taken
+        to read it at every epoch), because the sync costs a transfer
+        of the whole state to the host.
 
         Metric values count wrap-padded rows of ragged final batches
         (duplicated samples weigh in twice) — the same rows the loss
@@ -835,7 +875,8 @@ class MeshRunner(KerasIntrospection):
     def host_weights(self):
         """Full weights on host for parameter-server publication (the
         wire protocol is host numpy lists by contract). Current because
-        run_epochs writes back before callbacks fire."""
+        run_epochs writes back ahead of a callback that reads the model
+        (:func:`reads_model`), as the publication callback does."""
         return self.model.get_weights()
 
     # -- checkpointing (runner-dispatched; SparkModel stays agnostic) ----
