@@ -22,6 +22,7 @@ from elephas_tpu.models.switch import (
     switch_transformer_classifier,
     switch_transformer_lm,
 )
+from elephas_tpu.models.qwen3_next import qwen3_next_lm
 
 __all__ = [
     "mnist_mlp",
@@ -34,9 +35,15 @@ __all__ = [
     "generate",
     "switch_transformer_classifier",
     "switch_transformer_lm",
+    "qwen3_next_lm",
     "MoeFFN",
     "FlashMHA",
     "FusedLayerNorm",
+    "ZeroCentredRMSNorm",
+    "SwiGLU",
+    "GatedAttention",
+    "GatedDeltaNet",
+    "SparseMoeBlock",
 ]
 
 
@@ -54,4 +61,8 @@ def __getattr__(name):
         from elephas_tpu.models.switch import MoeFFN
 
         return MoeFFN
+    from elephas_tpu.models import qwen3_next
+
+    if name in qwen3_next.LAYER_NAMES:
+        return getattr(qwen3_next, name)
     raise AttributeError(name)

@@ -275,13 +275,14 @@ def _positions(maxlen: int, d_model: int) -> np.ndarray:
 
 
 @functools.lru_cache(maxsize=8)
-def _rope_tables(maxlen: int, head_dim: int):
+def _rope_tables(maxlen: int, head_dim: int, theta: float = 10000.0):
     """cos/sin tables ``[S, D]`` for rotary position embeddings
-    (half-split / GPT-NeoX convention; ``head_dim`` must be even).
+    (half-split / GPT-NeoX convention; ``head_dim`` must be even;
+    ``theta`` is the base of the frequencies).
     Cached so every attention layer shares ONE host table (and jax sees
     one constant object) instead of L identical copies — at long-context
     sequence lengths the table is large (code-review r4)."""
-    inv = 1.0 / (10000.0 ** (np.arange(0, head_dim, 2) / head_dim))
+    inv = 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
     ang = np.arange(maxlen)[:, None] * inv[None, :]  # [S, D/2]
     cos = np.concatenate([np.cos(ang), np.cos(ang)], axis=-1)
     sin = np.concatenate([np.sin(ang), np.sin(ang)], axis=-1)

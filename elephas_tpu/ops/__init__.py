@@ -10,10 +10,24 @@ package holds the ops where hand-scheduling beats the compiler:
 - :mod:`elephas_tpu.ops.ring_attention` — sequence-parallel attention
   over a mesh axis via ``ppermute`` (KV blocks rotate over ICI while
   each device computes its local query block).
+- :mod:`elephas_tpu.ops.gated_delta` — the gated delta rule of
+  recurrent-state (linear-attention) layers, chunked, forward and
+  backward.
+- :mod:`elephas_tpu.ops.moe` — expert-parallel and dropless
+  held-experts mixture-of-experts FFNs.
 """
 
 from elephas_tpu.ops.flash_attention import flash_attention
 from elephas_tpu.ops.ring_attention import ring_attention
 from elephas_tpu.ops.ulysses import ulysses_attention
+from elephas_tpu.ops.gated_delta import (
+    gated_delta_rule,
+    gated_delta_rule_recurrent,
+)
+from elephas_tpu.ops.moe import grouped_matmul, held_experts_ffn
 
-__all__ = ["flash_attention", "ring_attention", "ulysses_attention"]
+__all__ = [
+    "flash_attention", "ring_attention", "ulysses_attention",
+    "gated_delta_rule", "gated_delta_rule_recurrent",
+    "grouped_matmul", "held_experts_ffn",
+]

@@ -56,39 +56,52 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[:]  # [BQ, D]
-    k = k_ref[:]  # [BK, D]
-    s = jax.lax.dot_general(
-        q, k,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale  # [BQ, BK]
+    # read outside the conditional body below: the interpreter has no
+    # program_id inside one
+    i = pl.program_id(1)
+
+    def _accumulate():
+        q = q_ref[:]  # [BQ, D]
+        k = k_ref[:]  # [BK, D]
+        s = jax.lax.dot_general(
+            q, k,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [BQ, BK]
+
+        if causal:
+            rows = i * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0
+            )
+            cols = j * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1
+            )
+            s = jnp.where(cols <= rows, s, NEG_INF)
+
+        m_prev = m_ref[:]  # [BQ, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)  # [BQ, BK]
+        # fully-masked-so-far rows: m_new is still NEG_INF and s - m_new
+        # == 0 would make p == 1, accumulating phantom mass (the row
+        # would output mean(V) instead of zeros). Zero p so l stays 0
+        # for those rows.
+        p = jnp.where(m_new <= NEG_INF * 0.5, 0.0, p)
+        alpha = jnp.exp(m_prev - m_new)  # [BQ, 1]
+        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+            p, v_ref[:].astype(jnp.float32),
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[:] = m_new
 
     if causal:
-        i = pl.program_id(1)
-        rows = i * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        cols = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        s = jnp.where(cols <= rows, s, NEG_INF)
-
-    m_prev = m_ref[:]  # [BQ, 1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)  # [BQ, BK]
-    # fully-masked-so-far rows: m_new is still NEG_INF and s - m_new == 0
-    # would make p == 1, accumulating phantom mass (the row would output
-    # mean(V) instead of zeros). Zero p so l stays 0 for those rows.
-    p = jnp.where(m_new <= NEG_INF * 0.5, 0.0, p)
-    alpha = jnp.exp(m_prev - m_new)  # [BQ, 1]
-    l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-        p, v_ref[:].astype(jnp.float32),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_ref[:] = m_new
+        # a key block wholly ahead of the query block adds nothing (its
+        # scores are all masked, so the branch above leaves the
+        # accumulators as they are): skip its two products
+        pl.when(j * block_k < (i + 1) * block_q)(_accumulate)
+    else:
+        _accumulate()
 
     @pl.when(j == last_j)
     def _finalize():
@@ -124,9 +137,13 @@ def _cost(bh, s_q, s_k, d, itemsize):
 
 
 def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
-    """[BH, S, D] inputs → (out [BH, S, D], lse [BH, S])."""
+    """[BH, S, D] inputs → (out [BH, S, D], lse [BH, S]); ``k`` and
+    ``v`` may have a whole fraction of ``q``'s heads (``[BH / G, S, D]``)."""
     bh, s_q, d = q.shape
     s_k = k.shape[1]
+    # grouped-query attention: ``group`` consecutive query heads read
+    # one key/value head, through the index map (no repeated copy)
+    group = bh // k.shape[0]
     block_q, block_k = _resolve_blocks(block_q, block_k, s_q, s_k)
     grid = (bh, s_q // block_q, s_k // block_k)
     kernel = functools.partial(
@@ -141,8 +158,10 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((None, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((None, block_k, d),
+                         lambda b, i, j: (b // group, j, 0)),
+            pl.BlockSpec((None, block_k, d),
+                         lambda b, i, j: (b // group, j, 0)),
         ],
         out_specs=[
             pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -515,7 +534,20 @@ def _fwd_rule(q, k, v, scale, causal, block_q, block_k, interpret):
 
 
 def _bwd_rule(scale, causal, block_q, block_k, interpret, residuals, g):
-    return _flash_backward(scale, causal, block_q, block_k, residuals, g)
+    q, k, v, out, lse = residuals
+    group = q.shape[0] // k.shape[0]
+    if group == 1:
+        return _flash_backward(scale, causal, block_q, block_k, residuals, g)
+    # grouped-query: each query head against its key/value head's copy,
+    # then the group's gradients summed onto the one head they share
+    dq, dk, dv = _flash_backward(
+        scale, causal, block_q, block_k,
+        (q, jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0),
+         out, lse), g,
+    )
+    shared = lambda t: t.astype(jnp.float32).reshape(  # noqa: E731
+        (k.shape[0], group) + k.shape[1:]).sum(axis=1).astype(k.dtype)
+    return dq, shared(dk), shared(dv)
 
 
 _flash_attention_bhsd.defvjp(_fwd_rule, _bwd_rule)
@@ -619,6 +651,9 @@ def flash_attention(
 ):
     """Blockwise attention. ``q/k/v``: ``[batch, heads, seq, head_dim]``
     (or ``[bh, seq, head_dim]``). Differentiable; O(seq) memory.
+    ``k`` and ``v`` may have fewer heads than ``q`` (grouped-query
+    attention): ``heads_q / heads_kv`` consecutive query heads then
+    share one key/value head.
 
     ``block_q``/``block_k`` default to the module-level
     ``DEFAULT_BLOCK_Q``/``DEFAULT_BLOCK_K`` (resolved at CALL time, so
@@ -637,7 +672,12 @@ def flash_attention(
         q, k, v = q[None], k[None], v[None]
     b, h, s_q, d = q.shape
     s_k = k.shape[2]
-    merged = lambda t, s: t.reshape(b * h, s, d)  # noqa: E731
+    if h % k.shape[1] or k.shape[1] != v.shape[1]:
+        raise ValueError(
+            f"{h} query heads cannot share {k.shape[1]} key and "
+            f"{v.shape[1]} value heads"
+        )
+    merged = lambda t, s: t.reshape(-1, s, d)  # noqa: E731
     out = _flash_attention_bhsd(
         merged(q, s_q),
         merged(k, s_k),

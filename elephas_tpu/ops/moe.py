@@ -17,12 +17,24 @@ is linear).
 Call :func:`expert_parallel_ffn` INSIDE ``shard_map`` with tokens sharded
 over the same axis as the experts. :func:`moe_ffn_reference` is the
 single-device oracle used by the tests.
+
+:func:`held_experts_ffn` is the other construction, for experts as they
+are deployed today (hundreds of small gated experts, many a token): the
+router scores ALL experts, the chip computes the part of the result
+that the experts it HOLDS give, and no token is ever dropped: token
+slots are sorted by expert into a buffer sized for the worst case and
+go through one grouped matrix product (:func:`grouped_matmul`) whose
+work follows the rows that are really there.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+
+from elephas_tpu.utils import backend_guard
 
 
 def _topk_dispatch(x, gate_w, num_experts: int, capacity: int, k: int = 1):
@@ -217,3 +229,214 @@ def init_moe_params(
         jax.random.normal(k3, (num_experts, d_hidden, d_model), dtype) * scale2,
         jnp.zeros((num_experts, d_model), dtype),
     )
+
+
+# -- dropless routing over the experts held ------------------------------
+
+
+def route_top_k(x, router_w, k: int):
+    """``(weights [T, k] float32, experts [T, k] int32)``: softmax over
+    ALL of the router's outputs in float32, the ``k`` largest,
+    renormalised to sum to 1."""
+    logits = jnp.matmul(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    weights, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    return weights / jnp.sum(weights, axis=-1, keepdims=True), experts
+
+
+def _tile(size: int, wanted: int) -> int:
+    """The largest power-of-two fraction of ``wanted`` that divides
+    ``size`` (the kernel's tiles must divide its operands)."""
+    while wanted > 1 and size % wanted:
+        wanted //= 2
+    return wanted
+
+
+def grouped_matmul(lhs, rhs, group_sizes, kernel: bool | None = None):
+    """``out[r] = lhs[r] @ rhs[group of r]`` for rows sorted by group:
+    ``lhs [M, K]``, ``rhs [G, K, N]``, ``group_sizes [G]`` int32. Rows
+    past ``sum(group_sizes)`` belong to no group and come out zero, and
+    cost nothing but their zeroing. It is the Pallas grouped product
+    (megablox) wherever Pallas compiles, and ``lax.ragged_dot`` on the
+    ``cpu`` backend; ``kernel`` overrides that for a program compiled
+    for another platform than the process's own. Differentiable either
+    way."""
+    if kernel is None:
+        kernel = not backend_guard.pallas_interpret()
+    group_sizes = group_sizes.astype(jnp.int32)
+    if not kernel:
+        return jax.lax.ragged_dot(
+            lhs, rhs, group_sizes, preferred_element_type=jnp.float32
+        ).astype(lhs.dtype)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    m, k_dim = lhs.shape
+    n_dim = rhs.shape[-1]
+    tiling = (_tile(m, 256), _tile(k_dim, 1024), _tile(n_dim, 1024))
+    # the kernel visits only the tiles that hold a group's rows: what it
+    # leaves of the output (and, on the way back, of lhs's gradient) is
+    # not written at all, so both sides are selected, never multiplied
+    held = (jnp.arange(m, dtype=jnp.int32) < jnp.sum(group_sizes))[:, None]
+    out = gmm(jnp.where(held, lhs, 0), rhs, group_sizes, lhs.dtype, tiling)
+    return jnp.where(held, out, 0)
+
+
+def _slot_rows(rows, position):
+    """``[rows[position[:, j]] for j]`` as float32, with a zero row
+    where ``position == len(rows)`` (a slot outside the buffer):
+    ``position [T, k]`` -> ``k`` arrays ``[T, D]``."""
+    padded = jnp.concatenate([rows, jnp.zeros_like(rows[:1])], axis=0)
+    return [padded[position[:, j]].astype(jnp.float32)
+            for j in range(position.shape[1])]
+
+
+@jax.custom_vjp
+def _take_slots(x, token_of_row, position):
+    """``x[token_of_row]``: the rows of the slot buffer, ``[R, D]``.
+    ``position [T, k]`` says where each token slot sits in the buffer
+    (``R`` where it does not), so that the transpose is ``k`` gathers
+    and a sum, never a scatter."""
+    return x[token_of_row]
+
+
+def _take_slots_fwd(x, token_of_row, position):
+    return x[token_of_row], position
+
+
+def _take_slots_bwd(position, g):
+    return sum(_slot_rows(g, position)).astype(g.dtype), None, None
+
+
+_take_slots.defvjp(_take_slots_fwd, _take_slots_bwd)
+
+
+@jax.custom_vjp
+def _combine_slots(out, weights, position, token_of_row, weight_of_row):
+    """``y[t] = sum_j weights[t, j] * out[position[t, j]]`` in float32
+    (a slot outside the buffer adds nothing); gathers both ways."""
+    return sum(weights[:, j, None] * rows
+               for j, rows in enumerate(_slot_rows(out, position)))
+
+
+def _combine_slots_fwd(out, weights, position, token_of_row, weight_of_row):
+    y = _combine_slots(out, weights, position, token_of_row, weight_of_row)
+    return y, (out, position, token_of_row, weight_of_row)
+
+
+def _combine_slots_bwd(residuals, g):
+    out, position, token_of_row, weight_of_row = residuals
+    d_out = (weight_of_row[:, None] * g[token_of_row]).astype(out.dtype)
+    d_weights = jnp.stack(
+        [jnp.sum(g * rows, axis=-1) for rows in _slot_rows(out, position)],
+        axis=1)
+    return d_out, d_weights, None, None, None
+
+
+_combine_slots.defvjp(_combine_slots_fwd, _combine_slots_bwd)
+
+
+def _held_part(x, weights, local, w_gate_up, w_down, rows: int, held: int):
+    """The held experts' part for tokens ``x [T, D]`` through a slot
+    buffer of ``rows`` rows, which must hold every slot routed to a
+    held expert (``local < held``; ``local [T, k]`` is the expert's
+    index among the held, ``held`` for an absent one)."""
+    tokens, k = local.shape
+    with jax.named_scope("moe.route"):
+        flat = local.reshape(tokens * k)
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+        # absent experts sort behind the held: the buffer's rows are the
+        # first of the order
+        in_buffer = order[:rows]
+        token_of_row = in_buffer // k
+        position = jnp.minimum(
+            jnp.argsort(order).astype(jnp.int32), rows
+        ).reshape(tokens, k)
+        weight_of_row = jnp.where(
+            flat[in_buffer] < held, weights.reshape(tokens * k)[in_buffer],
+            0.0,
+        )
+        group_sizes = jnp.bincount(flat, length=held + 1)[:held]
+        slots = _take_slots(x, token_of_row, position)
+    with jax.named_scope("moe.experts"):
+        gate_up = grouped_matmul(slots, w_gate_up, group_sizes)
+        gate, up = jnp.split(gate_up, 2, axis=-1)
+        hidden = (jax.nn.silu(gate.astype(jnp.float32))
+                  * up.astype(jnp.float32)).astype(x.dtype)
+        out = grouped_matmul(hidden, w_down, group_sizes)
+    with jax.named_scope("moe.route"):
+        y = _combine_slots(out, weights, position, token_of_row,
+                           weight_of_row)
+    return y.astype(x.dtype)
+
+
+def _round_up(n: int, to: int) -> int:
+    return -(-n // to) * to
+
+
+def held_experts_ffn(x, router_w, w_gate_up, w_down, experts_held, k: int):
+    """The routed part of a sparse block that the experts held here
+    give: ``sum_e p_e * down_e(silu(gate_e(x)) * up_e(x))`` over the
+    token's ``k`` chosen experts that lie in ``experts_held``.
+
+    ``x [T, D]``; ``router_w [D, E]`` scores all ``E`` experts;
+    ``experts_held = (first, stop)`` is the range of them whose weights
+    are here, stacked: ``w_gate_up [E_held, D, 2 * I]`` (gate columns
+    first) and ``w_down [E_held, I, D]``. What the absent experts would
+    add is left out; nothing stands in for it.
+
+    No token is dropped whatever the imbalance. The slots routed here
+    are counted first. Up to twice what uniform routing would send,
+    they go through one buffer of that size; past it (every token may
+    choose ``k`` held experts) the tokens are taken in blocks, each
+    through a buffer that holds all of its ``k`` slots a token. Both
+    are the same program at two sizes, chosen by a ``lax.cond`` on the
+    count, and each is rematerialised in the backward pass, so that
+    neither keeps more than its inputs.
+
+    Returns ``(y [T, D] in x's dtype, counts int32 [3])``; ``counts``
+    is (token slots routed to held experts, token slots in all, the
+    fullest held expert's tokens)."""
+    first, stop = experts_held
+    held = stop - first
+    tokens = x.shape[0]
+    with jax.named_scope("moe.route"):
+        weights, experts = route_top_k(x, router_w, k)
+        local = jnp.where(
+            (experts >= first) & (experts < stop), experts - first, held
+        )
+        group_sizes = jnp.bincount(
+            local.reshape(-1), length=held + 1)[:held].astype(jnp.int32)
+        routed_here = jnp.sum(group_sizes)
+    w_gate_up, w_down = w_gate_up.astype(x.dtype), w_down.astype(x.dtype)
+    part = jax.checkpoint(
+        functools.partial(_held_part, held=held), static_argnums=(5,)
+    )
+    every = tokens * k
+    usual = _round_up(2 * every * held // router_w.shape[-1], 8)
+
+    def in_blocks(x, weights, local):
+        blocks = max(1, every // max(usual, 1))
+        while tokens % blocks:
+            blocks -= 1
+        size = tokens // blocks
+        split = lambda t: t.reshape((blocks, size) + t.shape[1:])  # noqa: E731
+        y = jax.lax.map(
+            lambda b: part(b[0], b[1], b[2], w_gate_up, w_down, size * k),
+            (split(x), split(weights), split(local)),
+        )
+        return y.reshape(tokens, -1)
+
+    if usual >= every:
+        y = part(x, weights, local, w_gate_up, w_down, every)
+    else:
+        y = jax.lax.cond(
+            routed_here <= usual,
+            lambda *a: part(*a, w_gate_up, w_down, usual),
+            in_blocks, x, weights, local,
+        )
+    counts = jnp.stack([
+        routed_here, jnp.int32(every), jnp.max(group_sizes),
+    ]).astype(jnp.int32)
+    return y, counts

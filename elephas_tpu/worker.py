@@ -241,17 +241,29 @@ class KerasIntrospection:
         uses. CompileMetrics (and its inner metrics) build lazily — force
         variable creation with one tiny host-side update, then reset.
         """
-        yp = self.model(x_sample[:1], training=False)
+        # loss trackers ('loss' plus per-output '<name>_loss' Means) are
+        # computed by the evaluator's own per-sample loss path, not as
+        # y/y_pred metrics
+        loss_tracker_names = set(self._loss_keys())
+        if all(m.name in loss_tracker_names for m in self.model.metrics):
+            return []  # compiled with no metric
+        # a prediction's shape and dtype are all a metric needs to build
+        # its variables: zeros of the model's output spec stand in for
+        # one, so the master model is never run op by op
+        import keras
+
+        x_head = np.asarray(x_sample)[:1]
+        yp = jax.tree.map(
+            lambda spec: jnp.zeros(spec.shape, spec.dtype),
+            self.model.compute_output_spec(
+                keras.KerasTensor(x_head.shape, dtype=str(x_head.dtype))),
+        )
         multi = isinstance(yp, (list, tuple))
         names = self._output_names()
 
         def y_head(y):
             return jax.tree.map(lambda a: np.asarray(a)[:1], y)
 
-        # loss trackers ('loss' plus per-output '<name>_loss' Means) are
-        # computed by the evaluator's own per-sample loss path, not as
-        # y/y_pred metrics
-        loss_tracker_names = set(self._loss_keys())
         out = []
         for m in self.model.metrics:
             if m.name in loss_tracker_names:
@@ -373,6 +385,7 @@ class MeshRunner(KerasIntrospection):
         self.mesh = mesh
         self.num_workers = mesh.devices.size
         self._epoch_fn = None
+        self._counters = None  # found on first use (_counter_layers)
         self._eval_fn = None
         self._predict_fn = None
         model.optimizer.build(model.trainable_variables)
@@ -395,16 +408,36 @@ class MeshRunner(KerasIntrospection):
             if d.process_index == pid
         ]
 
-    def _device_state(self, stacked: bool = True):
+    def _device_state(self, stacked: bool = True, park_master: bool = False):
         """Current model state, replicated to ``[W, ...]`` worker shards.
 
         Multi-host: each process materializes only its addressable
         workers' slices (``jax.make_array_from_process_local_data``); the
         global array spans the pod without any host holding all of it.
+
+        ``park_master`` (a ``fit``, which ends in a write-back): the
+        master model's variables are given the host's copy, just read,
+        before the runner's goes up. Keras puts a variable where JAX
+        puts any new array, on the first accelerator, so the state
+        would otherwise be there twice for the length of the call (the
+        hybrid MoE LM's 5.0 GB does not fit twice beside its epoch
+        program). A write-back assigns them as it always did, so after
+        the call, and for a callback that reads the master model, they
+        are on the default device again.
         """
         W = self.num_workers
         sharding = NamedSharding(self.mesh, P("workers"))
         tv, ntv, ov = self._host_state()
+        if park_master:
+            model = self.model
+            host = jax.local_devices(backend="cpu")[0]
+            for variables, leaves in (
+                (model.trainable_variables, tv),
+                (model.non_trainable_variables, ntv),
+                (model.optimizer.variables, ov),
+            ):
+                for var, leaf in zip(variables, leaves):
+                    var.assign(jax.device_put(leaf, host))
         multiproc = jax.process_count() > 1
         n_local = len(self._local_worker_indices()) if multiproc else W
 
@@ -453,15 +486,21 @@ class MeshRunner(KerasIntrospection):
             return np.asarray(leaf[index])
         return np.asarray(leaf.addressable_shards[0].data)[0]
 
-    def _write_back(self, tv, ntv, ov=None):
-        """Worker-0 slice → model variables (all replicas agree post-sync)."""
-        for var, leaf in zip(self.model.trainable_variables, tv):
-            var.assign(self._worker_slice(leaf))
-        for var, leaf in zip(self.model.non_trainable_variables, ntv):
-            var.assign(self._worker_slice(leaf))
-        if ov is not None:
-            for var, leaf in zip(self.model.optimizer.variables, ov):
+    def _write_back(self, tv, ntv, ov=None, release: bool = False):
+        """Worker-0 slice → model variables (all replicas agree post-sync).
+        ``release`` (the call's last write-back): each device leaf is
+        freed once it is read, so that the master's copy takes its place
+        on the devices and is never there beside it."""
+        model = self.model
+        for variables, leaves in (
+            (model.trainable_variables, tv),
+            (model.non_trainable_variables, ntv),
+            (model.optimizer.variables, ov or ()),
+        ):
+            for var, leaf in zip(variables, leaves):
                 var.assign(self._worker_slice(leaf))
+                if release and isinstance(leaf, jax.Array):
+                    leaf.delete()
 
     def _traced_device_state(self):
         """:meth:`_device_state` under its ``fit.device_state`` span.
@@ -469,7 +508,7 @@ class MeshRunner(KerasIntrospection):
         replica's ``bytes``, from shapes alone): the args that carry
         the per-variable detail, which must never become ring events."""
         with telemetry.trace_span("fit.device_state") as sp:
-            state = self._device_state()
+            state = self._device_state(park_master=True)
             leaves = [leaf for part in state for leaf in part]
             size = {
                 "variables": len(leaves),
@@ -488,7 +527,7 @@ class MeshRunner(KerasIntrospection):
             "fit.write_back", epoch=epoch, final=final, **size
         ):
             if sync:
-                self._write_back(*state)
+                self._write_back(*state, release=final)
 
     def _end_epoch(self, epoch, epoch_loss, state, size, callbacks):
         """The tail that the staged and the streamed epoch loops share:
@@ -508,6 +547,63 @@ class MeshRunner(KerasIntrospection):
         ):
             for cb in callbacks:
                 cb(epoch, epoch_loss)
+
+    # -- layer counters ------------------------------------------------
+
+    def _counter_layers(self) -> list:
+        """``[(layer name, counter names, index among the non-trainable
+        variables)]`` of the layers that count what they do inside the
+        compiled program. Such a layer says so itself: its
+        ``epoch_counters`` maps the attribute that holds an integer
+        non-trainable variable, which the layer adds to call by call,
+        to the names of that variable's entries (a sparse block's
+        routed token slots). Found once a runner."""
+        if self._counters is None:
+            index = {
+                id(v): i
+                for i, v in enumerate(self.model.non_trainable_variables)
+            }
+            self._counters = [
+                (layer.name, tuple(names), index[id(getattr(layer, attr))])
+                for layer in self.model._flatten_layers()
+                for attr, names in getattr(layer, "epoch_counters", {}).items()
+            ]
+        return self._counters
+
+    def _counter_totals(self, ntv=None) -> list:
+        """Every counter layer's running totals, summed over the
+        workers: from the device state ``ntv`` (a few integers a layer
+        cross to the host), or from the master model before a call's
+        first epoch (each worker starts from its copy)."""
+        totals = []
+        for _name, _names, i in self._counter_layers():
+            if ntv is None:
+                var = self.model.non_trainable_variables[i]
+                value = np.asarray(var.value).astype(np.int64)
+                totals.append(value * self.num_workers)
+            else:
+                totals.append(
+                    np.asarray(self._gather(ntv[i])).astype(np.int64).sum(0))
+        return totals
+
+    def _emit_counters(self, epoch: int, ntv, before: list) -> list:
+        """One ``fit.counters`` event an epoch: what each counter layer
+        added up in the epoch that just ended (its totals less those
+        before it; the variables are int32 and may wrap). Called where
+        the epoch's loss has just been read, so the program is done and
+        nothing waits. Returns the totals for the next epoch's call."""
+        layers = self._counter_layers()
+        if not layers:
+            return before
+        now = self._counter_totals(ntv)
+        by_layer: dict = {}
+        for (name, names, _i), after, prior in zip(layers, now, before):
+            by_layer.setdefault(name, {}).update(
+                (key, int(v))
+                for key, v in zip(names, (after - prior) % (1 << 32))
+            )
+        telemetry.emit("fit.counters", epoch=int(epoch), layers=by_layer)
+        return now
 
     # -- loss helpers --------------------------------------------------
 
@@ -617,6 +713,7 @@ class MeshRunner(KerasIntrospection):
         with span("fit.shard_data", bytes=xs.nbytes + ys.nbytes):
             xb = self._shard_data(xs)
             yb = self._shard_data(ys)
+        counted = self._counter_totals()  # as the device state starts
         (tv, ntv, ov), size = self._traced_device_state()
         metric_objects = self._unwrapped_metrics(partitions[0][0], partitions[0][1])
         if self._epoch_fn is None:
@@ -629,6 +726,7 @@ class MeshRunner(KerasIntrospection):
                 tv, ntv, ov, mvs, loss = self._epoch_fn(tv, ntv, ov, mvs, xb, yb)
             with span("fit.loss_wait", epoch=epoch):
                 epoch_loss = float(np.asarray(loss))  # replicated: direct read
+            counted = self._emit_counters(epoch, ntv, counted)
             history["loss"].append(epoch_loss)
             self._history_from_metrics(history, metric_objects, mvs)
             if verbose:
@@ -684,6 +782,7 @@ class MeshRunner(KerasIntrospection):
         if self._epoch_fn is None:
             self._epoch_fn = self._build_epoch_fn(metric_objects)
         span = telemetry.trace_span
+        counted = self._counter_totals()  # as the device state starts
         (tv, ntv, ov), size = self._traced_device_state()
 
         # multi-host: gather only this process's workers' rows from the
@@ -727,6 +826,7 @@ class MeshRunner(KerasIntrospection):
                     sum(float(np.asarray(l)) * s for l, s in losses)
                     / total_steps
                 )
+            counted = self._emit_counters(epoch, ntv, counted)
             history["loss"].append(epoch_loss)
             self._history_from_metrics(history, metric_objects, mvs)
             if verbose:

@@ -51,9 +51,9 @@ def write_backs(monkeypatch):
     calls = []
     original = MeshRunner._write_back
 
-    def counted(self, tv, ntv, ov=None):
+    def counted(self, tv, ntv, ov=None, **how):
         calls.append(len(tv) + len(ntv) + len(ov or ()))
-        return original(self, tv, ntv, ov)
+        return original(self, tv, ntv, ov, **how)
 
     monkeypatch.setattr(MeshRunner, "_write_back", counted)
     return calls
@@ -306,3 +306,103 @@ def test_tagged_list_passes_through_other_runners(tmp_path, layout):
     assert lines[-1]["final"] is True
     assert len(history["val_loss"]) == epochs
     assert len(set(history["val_loss"])) == epochs
+
+
+# -- where the master model is while the runner holds the state (ISSUE 27) --
+
+
+@pytest.fixture
+def far_host(monkeypatch):
+    """Makes the host's device another than the workers': the last of
+    the virtual CPU devices stands for the host beside a chip, so that
+    a parked master is told from one on the default device."""
+    import jax
+
+    far = jax.devices()[-1]
+    assert far not in jax.devices()[:WORKERS]
+    original = jax.local_devices
+
+    def local_devices(*args, backend=None, **kwargs):
+        if backend == "cpu":
+            return [far]
+        return original(*args, backend=backend, **kwargs)
+
+    monkeypatch.setattr(jax, "local_devices", local_devices)
+    return far
+
+
+def _where(model):
+    return {
+        d for v in list(model.variables) + list(model.optimizer.variables)
+        for d in v.value.devices()
+    }
+
+
+def test_master_waits_on_the_host_during_fit_and_comes_back(far_host):
+    """While the runner holds the state the master's variables are host
+    arrays (the state is on the devices once, not twice); the call's
+    last write-back puts them where Keras puts any variable, and what
+    it leaves is bit-equal to a fit that parked nothing far away."""
+    import jax
+
+    x, y = _rows()
+    sm = _spark_model()
+    seen = []
+
+    @reads_model(False)
+    def look(epoch, loss):
+        seen.append(_where(sm.master_network))
+
+    history = sm._get_runner().run_epochs(
+        _partitions(x, y), 3, BATCH, callbacks=[look])
+    assert seen == [{far_host}] * 3
+    assert _where(sm.master_network) == {jax.devices()[0]}
+    assert "accuracy" in history and len(history["accuracy"]) == 3
+
+    twin = _spark_model()
+    twin_history = twin.fit((x, y), epochs=3, batch_size=BATCH)
+    assert history["loss"] == twin_history["loss"]
+    _assert_bit_equal(_state(sm.master_network), _state(twin.master_network))
+
+
+def test_callback_that_reads_the_master_finds_it_live_off_the_host(far_host):
+    """A sync ahead of a reading callback assigns the variables as it
+    always did: live weights, on the default device."""
+    x, y = _rows()
+    sm = _spark_model()
+    start = _state(sm.master_network)
+    seen = []
+
+    def read(epoch, loss):  # declares nothing: reads the model
+        seen.append((_where(sm.master_network),
+                     _differ(_state(sm.master_network), start)))
+
+    sm._get_runner().run_epochs(_partitions(x, y), 2, BATCH, callbacks=[read])
+    assert [live for _where_, live in seen] == [True, True]
+    assert all(far_host not in where for where, _live in seen)
+
+
+def test_final_write_back_frees_the_runners_copy(monkeypatch):
+    """The call's last write-back frees each device leaf once it is
+    read; a sync at an epoch boundary frees nothing (the next epoch
+    trains on)."""
+    import jax
+
+    kept = []
+    original = MeshRunner._write_back
+
+    def spy(self, tv, ntv, ov=None, **how):
+        out = original(self, tv, ntv, ov, **how)
+        leaves = [l for part in (tv, ntv, ov or ()) for l in part]
+        kept.append((how.get("release", False), [
+            l.is_deleted() for l in leaves if isinstance(l, jax.Array)]))
+        return out
+
+    monkeypatch.setattr(MeshRunner, "_write_back", spy)
+    x, y = _rows()
+    sm = _spark_model()
+    sm._get_runner().run_epochs(
+        _partitions(x, y), 2, BATCH, callbacks=[lambda epoch, loss: None])
+    assert [release for release, _ in kept] == [False, False, True]
+    assert not any(kept[0][1]) and not any(kept[1][1])
+    assert kept[2][1] and all(kept[2][1])

@@ -1,0 +1,511 @@
+"""The hybrid linear-attention MoE block (``qwen3_next_lm``) against the
+benchmark's plain reference, at a small size on the CPU: widths cut,
+structure whole (16 experts with 4 held, top-2, two key heads over four
+value heads, chunk 4, sequence 24)."""
+
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+CFG = {
+    "hidden_size": 32, "vocab_size": 64, "num_hidden_layers": 4,
+    "full_attention_interval": 4, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "head_dim": 16, "partial_rotary_factor": 0.25,
+    "rope_theta": 1e7, "rms_norm_eps": 1e-6, "linear_num_key_heads": 2,
+    "linear_num_value_heads": 4, "linear_key_head_dim": 8,
+    "linear_value_head_dim": 8, "linear_conv_kernel_dim": 4,
+    "num_experts": 16, "num_experts_per_tok": 2, "num_experts_held": 4,
+    "experts_held_first": 4, "moe_intermediate_size": 16,
+    "shared_expert_intermediate_size": 16, "sequence_length": 24,
+    "chunk_size": 4, "remat": True, "dtype": "float32",
+    "assumed": {"initializer_range": 0.2},
+    "optimizer": {"name": "sgd", "learning_rate": 0.05, "momentum": 0.9},
+}
+SEQ = CFG["sequence_length"]
+
+
+def _load(kind, name):
+    path = os.path.join(ROOT, "benchmarks", kind, name + ".py")
+    spec = importlib.util.spec_from_file_location(
+        "bench_" + kind + "_" + name.replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def ref():
+    return _load("reference", "qwen3-next-80b-a3b-ep16")
+
+
+@pytest.fixture(scope="module")
+def builder():
+    return _load("builders", "keras_qwen3_next")
+
+
+def _close(got, want, tol=2e-4):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    scale = max(np.abs(want).max(), 1e-6)
+    assert np.abs(got - want).max() <= tol * scale, (
+        np.abs(got - want).max(), scale)
+
+
+# -- the chunked scan against the token-by-token recurrence ---------------
+
+
+def _scan_inputs(s, heads=3, dk=8, dv=8, b=2, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 5)
+    q = jax.random.normal(ks[0], (b, s, heads, dk))
+    k = jax.random.normal(ks[1], (b, s, heads, dk))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * dk ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (b, s, heads, dv))
+    g = -jax.nn.softplus(jax.random.normal(ks[3], (b, s, heads)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, heads)))
+    return q, k, v, g, beta
+
+
+@pytest.mark.parametrize("s,chunk", [(22, 4), (24, 4), (7, 64), (33, 8)])
+def test_chunked_scan_forward(s, chunk):
+    from elephas_tpu.ops import gated_delta_rule, gated_delta_rule_recurrent
+
+    args = _scan_inputs(s)
+    want, want_state = gated_delta_rule_recurrent(*args)
+    got, got_state = gated_delta_rule(*args, chunk_size=chunk)
+    _close(got, want, 1e-5)
+    _close(got_state, want_state, 1e-5)
+
+
+@pytest.mark.parametrize("s,chunk", [(22, 4), (24, 4), (7, 64)])
+def test_chunked_scan_backward(s, chunk):
+    from elephas_tpu.ops import gated_delta_rule, gated_delta_rule_recurrent
+
+    args = _scan_inputs(s, seed=1)
+    loss = lambda f: lambda *a: jnp.sum(jnp.sin(f(*a)[0]))  # noqa: E731
+    want = jax.grad(loss(gated_delta_rule_recurrent), (0, 1, 2, 3, 4))(*args)
+    got = jax.grad(
+        loss(lambda *a: gated_delta_rule(*a, chunk_size=chunk)),
+        (0, 1, 2, 3, 4))(*args)
+    for g, w in zip(got, want):
+        _close(g, w, 1e-5)
+
+
+# -- grouped-query flash attention ------------------------------------------
+
+
+@pytest.mark.parametrize("heads,kv", [(4, 2), (8, 1), (4, 4)])
+def test_flash_attention_grouped_query(heads, kv):
+    from elephas_tpu.ops.flash_attention import (
+        attention_reference, flash_attention,
+    )
+
+    ks = jax.random.split(jax.random.key(3), 3)
+    q = jax.random.normal(ks[0], (2, heads, 32, 8))
+    k = jax.random.normal(ks[1], (2, kv, 32, 8))
+    v = jax.random.normal(ks[2], (2, kv, 32, 8))
+    ours = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, causal=True, block_q=8, block_k=8)
+    plain = lambda q, k, v: attention_reference(  # noqa: E731
+        q, jnp.repeat(k, heads // kv, 1), jnp.repeat(v, heads // kv, 1),
+        causal=True)
+    _close(ours(q, k, v), plain(q, k, v), 1e-5)
+    loss = lambda f: lambda *a: jnp.sum(jnp.sin(f(*a)))  # noqa: E731
+    got = jax.grad(loss(ours), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(plain), (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        _close(g, w, 1e-5)
+
+
+def test_flash_attention_refuses_uneven_groups():
+    from elephas_tpu.ops.flash_attention import flash_attention
+
+    q = jnp.zeros((1, 4, 8, 8))
+    with pytest.raises(ValueError, match="query heads"):
+        flash_attention(q, q[:, :3], q[:, :3])
+
+
+# -- each layer kind against the reference ----------------------------------
+
+
+def _layer_params(ref, prefix, seed=0):
+    params = ref.init_params(CFG, seed)
+    return {k: v for k, v in params.items() if k.startswith(prefix)}
+
+
+def _keras_layer(kind, remat=False):
+    from elephas_tpu.models import qwen3_next as zoo
+
+    if kind == "attn":
+        return zoo.GatedAttention(
+            CFG["num_attention_heads"], CFG["num_key_value_heads"],
+            CFG["head_dim"], 4, CFG["rope_theta"], remat=remat,
+            name="layer3_attn")
+    if kind == "gdn":
+        return zoo.GatedDeltaNet(
+            CFG["linear_num_key_heads"], CFG["linear_num_value_heads"],
+            CFG["linear_key_head_dim"], CFG["linear_value_head_dim"],
+            CFG["linear_conv_kernel_dim"], CFG["chunk_size"], remat=remat,
+            name="layer0_gdn")
+    return zoo.SparseMoeBlock(
+        CFG["num_experts"], CFG["num_experts_per_tok"],
+        CFG["moe_intermediate_size"], CFG["shared_expert_intermediate_size"],
+        (4, 8), remat=remat, name="layer0_moe")
+
+
+REF_FN = {"attn": "_attention", "gdn": "_gated_delta_net",
+          "moe": "_sparse_block"}
+PREFIX = {"attn": "layer3_attn/", "gdn": "layer0_gdn/", "moe": "layer0_moe/"}
+
+
+def _stateless(layer, params, x):
+    tv = [params[v.path] for v in layer.trainable_variables]
+    ntv = [v.value for v in layer.non_trainable_variables]
+    out, _ntv = layer.stateless_call(tv, ntv, x)
+    return out
+
+
+@pytest.mark.parametrize("remat", [False, True])
+@pytest.mark.parametrize("kind", ["attn", "gdn", "moe"])
+def test_layer_forward_and_gradients(ref, kind, remat):
+    layer = _keras_layer(kind, remat)
+    x = jax.random.normal(jax.random.key(5), (2, SEQ, CFG["hidden_size"]))
+    layer.build(x.shape)
+    params = _layer_params(ref, PREFIX[kind])
+    assert {v.path for v in layer.trainable_variables} == set(params)
+    plain = getattr(ref, REF_FN[kind])
+    mm = lambda a, w: jnp.matmul(a, w, precision=ref.HI)  # noqa: E731
+
+    def want_fn(p, x):
+        return plain(p, PREFIX[kind], x, CFG, lambda t: t, mm)
+
+    def got_fn(p, x):
+        return _stateless(layer, p, x)
+
+    _close(got_fn(params, x), want_fn(params, x))
+    loss = lambda f: lambda p, x: jnp.sum(jnp.sin(3.0 * f(p, x)))  # noqa: E731
+    got = jax.grad(loss(got_fn), (0, 1))(params, x)
+    want = jax.grad(loss(want_fn), (0, 1))(params, x)
+    _close(got[1], want[1])
+    for path in params:
+        _close(got[0][path], want[0][path], 5e-4)
+
+
+def test_norm_and_swiglu(ref):
+    from elephas_tpu.models import qwen3_next as zoo
+
+    x = jax.random.normal(jax.random.key(6), (2, 5, 32))
+    norm = zoo.ZeroCentredRMSNorm(name="n")
+    norm.build(x.shape)
+    w = jax.random.normal(jax.random.key(7), (32,)) * 0.1
+    got, _ = norm.stateless_call([w], [], x)
+    _close(got, ref._rms(x, 1e-6) * (1.0 + w), 1e-5)
+    mlp = zoo.SwiGLU(16, name="m")
+    mlp.build(x.shape)
+    gate_up = jax.random.normal(jax.random.key(8), (32, 32)) * 0.2
+    down = jax.random.normal(jax.random.key(9), (16, 32)) * 0.2
+    got, _ = mlp.stateless_call([gate_up, down], [], x)
+    gate, up = jnp.split(x @ gate_up, 2, axis=-1)
+    _close(got, (jax.nn.silu(gate) * up) @ down, 1e-5)
+
+
+# -- the share of a deployment ------------------------------------------------
+
+
+def test_shares_add_up_to_the_uncut_layer(ref):
+    """The routed parts of all four shares (4 of the 16 experts each),
+    with the shared expert counted once, add up to what the uncut
+    reference (all 16 held) gives for the layer."""
+    from elephas_tpu.models import qwen3_next as zoo
+
+    whole_cfg = dict(CFG, num_experts_held=16, experts_held_first=0)
+    params = {k: v for k, v in ref.init_params(whole_cfg, 3).items()
+              if k.startswith("layer0_moe/")}
+    x = jax.random.normal(jax.random.key(10), (2, SEQ, 32))
+    mm = lambda a, w: jnp.matmul(a, w, precision=ref.HI)  # noqa: E731
+    want = ref._sparse_block(params, "layer0_moe/", x, whole_cfg,
+                             lambda t: t, mm)
+    no_shared = dict(params)
+    no_shared["layer0_moe/shared_expert/down"] = jnp.zeros_like(
+        params["layer0_moe/shared_expert/down"])
+    total, routed_slots = 0.0, 0
+    for share in range(4):
+        first = 4 * share
+        layer = zoo.SparseMoeBlock(16, 2, 16, 16, (first, first + 4),
+                                   name="layer0_moe")
+        layer.build(x.shape)
+        mine = dict(params if share == 0 else no_shared)
+        for name in ("experts_gate_up", "experts_down"):
+            mine["layer0_moe/" + name] = params[
+                "layer0_moe/" + name][first:first + 4]
+        tv = [mine[v.path] for v in layer.trainable_variables]
+        out, ntv = layer.stateless_call(
+            tv, [v.value for v in layer.non_trainable_variables], x)
+        total = total + out
+        routed_slots += int(ntv[0][0])
+    _close(total, want)
+    assert routed_slots == 2 * SEQ * 2  # every slot is some share's
+
+
+def test_no_token_dropped_when_all_choose_one_held_expert(ref):
+    """A router that sends every token to held expert 5 (and its second
+    choice anywhere): 48 rows on one expert, four times the mean of a
+    uniform router over the four held, and still the reference's
+    result."""
+    layer = _keras_layer("moe")
+    x = jax.random.normal(jax.random.key(11), (2, SEQ, 32))
+    layer.build(x.shape)
+    params = _layer_params(ref, "layer0_moe/", seed=4)
+    # a constant input feature drives expert 5's score past all others
+    x = x.at[..., 0].set(1.0)
+    router = params["layer0_moe/router"].at[0, 5].set(60.0)
+    params = dict(params, **{"layer0_moe/router": router})
+    mm = lambda a, w: jnp.matmul(a, w, precision=ref.HI)  # noqa: E731
+    want = ref._sparse_block(params, "layer0_moe/", x, CFG, lambda t: t, mm)
+    tv = [params[v.path] for v in layer.trainable_variables]
+    got, ntv = layer.stateless_call(
+        tv, [v.value for v in layer.non_trainable_variables], x)
+    _close(got, want)
+    held_slots, slots, fullest = (int(v) for v in ntv[0])
+    assert slots == 2 * SEQ * 2 and fullest == 2 * SEQ
+    assert held_slots >= 2 * SEQ
+
+
+@pytest.mark.parametrize("held,bias", [((4, 8), 0.0), ((4, 8), 60.0),
+                                       ((0, 16), 0.0), ((12, 16), 0.0)])
+def test_held_experts_ffn_paths(held, bias):
+    """The one-buffer path, the blocked path (past twice the uniform
+    load) and the all-held path give the plain sum over held experts,
+    forward and backward."""
+    from elephas_tpu.ops import held_experts_ffn
+
+    t, d, e, width, k = 48, 16, 16, 8, 2
+    ks = jax.random.split(jax.random.key(12), 4)
+    x = jax.random.normal(ks[0], (t, d)).at[:, 0].set(1.0)
+    router = jax.random.normal(ks[1], (d, e)).at[0, 5].add(bias)
+    n = held[1] - held[0]
+    gate_up = jax.random.normal(ks[2], (n, d, 2 * width)) * 0.3
+    down = jax.random.normal(ks[3], (n, width, d)) * 0.3
+
+    def plain(x, router, gate_up, down):
+        p = jax.nn.softmax(x @ router, -1)
+        top, chosen = jax.lax.top_k(p, k)
+        top = top / top.sum(-1, keepdims=True)
+        y = 0.0
+        for i in range(n):
+            w = jnp.sum(jnp.where(chosen == held[0] + i, top, 0.0), -1)
+            gate, up = jnp.split(x @ gate_up[i], 2, -1)
+            y = y + w[:, None] * ((jax.nn.silu(gate) * up) @ down[i])
+        return y
+
+    args = (x, router, gate_up, down)
+    got, counts = held_experts_ffn(*args, held, k)
+    _close(got, plain(*args), 1e-5)
+    assert int(counts[1]) == t * k
+    if bias:
+        assert int(counts[0]) > 2 * t * k * n // e  # the blocked path ran
+    loss = lambda f: lambda *a: jnp.sum(jnp.sin(f(*a)))  # noqa: E731
+    got_g = jax.grad(loss(lambda *a: held_experts_ffn(*a, held, k)[0]),
+                     (0, 1, 2, 3))(*args)
+    want_g = jax.grad(loss(plain), (0, 1, 2, 3))(*args)
+    for g, w in zip(got_g, want_g):
+        _close(g, w, 1e-5)
+
+
+def test_sparse_block_refuses_a_range_outside_the_experts():
+    from elephas_tpu.models import qwen3_next as zoo
+
+    with pytest.raises(ValueError, match="experts_held"):
+        zoo.SparseMoeBlock(16, 2, 16, 16, (12, 20))
+    with pytest.raises(ValueError, match="experts a token"):
+        zoo.SparseMoeBlock(4, 8, 16, 16)
+
+
+# -- the whole model through SparkModel.fit -----------------------------------
+
+
+def _tokens(seed, rows=4):
+    rng = np.random.default_rng(seed)
+    tok = rng.integers(0, CFG["vocab_size"], size=(rows, SEQ + 1))
+    tok = tok.astype(np.int32)
+    return tok[:, :-1], tok[:, 1:]
+
+
+@pytest.fixture(scope="module")
+def fitted(ref, builder):
+    """Two SGD steps (one epoch of 4 sequences, 2 a step) through
+    ``SparkModel.fit`` from the reference's seeded weights."""
+    from elephas_tpu import SparkModel, telemetry
+    from elephas_tpu.data import SparkContext
+    from elephas_tpu.utils import rdd_utils
+
+    params = ref.init_params(CFG, 7)
+    model = builder.build(CFG, params)
+    x, y = _tokens(7)
+    rdd = rdd_utils.to_simple_rdd(SparkContext("local[1]"), x, y,
+                                  num_partitions=1)
+    since = telemetry.default_tracer().seq
+    history = SparkModel(model, mode="synchronous", num_workers=1).fit(
+        rdd, epochs=1, batch_size=2)
+    events = telemetry.default_tracer().events(since, name="fit.counters")
+    want = ref.follow(CFG, 7, [(x[:2], y[:2]), (x[2:], y[2:])])
+    return {"model": model, "history": history, "want": want,
+            "start": {k: np.asarray(v) for k, v in params.items()},
+            "events": events}
+
+
+def test_fit_step_loss_matches_reference(fitted):
+    got = fitted["history"]["loss"][0]
+    assert abs(got - np.mean(fitted["want"]["losses"])) < 2e-4 * got
+
+
+def test_fit_step_momenta_and_change_match_reference(fitted):
+    model, want = fitted["model"], fitted["want"]
+    norm = lambda a: float(np.sqrt(np.sum(np.square(  # noqa: E731
+        np.asarray(a, np.float64)))))
+    momenta = {v.path: np.asarray(v.value)
+               for v in model.optimizer.variables}
+    variables = {v.path: np.asarray(v.value) for v in model.variables}
+    floor = float(np.median(list(want["velocity_norm"].values())))
+    for path, ref_norm in want["velocity_norm"].items():
+        got = norm(momenta["SGD/" + path.replace("/", "_") + "_momentum"])
+        assert abs(got - ref_norm) <= 2e-3 * max(ref_norm, floor), path
+        got = norm(variables[path] - fitted["start"][path])
+        want_change = want["change_norm"][path]
+        assert abs(got - want_change) <= 2e-3 * max(
+            want_change, float(np.median(list(want["change_norm"].values())))
+        ), path
+
+
+def test_fit_emits_one_counters_event_an_epoch(fitted):
+    events = fitted["events"]
+    assert len(events) == 1 and events[0]["mono_ns"] is not None
+    layers = events[0]["args"]["layers"]
+    assert sorted(layers) == [f"layer{i}_moe" for i in range(4)]
+    for counts in layers.values():
+        assert counts["slots"] == 4 * SEQ * 2
+        assert 0 < counts["max_expert_tokens"] <= counts["held_slots"]
+        assert counts["held_slots"] <= counts["slots"]
+
+
+def test_fit_with_a_metric_never_runs_the_master_model_op_by_op(
+        ref, builder, monkeypatch):
+    """Compiled with a metric, and with the master parked on another
+    device than the worker's (as beside a chip): the metric's variables
+    are built from the model's output spec, so every forward pass is a
+    traced one, and the history carries the metric."""
+    import keras
+
+    from elephas_tpu import SparkModel
+    from elephas_tpu.models import qwen3_next as zoo
+
+    far = jax.devices()[-1]
+    original = jax.local_devices
+    monkeypatch.setattr(
+        jax, "local_devices",
+        lambda *a, backend=None, **k: [far] if backend == "cpu"
+        else original(*a, backend=backend, **k))
+    for kind in ("GatedAttention", "GatedDeltaNet", "SparseMoeBlock"):
+        cls = getattr(zoo, kind)
+        plain = cls._forward
+
+        def traced_only(self, x, plain=plain):
+            assert isinstance(x, jax.core.Tracer), "an eager forward pass"
+            return plain(self, x)
+
+        monkeypatch.setattr(cls, "_forward", traced_only)
+    model = builder.build(CFG, ref.init_params(CFG, 7))
+    model.compile(
+        optimizer=model.optimizer, loss=zoo.next_token_loss,
+        metrics=[keras.metrics.SparseCategoricalAccuracy(name="accuracy")])
+    x, y = _tokens(7)
+    history = SparkModel(model, mode="synchronous", num_workers=1).fit(
+        (x, y), epochs=2, batch_size=2)
+    assert len(history["accuracy"]) == 2
+    assert all(0.0 <= a <= 1.0 for a in history["accuracy"])
+    assert {d for v in model.variables for d in v.value.devices()} == {
+        jax.devices()[0]}
+
+
+def test_a_layer_names_its_own_counter_variable():
+    """The epoch runner knows no layer's variable by name: a layer's
+    ``epoch_counters`` maps the attribute that holds the variable to
+    its entries' names."""
+    import keras
+
+    from elephas_tpu import SparkModel, telemetry
+
+    class CountsRows(keras.layers.Layer):
+        epoch_counters = {"seen": ("rows", "calls")}
+
+        def build(self, input_shape):
+            self.seen = self.add_weight(
+                name="seen", shape=(2,), dtype="int32",
+                initializer="zeros", trainable=False)
+
+        def call(self, x):
+            self.seen.assign(
+                self.seen.value + jnp.array([x.shape[0], 1], jnp.int32))
+            return x
+
+    model = keras.Sequential([
+        keras.layers.Input((8,)), CountsRows(name="counting"),
+        keras.layers.Dense(2, activation="softmax")])
+    model.compile(optimizer="sgd", loss="sparse_categorical_crossentropy")
+    rng = np.random.default_rng(3)
+    x = rng.standard_normal((32, 8)).astype(np.float32)
+    y = (x.sum(axis=1) > 0).astype(np.int32)
+    since = telemetry.default_tracer().seq
+    SparkModel(model, mode="synchronous", num_workers=2).fit(
+        (x, y), epochs=2, batch_size=4)
+    events = telemetry.default_tracer().events(since, name="fit.counters")
+    assert [e["args"]["layers"] for e in events] == [
+        {"counting": {"rows": 32, "calls": 8}}] * 2
+
+
+def test_builder_assign_checks_paths_and_zeroes_counters(fitted, ref, builder):
+    model = fitted["model"]
+    params = ref.init_params(CFG, 8)
+    builder.assign(model, params)
+    for var in model.variables:
+        if var.path.endswith("/route_counts"):
+            assert not np.asarray(var.value).any()
+    with pytest.raises(ValueError, match="differ"):
+        builder.assign(model, {k: v for k, v in params.items()
+                               if "router" not in k})
+
+
+def test_reference_param_count_and_flops(builder, ref):
+    """The published widths: 625.7M parameters held here, about 1.4
+    GFLOP a token forward and backward."""
+    import json
+
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "qwen3-next-80b-a3b-ep16.json")) as f:
+        cfg = json.load(f)
+    shapes = ref.param_shapes(cfg)
+    count = sum(int(np.prod(shape)) for shape, _kind in shapes.values())
+    assert count == cfg["parameters"]
+    assert 620e6 < count < 630e6
+    traffic = {"sequence_length": 8192, "batch_size": 2}
+    per_token = builder.train_flops_per_example(cfg, traffic) / 8192
+    assert 1.2e9 < per_token < 1.6e9
+    scan = builder.gdn_scan_step_cost(cfg, traffic)
+    experts = builder.moe_experts_step_cost(cfg, traffic, 4 * 10240)
+    assert scan["flops"] > 0 and scan["bytes"] > 0
+    assert experts["flops"] == 3 * 2 * 3 * 2048 * 512 * 4 * 10240
+
+
+def test_control_one_precision_down_moves_the_loss(ref):
+    x, y = _tokens(9, rows=2)
+    sound = ref.follow(CFG, 9, [(x, y)])
+    lower = ref.follow(CFG, 9, [(x, y)], lower=True)
+    assert np.isfinite(lower["losses"][0])
+    gaps = [abs(lower["velocity_norm"][p] - n) / max(n, 1e-12)
+            for p, n in sound["velocity_norm"].items()]
+    assert max(gaps) > 1e-3

@@ -25,25 +25,28 @@ from elephas_tpu.ops.flash_attention import (
 from elephas_tpu.ops.layer_norm import layer_norm
 from elephas_tpu.ops.ring_attention import ring_attention_sharded
 
-try:
-    from jax.experimental import topologies
-
-    TOPO = topologies.get_topology_desc(
-        platform="tpu", topology_name="v5e:2x2"
-    )
-except Exception as e:  # noqa: BLE001 — no TPU compiler on this machine
-    TOPO, WHY = None, f"cannot describe a v5e:2x2 here: {e}"
-else:
-    WHY = ""
-
-pytestmark = pytest.mark.skipif(TOPO is None, reason=WHY)
+TOPO = None  # the described v5e:2x2, set by the fixture below
 
 
 @pytest.fixture(scope="module", autouse=True)
-def _no_persistent_cache():
-    """A compile for a described chip is written to JAX's persistent
+def _described_chip():
+    """Describes the chip once a module, inside a test's set-up and
+    never at import: only one process at a time may load the TPU's
+    library, and under several test workers every worker imports this
+    file. Where no v5e:2x2 can be described the file's tests skip.
+
+    A compile for a described chip is written to JAX's persistent
     cache but cannot be read back without the chip (the next run warns
     and compiles again), so the cache stays off around these."""
+    global TOPO
+    try:
+        from jax.experimental import topologies
+
+        TOPO = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no TPU compiler on this machine
+        pytest.skip(f"cannot describe a v5e:2x2 here: {e}")
     from jax.experimental.compilation_cache import compilation_cache
 
     was = jax.config.jax_enable_compilation_cache
@@ -212,3 +215,52 @@ def test_serving_decode_step_at_gpt2_small_width(meshed):
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes + memory.argument_size_in_bytes < 2**34
     engine.release_telemetry()
+
+
+# -- the hybrid MoE block's kernels at the published widths (ISSUE 27) ----
+
+
+def test_flash_forward_grouped_query_at_8192_positions():
+    """16 query heads over 2 key/value heads, head size 256, causal,
+    8192 positions: the key/value head is found through the index map."""
+    q = on_chip((2, 16, 8192, 256), jnp.bfloat16)
+    kv = on_chip((2, 2, 8192, 256), jnp.bfloat16)
+    assert kernels_in(
+        lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=512, block_k=512, interpret=False
+        ),
+        q, kv, kv,
+    ) == 1
+
+
+def test_grouped_matmul_over_held_experts_forward_and_backward():
+    """20480 slot rows over 32 held experts at hidden 2048 and twice
+    the expert width: the grouped product and both of its gradients
+    are Mosaic kernels."""
+    from elephas_tpu.ops.moe import grouped_matmul
+
+    rows = on_chip((20480, 2048), jnp.bfloat16)
+    experts = on_chip((32, 2048, 1024), jnp.bfloat16)
+    sizes = on_chip((32,), jnp.int32)
+
+    def loss(rows, experts, sizes):
+        out = grouped_matmul(rows, experts, sizes, kernel=True)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    assert kernels_in(jax.grad(loss, (0, 1)), rows, experts, sizes) >= 3
+
+
+def test_chunked_gated_delta_rule_fits_at_published_widths():
+    """32 value heads of 128 x 128 state over 2 x 8192 tokens in chunks
+    of 64, forward and backward: compiles, and under 16 GiB."""
+    from elephas_tpu.ops.gated_delta import gated_delta_rule
+
+    qk = on_chip((2, 8192, 32, 128), jnp.bfloat16)
+    gate = on_chip((2, 8192, 32), jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(gated_delta_rule(q, k, v, g, beta)[0].astype(
+            jnp.float32))
+
+    kernels_in(jax.grad(jax.checkpoint(loss), (0, 1, 2, 3, 4)),
+               qk, qk, qk, gate, gate)
