@@ -8,9 +8,15 @@ materializes the ``[S, S]`` score matrix in HBM, so memory is O(S·D)
 instead of O(S²).
 
 Backward uses the standard flash recurrences (dV = Pᵀ dO, dS = P∘(dP − Δ),
-…) evaluated blockwise under ``lax.scan`` — O(S·D) residuals (just
-q/k/v/out/LSE), XLA-fused. The whole op carries a ``jax.custom_vjp`` so it
-drops into any ``jax.grad`` training step.
+…) over O(S·D) residuals (just q/k/v/out/LSE). In the ``[BH, S, D]``
+layout they are two Pallas kernels in the forward's style (dK/dV, then
+dQ): score blocks stay in VMEM, operands reach the MXU in the inputs'
+dtype, causally empty block pairs are skipped, and a key/value head that
+several query heads share is read in place. The packed qkv layout still
+evaluates them blockwise under ``lax.scan``, XLA-fused
+(:func:`_flash_backward`, also the tests' oracle for the kernels). The
+whole op carries a ``jax.custom_vjp`` so it drops into any ``jax.grad``
+training step.
 
 On the ``cpu`` backend, and only there, the same kernel runs in Pallas
 interpreter mode (tests), keeping one code path; on every other backend
@@ -416,7 +422,7 @@ def packed_layout_supported(d: int, h: int) -> bool:
     return d % 128 == 0 or (d == 64 and h % 2 == 0)
 
 
-# -- blockwise backward (flash recurrences, XLA-fused) ------------------
+# -- blockwise backward (flash recurrences, XLA-fused): packed layout ----
 
 
 def _causal_mask(i, j, block_q, block_k):
@@ -519,6 +525,204 @@ def _flash_backward_packed(scale, causal, block_q, block_k, residuals, g):
     return (jnp.stack([dq, dk, dv], axis=2),)
 
 
+# -- backward kernels ([BH, S, D] layout) -------------------------------
+
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
+                    causal: bool, block_q: int, block_k: int):
+    """dK and dV of one key block of one key/value head, summed over the
+    query heads that share it (grid axis 2) and the query blocks (axis
+    3). Scores are held transposed, ``[BK, BQ]``: ``lse`` and ``delta``
+    then broadcast from the ``[1, BQ]`` rows they arrive as, and both
+    accumulating products contract the leading query axis of ``do`` and
+    ``q`` as they lie."""
+    j, g, i = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    last_g, last_i = pl.num_programs(2) - 1, pl.num_programs(3) - 1
+
+    @pl.when((g == 0) & (i == 0))
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def _accumulate():
+        q, do = q_ref[:], do_ref[:]  # [BQ, D]
+        k, v = k_ref[:], v_ref[:]  # [BK, D]
+        nt = (((1,), (1,)), ((), ()))
+        nn = (((1,), (0,)), ((), ()))
+        st = jax.lax.dot_general(
+            k, q, nt, preferred_element_type=jnp.float32) * scale
+        if causal:
+            keys = j * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 0)
+            queries = i * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 1)
+            st = jnp.where(keys <= queries, st, NEG_INF)
+        lse = lse_ref[:]  # [1, BQ]
+        # fully-masked rows carry lse == NEG_INF; exp(s - lse) would be 1
+        pt = jnp.where(lse <= NEG_INF * 0.5, 0.0, jnp.exp(st - lse))
+        dv_acc[:] += jax.lax.dot_general(
+            pt.astype(do.dtype), do, nn, preferred_element_type=jnp.float32)
+        dpt = jax.lax.dot_general(
+            v, do, nt, preferred_element_type=jnp.float32)
+        dst = pt * (dpt - delta_ref[:])
+        dk_acc[:] += jax.lax.dot_general(
+            dst.astype(q.dtype), q, nn, preferred_element_type=jnp.float32)
+
+    if causal:
+        # a query block wholly behind the key block sees none of it
+        pl.when(j * block_k < (i + 1) * block_q)(_accumulate)
+    else:
+        _accumulate()
+
+    @pl.when((g == last_g) & (i == last_i))
+    def _finalize():
+        dk_ref[:] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+        dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                   dq_ref, dq_acc, lse_col, delta_col, *, scale: float,
+                   causal: bool, block_q: int, block_k: int):
+    """dQ of one query block, summed over the key blocks (grid axis 2).
+    ``lse`` and ``delta`` arrive as ``[1, BQ]`` rows and are turned to
+    ``[BQ, 1]`` columns once a query block."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    last_j = pl.num_programs(2) - 1
+
+    @pl.when(j == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+        lse_col[:] = jnp.transpose(lse_ref[:])
+        delta_col[:] = jnp.transpose(delta_ref[:])
+
+    def _accumulate():
+        q, do = q_ref[:], do_ref[:]  # [BQ, D]
+        k, v = k_ref[:], v_ref[:]  # [BK, D]
+        nt = (((1,), (1,)), ((), ()))
+        s = jax.lax.dot_general(
+            q, k, nt, preferred_element_type=jnp.float32) * scale
+        if causal:
+            s = jnp.where(_causal_mask(i, j, block_q, block_k), s, NEG_INF)
+        lse = lse_col[:]  # [BQ, 1]
+        p = jnp.where(lse <= NEG_INF * 0.5, 0.0, jnp.exp(s - lse))
+        dp = jax.lax.dot_general(
+            do, v, nt, preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_col[:])
+        dq_acc[:] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    if causal:
+        pl.when(j * block_k < (i + 1) * block_q)(_accumulate)
+    else:
+        _accumulate()
+
+    @pl.when(j == last_j)
+    def _finalize():
+        dq_ref[:] = (dq_acc[:] * scale).astype(dq_ref.dtype)
+
+
+def _flash_backward_kernels(scale, causal, block_q, block_k, interpret,
+                            residuals, g):
+    """The flash recurrences of :func:`_flash_backward` as two Pallas
+    kernels. Operands reach the MXU in the dtype the inputs came in and
+    every product accumulates in float32; a block pair the causal mask
+    empties is skipped; a key/value head shared by ``group`` query
+    heads is read in place and its gradient summed in the kernel."""
+    q, k, v, out, lse = residuals
+    bh, s_q, d = q.shape
+    bkv, s_k, _ = k.shape
+    group = bh // bkv
+    block_q, block_k = _resolve_blocks(block_q, block_k, s_q, s_k)
+    nq, nk = s_q // block_q, s_k // block_k
+    f32 = jnp.float32
+    # Δ_i = rowsum(dO ∘ O)
+    delta = jnp.sum(g.astype(f32) * out.astype(f32), axis=-1)
+    lse, delta = lse[:, None, :], delta[:, None, :]  # [BH, 1, S] rows
+    params = dict(scale=scale, causal=causal, block_q=block_q,
+                  block_k=block_k)
+    concrete = all(type(t) is int for t in (bh, s_q, s_k, d))
+
+    def cost(products):
+        if not concrete:
+            return None
+        return pl.CostEstimate(
+            flops=2 * products * bh * s_q * s_k * d,
+            bytes_accessed=(3 * bh * s_q * d + 4 * bkv * s_k * d)
+            * q.dtype.itemsize,
+            transcendentals=bh * s_q * s_k,
+        )
+
+    # A skipped pair's copies are skipped too: the index map stays on the
+    # nearest block the mask leaves something of (the first query block
+    # that sees key block j, the last key block that query block i sees),
+    # and a block that does not change is not fetched again.
+    if causal:
+        first_i = lambda i, j: jnp.minimum(  # noqa: E731
+            jnp.maximum(i, j * block_k // block_q), nq - 1)
+        last_j = lambda i, j: jnp.minimum(  # noqa: E731
+            j, ((i + 1) * block_q - 1) // block_k)
+    else:
+        first_i = lambda i, j: i  # noqa: E731
+        last_j = lambda i, j: j  # noqa: E731
+
+    q_side = lambda b, j, g_, i: (b * group + g_, first_i(i, j), 0)  # noqa: E731
+    kv_side = lambda b, j, g_, i: (b, j, 0)  # noqa: E731
+    row = lambda b, j, g_, i: (b * group + g_, 0, first_i(i, j))  # noqa: E731
+    dk, dv = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, **params),
+        grid=(bkv, nk, group, nq),
+        in_specs=[
+            pl.BlockSpec((None, block_q, d), q_side),
+            pl.BlockSpec((None, block_k, d), kv_side),
+            pl.BlockSpec((None, block_k, d), kv_side),
+            pl.BlockSpec((None, block_q, d), q_side),
+            pl.BlockSpec((None, 1, block_q), row),
+            pl.BlockSpec((None, 1, block_q), row),
+        ],
+        out_specs=[
+            pl.BlockSpec((None, block_k, d), kv_side),
+            pl.BlockSpec((None, block_k, d), kv_side),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_k, d), f32),
+            pltpu.VMEM((block_k, d), f32),
+        ],
+        cost_estimate=cost(4),
+        interpret=interpret,
+    )(q, k, v, g, lse, delta)
+
+    q_side = lambda b, i, j: (b, i, 0)  # noqa: E731
+    kv_side = lambda b, i, j: (b // group, last_j(i, j), 0)  # noqa: E731
+    row = lambda b, i, j: (b, 0, i)  # noqa: E731
+    dq = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, **params),
+        grid=(bh, nq, nk),
+        in_specs=[
+            pl.BlockSpec((None, block_q, d), q_side),
+            pl.BlockSpec((None, block_k, d), kv_side),
+            pl.BlockSpec((None, block_k, d), kv_side),
+            pl.BlockSpec((None, block_q, d), q_side),
+            pl.BlockSpec((None, 1, block_q), row),
+            pl.BlockSpec((None, 1, block_q), row),
+        ],
+        out_specs=pl.BlockSpec((None, block_q, d), q_side),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((block_q, d), f32),
+            pltpu.VMEM((block_q, 1), f32),
+            pltpu.VMEM((block_q, 1), f32),
+        ],
+        cost_estimate=cost(3),
+        interpret=interpret,
+    )(q, k, v, g, lse, delta)
+    return dq, dk, dv
+
+
 # -- public op ---------------------------------------------------------
 
 
@@ -534,20 +738,9 @@ def _fwd_rule(q, k, v, scale, causal, block_q, block_k, interpret):
 
 
 def _bwd_rule(scale, causal, block_q, block_k, interpret, residuals, g):
-    q, k, v, out, lse = residuals
-    group = q.shape[0] // k.shape[0]
-    if group == 1:
-        return _flash_backward(scale, causal, block_q, block_k, residuals, g)
-    # grouped-query: each query head against its key/value head's copy,
-    # then the group's gradients summed onto the one head they share
-    dq, dk, dv = _flash_backward(
-        scale, causal, block_q, block_k,
-        (q, jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0),
-         out, lse), g,
+    return _flash_backward_kernels(
+        scale, causal, block_q, block_k, interpret, residuals, g
     )
-    shared = lambda t: t.astype(jnp.float32).reshape(  # noqa: E731
-        (k.shape[0], group) + k.shape[1:]).sum(axis=1).astype(k.dtype)
-    return dq, shared(dk), shared(dv)
 
 
 _flash_attention_bhsd.defvjp(_fwd_rule, _bwd_rule)

@@ -57,6 +57,96 @@ def test_flash_gradients_match(causal):
         )
 
 
+def _backward_cases():
+    """The backward kernels' shapes: key/value heads shared by 1, 2 and
+    8 query heads, causal or not, always ``block_q != block_k``; then
+    unequal sequence lengths, bfloat16 inputs, and rows the mask
+    leaves nothing of."""
+    cases = {}
+    for group in (1, 2, 8):
+        cases[f"g{group}-causal"] = dict(group=group, causal=True)
+        cases[f"g{group}-full"] = dict(group=group, causal=False)
+    cases["g2-full-sq32-sk48"] = dict(
+        group=2, causal=False, s_k=48, block_q=16, block_k=8)
+    # key blocks that no query sees: their gradient is zero
+    cases["g2-causal-sq16-sk48"] = dict(group=2, causal=True, s_q=16, s_k=48)
+    cases["g2-causal-bf16"] = dict(group=2, causal=True, dtype=jnp.bfloat16)
+    cases["g8-full-bf16"] = dict(group=8, causal=False, dtype=jnp.bfloat16)
+    cases["g2-causal-masked-rows"] = dict(
+        group=2, causal=True, masked_rows=True)
+    return cases
+
+
+@pytest.mark.parametrize("case", list(_backward_cases()))
+def test_flash_backward_kernels_match_the_blockwise_rule_and_autodiff(case):
+    """The two backward kernels against ``_flash_backward`` on the
+    repeated heads (the blockwise rule they replaced, still the packed
+    layout's) and against ``jax.grad`` of the naive oracle."""
+    from elephas_tpu.ops.flash_attention import (
+        NEG_INF,
+        _flash_attention_bhsd,
+        _flash_backward,
+        _flash_backward_kernels,
+        _flash_forward,
+    )
+
+    c = {"s_q": 32, "s_k": 32, "block_q": 8, "block_k": 16,
+         "dtype": jnp.float32, "masked_rows": False,
+         **_backward_cases()[case]}
+    group, causal, d, kv_heads = c["group"], c["causal"], 16, 2
+    scale = d ** -0.5
+    ks = jax.random.split(jax.random.key(7), 4)
+    q = jax.random.normal(ks[0], (kv_heads * group, c["s_q"], d), c["dtype"])
+    k = jax.random.normal(ks[1], (kv_heads, c["s_k"], d), c["dtype"])
+    v = jax.random.normal(ks[2], (kv_heads, c["s_k"], d), c["dtype"])
+    g = jax.random.normal(ks[3], q.shape, c["dtype"])
+    blocks = (c["block_q"], c["block_k"])
+
+    out, lse = _flash_forward(q, k, v, scale, causal, *blocks, True)
+    if c["masked_rows"]:
+        # a query block whose keys are all masked, as a ring-attention
+        # chunk ahead of its queries leaves it: lse == NEG_INF, out == 0
+        lse = lse.at[:, :c["block_q"]].set(NEG_INF)
+        out = out.at[:, :c["block_q"]].set(0)
+        got = _flash_backward_kernels(
+            scale, causal, *blocks, True, (q, k, v, out, lse), g)
+        assert not np.any(np.asarray(got[0][:, :c["block_q"]]))
+    else:
+        # through the public op's rule
+        _, vjp = jax.vjp(lambda q, k, v: _flash_attention_bhsd(
+            q, k, v, scale, causal, *blocks, True), q, k, v)
+        got = vjp(g)
+
+    f32 = lambda t: t.astype(jnp.float32)  # noqa: E731
+    repeated = lambda t: jnp.repeat(f32(t), group, axis=0)  # noqa: E731
+    shared = lambda t: t.reshape(  # noqa: E731
+        (kv_heads, group) + t.shape[1:]).sum(axis=1)
+    dq, dk, dv = _flash_backward(
+        scale, causal, *blocks,
+        (f32(q), repeated(k), repeated(v), f32(out), lse), f32(g))
+    wants = [("blockwise", (dq, shared(dk), shared(dv)))]
+    if not c["masked_rows"]:
+        _, vjp = jax.vjp(lambda q, k, v: attention_reference(
+            q, jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0),
+            causal=causal, scale=scale), f32(q), f32(k), f32(v))
+        wants.append(("autodiff", vjp(f32(g))))
+    # float32 inputs round nowhere. bfloat16 keeps 8 significant bits:
+    # the gradient's own rounding is up to 2^-9 of an element, and p and
+    # ds, rounded the same way as operands, add as much each to a sum of
+    # up to 32 terms: 2^-6 of the largest element holds all three
+    for name, want in wants:
+        for a, w, leaf in zip(got, want, "qkv"):
+            assert a.dtype == c["dtype"]
+            if c["dtype"] == jnp.float32:
+                np.testing.assert_allclose(
+                    np.asarray(a), np.asarray(w), atol=1e-5, rtol=1e-5,
+                    err_msg=f"{name} d{leaf}")
+            else:
+                worst = float(jnp.max(jnp.abs(f32(a) - w)))
+                assert worst <= 2.0 ** -6 * float(jnp.max(jnp.abs(w))), (
+                    name, leaf, worst)
+
+
 def test_flash_rejects_ragged_blocks():
     q, k, v = _qkv(bh=1, s=100, d=16)
     with pytest.raises(ValueError, match="multiples"):

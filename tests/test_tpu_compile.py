@@ -233,6 +233,28 @@ def test_flash_forward_grouped_query_at_8192_positions():
     ) == 1
 
 
+def test_flash_backward_grouped_query_at_8192_positions():
+    """The gradient of the same attention at blocks of 256: the forward
+    kernel, dK/dV and dQ. The key/value head is read in place on the
+    way back too: the program's temporaries stay under one float32
+    ``[32, 8192, 256]`` array, what a repeated copy of ``k`` or ``v``
+    alone would take (the output and the softmax statistics are
+    half of that)."""
+    q = on_chip((2, 16, 8192, 256), jnp.bfloat16)
+    kv = on_chip((2, 2, 8192, 256), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = flash_attention(
+            q, k, v, causal=True, block_q=256, block_k=256, interpret=False
+        )
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    repeated_f32 = 2 * 16 * 8192 * 256 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < repeated_f32
+
+
 def test_grouped_matmul_over_held_experts_forward_and_backward():
     """20480 slot rows over 32 held experts at hidden 2048 and twice
     the expert width: the grouped product and both of its gradients
