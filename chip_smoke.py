@@ -35,7 +35,7 @@ import numpy as np
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 # -- the widths main() runs at --------------------------------------------
-# ResNet-50 as bench.py's "full" preset builds it (the north-star cell).
+# ResNet-50 at the widths of the benchmark's resnet50-fit-staged cell.
 CONVNET = dict(image=224, classes=1000, batch=256, steps=2, epochs=2)
 # GPT-2-small, the shape models.transformer_lm's block implements.
 LM = dict(vocab_size=50257, maxlen=1024, d_model=768, num_heads=12,

@@ -37,8 +37,8 @@ Weight generations are stamped end-to-end: PS ``status()`` and
 journals, engine ``stats()``/``debug_snapshot()``/flight-recorder
 traces, the ``elephas_serving_weight_version`` gauge every scrape and
 fleet view carries, the migration wire header (``weight_ver``, v3 —
-mismatched non-zero generations refuse loudly), ``/healthz``, and
-``bench.py --preset deploy`` gates the whole story.
+mismatched non-zero generations refuse loudly) and ``/healthz``;
+``tests/test_deploy.py`` holds the whole story to its counts.
 """
 
 from elephas_tpu.deploy.rollout import CanaryController  # noqa: F401

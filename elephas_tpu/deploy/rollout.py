@@ -20,7 +20,7 @@ evaluations with no active ``slo_burn``" — the same logical-clock
 stance every control path in this repo takes (a 1-CPU CI box must
 reach the same verdict as a fast workstation). The caller owns the
 evaluation cadence (the fault harness's ``WatchdogPoller``, a gateway
-``/healthz`` probe loop, or a bench loop driving it directly).
+``/healthz`` probe loop, or a test driving it directly).
 
 Division of labor during a canary:
 

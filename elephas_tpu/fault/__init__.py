@@ -4,8 +4,8 @@
 :class:`FaultPlan` objects describing PS crashes, wire faults,
 duplicated update frames, and worker-partition loss. ``harness`` is
 the executable side — :class:`RestartablePS`, :class:`PSKiller`, and
-:func:`run_chaos_training` drive real servers/workers under a plan,
-shared by the chaos test suite and ``bench.py --preset faults``.
+:func:`run_chaos_training` drive real servers/workers under a plan
+for the chaos test suite.
 
 The production fault-tolerance machinery itself lives where the
 failures happen: journaled restartable servers in
@@ -31,8 +31,6 @@ from elephas_tpu.fault.harness import (  # noqa: F401
     RestartablePS,
     ShardKiller,
     ShardedRestartablePS,
-    measure_faults,
-    measure_sharded_faults,
     run_chaos_training,
     run_elastic_membership,
     run_sharded_chaos_training,

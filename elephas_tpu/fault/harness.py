@@ -5,10 +5,8 @@ FaultPlan`: a :class:`RestartablePS` that can crash-and-recover a live
 parameter server on its original port (journal replay), a
 :class:`PSKiller` that triggers the crash mid-training and measures
 recovery from real server counters, and :func:`run_chaos_training`,
-which drives a real ``AsynchronousSparkWorker`` against all of it —
-shared by ``tests/test_fault_tolerance.py`` and ``bench.py --preset
-faults`` so the tested faults and the benchmarked faults are the same
-code path.
+which drives a real ``AsynchronousSparkWorker`` against all of it
+(``tests/test_fault_tolerance.py`` is its caller).
 
 Everything here is deterministic given ``(plan.seed, data seed)`` up to
 scheduler timing: the data, the model init, the duplicate schedule, and
@@ -23,7 +21,6 @@ from __future__ import annotations
 import itertools
 import logging
 import os
-import tempfile
 import threading
 import time
 
@@ -67,10 +64,10 @@ def recovery_windows_from_trace(
     trace stream — the ``chaos.recovery`` spans :class:`PSKiller` /
     :class:`ShardKiller` record, filtered to those that actually
     observed recovery. With ``shard`` set, only that shard's spans
-    (the ``shard`` arg the sharded killer stamps) are returned — how
-    ``bench.py --preset faults --faults-shards N`` reports per-shard
-    windows (ISSUE 5/6: the bench reads the same stream an operator's
-    trace viewer shows, not bespoke harness counters)."""
+    (the ``shard`` arg the sharded killer stamps) are returned: how
+    ``run_sharded_chaos_training`` reports per-shard windows (ISSUE
+    5/6: from the same stream an operator's trace viewer shows, not
+    bespoke harness counters)."""
     tracer = tracer or telemetry.tracer()
     return [
         float(e["dur"])
@@ -220,9 +217,9 @@ class PSKiller(threading.Thread):
         if not self._wait_for_updates(self.baseline + self.after_updates):
             return
         # the kill→first-post-restart-apply window is ONE span on the
-        # shared trace timeline (ISSUE 5): the bench and tests read the
-        # recovery number from the same stream an operator's trace
-        # viewer shows. `recovered` is stamped on the span so a
+        # shared trace timeline (ISSUE 5): the tests read the recovery
+        # number from the same stream an operator's trace viewer
+        # shows. `recovered` is stamped on the span so a
         # cancelled run never masquerades as a measured recovery.
         with telemetry.trace_span(
             "chaos.recovery", port=self.ps.port,
@@ -287,7 +284,7 @@ class WatchdogPoller:
     the duration of a chaos run — the end-to-end wiring the ISSUE-13
     acceptance asks for (shard kill ⇒ anomaly with the right label ⇒
     clear on recovery), shared by ``run_sharded_chaos_training`` and
-    the tests so the tested detection is the benchmarked detection."""
+    the tests."""
 
     def __init__(self, watchdog, interval_s: float = 0.05):
         self.watchdog = watchdog
@@ -730,9 +727,8 @@ def run_sharded_chaos_training(
     watch: bool = False,
 ) -> dict:
     """One real async-worker run against a SHARDED restartable PS —
-    the multi-shard sibling of :func:`run_chaos_training`, shared by
-    ``tests/test_ps_sharding.py`` and ``bench.py --preset faults
-    --faults-shards N``.
+    the multi-shard sibling of :func:`run_chaos_training`
+    (``tests/test_ps_sharding.py`` is its caller).
 
     Under a plan with ``kill_ps_after_updates``, shard
     ``plan.kill_shard`` is crash-killed mid-run and recovers from its
@@ -1041,7 +1037,7 @@ def run_elastic_membership(
 
 def _chaos_data(seed: int, rows: int, d: int = 16, k: int = 3):
     """Seeded separable blobs (the conftest recipe, self-contained so
-    bench runs outside pytest)."""
+    the harness runs outside pytest)."""
     rng = np.random.default_rng(seed)
     centers = rng.normal(size=(k, d)) * 2.0
     y = rng.integers(0, k, size=rows)
@@ -1195,9 +1191,8 @@ def run_chaos_training(
         "trace_id": trace_id,
         "dt_s": dt,
         "samples_per_s": rows * epochs / dt,
-        # kill→recovery read from the trace stream (ISSUE 5): the
-        # number the bench reports, sourced from the same events an
-        # operator's trace viewer shows
+        # kill→recovery read from the trace stream (ISSUE 5): sourced
+        # from the same events an operator's trace viewer shows
         "recovery_s_trace": trace_windows[-1] if trace_windows else None,
         "updates_applied": counters["updates_applied"] - baseline_updates,
         "duplicates_skipped": counters["updates_duplicate"],
@@ -1216,104 +1211,3 @@ def run_chaos_training(
         "data": (x, y),
     }
 
-
-def measure_faults(
-    transport: str = "socket",
-    rows: int = 256,
-    epochs: int = 2,
-    batch_size: int = 64,
-    seed: int = 0,
-    kill_after_updates: int | None = None,
-    restart_delay_s: float = 0.75,
-    duplicate_fraction: float = 0.25,
-    trace_export: str | None = None,
-):
-    """``bench.py --preset faults`` backend: one fault-free run and one
-    chaos run (PS kill+restart mid-epoch, a seeded fraction of update
-    frames duplicated on the wire, periodic wire delays) on the same
-    seeded data/model. Returns ``(clean, faulted, plan)`` — the caller
-    owns the JSON contract and the credibility gate."""
-    from elephas_tpu.fault.plan import SocketFaults
-
-    clean = run_chaos_training(
-        transport, rows=rows, epochs=epochs, batch_size=batch_size,
-        seed=seed, plan=None,
-    )
-    if kill_after_updates is None:
-        # land the kill mid-epoch, around a third into the sync stream
-        periods = max(1, -(-rows // batch_size)) * epochs
-        kill_after_updates = max(2, periods // 3)
-    plan = FaultPlan(
-        seed=seed,
-        kill_ps_after_updates=kill_after_updates,
-        restart_delay_s=restart_delay_s,
-        duplicate_fraction=duplicate_fraction,
-        socket_faults=SocketFaults(delay_every=13, delay_ms=4.0),
-    )
-    with tempfile.TemporaryDirectory(prefix="elephas-faults-") as jdir:
-        faulted = run_chaos_training(
-            transport,
-            rows=rows,
-            epochs=epochs,
-            batch_size=batch_size,
-            seed=seed,
-            plan=plan,
-            journal_dir=jdir,
-            trace_export=trace_export,
-        )
-    return clean, faulted, plan
-
-
-def measure_sharded_faults(
-    transport: str = "socket",
-    num_shards: int = 2,
-    rows: int = 256,
-    epochs: int = 2,
-    batch_size: int = 64,
-    seed: int = 0,
-    kill_after_updates: int | None = None,
-    restart_delay_s: float = 0.75,
-    duplicate_fraction: float = 0.25,
-    kill_shard: int = 0,
-    standby: bool = False,
-    trace_export: str | None = None,
-):
-    """``bench.py --preset faults --faults-shards N`` backend (ISSUE
-    6): one fault-free SHARDED run and one chaos run on the same
-    seeded data/model, where only shard ``kill_shard`` is crash-killed
-    mid-run (plus a seeded fraction of duplicated update frames on
-    every shard) and recovers from its own journal. Returns
-    ``(clean, faulted, plan)``; the caller owns the JSON contract and
-    the credibility gates (per-shard trace-vs-counters agreement,
-    surviving-shard progress, exactly-once totals)."""
-    clean = run_sharded_chaos_training(
-        transport, num_shards=num_shards, rows=rows, epochs=epochs,
-        batch_size=batch_size, seed=seed, plan=None,
-    )
-    if kill_after_updates is None:
-        # land the kill mid-epoch, around a third into the sync stream
-        # (every sync period touches every shard, so per-shard applied
-        # counts track the period count)
-        periods = max(1, -(-rows // batch_size)) * epochs
-        kill_after_updates = max(2, periods // 3)
-    plan = FaultPlan(
-        seed=seed,
-        kill_ps_after_updates=kill_after_updates,
-        restart_delay_s=restart_delay_s,
-        duplicate_fraction=duplicate_fraction,
-        kill_shard=kill_shard,
-    )
-    with tempfile.TemporaryDirectory(prefix="elephas-shard-faults-") as jdir:
-        faulted = run_sharded_chaos_training(
-            transport,
-            num_shards=num_shards,
-            rows=rows,
-            epochs=epochs,
-            batch_size=batch_size,
-            seed=seed,
-            plan=plan,
-            journal_dir=jdir,
-            standby=standby,
-            trace_export=trace_export,
-        )
-    return clean, faulted, plan

@@ -2,9 +2,8 @@
 
 A :class:`FaultPlan` is pure data + seeded decision functions — it
 holds *what goes wrong and when*, never any injection machinery, so the
-same plan object drives a unit test, the chaos suite, and
-``bench.py --preset faults`` and reproduces the identical fault
-schedule from the same seed. Decisions are pure functions of
+same plan object drives a unit test and the chaos suite and
+reproduces the identical fault schedule from the same seed. Decisions are pure functions of
 ``(seed, event key)`` — independent of call order, so two runs that
 push the same sequence IDs see the same duplicates even if unrelated
 ops interleave differently.

@@ -236,7 +236,7 @@ class Router:
     weights (the migration/re-drive bit-exactness contract rides on
     it). ``placement`` selects the strategy: ``"affinity"`` (default —
     the full two-stage algorithm), ``"load"`` (skip the prefix
-    probes), or ``"round_robin"`` (the bench's control arm).
+    probes), or ``"round_robin"`` (the control for the other two).
     ``poll_every`` sets how many placements ride one fleet-view poll
     (the view is ranking information — a few placements of staleness
     cost balance, never correctness). ``port`` arms the HTTP front
@@ -1029,7 +1029,7 @@ class Router:
     @property
     def tokens_delivered(self) -> int:
         """Plain host-truth delivered-token count (control-flow safe:
-        the chaos trigger and the bench cross-check read this; the
+        the chaos trigger and the tests' cross-check read this; the
         registry counter is its report-only twin)."""
         return self._tokens_delivered
 

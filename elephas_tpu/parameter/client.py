@@ -27,7 +27,7 @@ could double-apply. ``heartbeat()`` refreshes this worker's lease and
 ``status()`` fetches the server's membership/counters JSON.
 
 ``bytes_sent`` / ``bytes_received`` count payload bytes on the wire so
-callers (``bench.py --preset ps``) can report bytes-per-sync honestly.
+callers can report bytes-per-sync honestly.
 """
 
 from __future__ import annotations
@@ -120,7 +120,7 @@ class BaseParameterClient:
 
         # -- telemetry (ISSUE 5): wire counters live in the registry;
         # the same-named attributes below are read-back views, so the
-        # bench's bytes-per-sync and a Prometheus scrape can never
+        # caller's bytes-per-sync and a Prometheus scrape can never
         # disagree. Labeled by a process-monotonic instance id, not
         # client_id (which embeds a uuid — scrapes should be stable
         # across identically-driven gang processes).

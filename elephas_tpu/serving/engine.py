@@ -2935,7 +2935,7 @@ AdmissionRejected` at submit), policy-derived preemption priority, and
         "total_logprob", "greedy_tokens": [argmax token per position],
         "agreement": fraction of completion tokens matching greedy}``
         — greedy tokens make this the fp-oracle token-agreement probe
-        the quant bench gates consume (temperature-0 caveat: agreement
+        for a quantized pool (temperature-0 caveat: agreement
         compares argmax, so it is exactly what greedy decode would
         emit position-by-position given this prefix).
 
@@ -3500,8 +3500,8 @@ AdmissionRejected` at submit), policy-derived preemption priority, and
     def release_telemetry(self) -> None:
         """Retire this engine's labeled series — its own, its
         scheduler's, and its prefix cache's — from the process
-        registry. Hosts that construct engines in a loop (the bench's
-        alternating rounds, per-request test engines) call this when an
+        registry. Hosts that construct engines in a loop (per-request
+        test engines) call this when an
         engine is done so scrape output doesn't accumulate dead
         incarnations; never called implicitly, because scraping a
         finished engine's counters is a supported shape. Object-held
@@ -3611,7 +3611,7 @@ AdmissionRejected` at submit), policy-derived preemption priority, and
         }
 
     def stats(self) -> dict:
-        """Serving counters for the bench: aggregate generated tokens,
+        """Serving counters: aggregate generated tokens,
         decode steps, mean slot occupancy, per-request whole-request
         latencies, TTFT (submit→first token) and inter-token arrival
         percentiles of finished requests (ISSUE 4 — the chunked-prefill

@@ -349,11 +349,13 @@ class Gateway:
         loop = asyncio.get_running_loop()
         try:
             if self._server is not None:
-                self._server.close()
-                await self._server.wait_closed()
+                self._server.close()  # stop accepting; the port is free
             # sever live SSE connections — the zombie keep-alive bug
             # class (PR 3, parameter servers): a handler mid-stream
-            # must not outlive the gateway
+            # must not outlive the gateway. This comes BEFORE
+            # wait_closed(): since Python 3.12 that call waits for
+            # every accepted connection to drop, so awaiting it first
+            # parks this task behind the very streams it is here to cut
             for w in list(self._writers):
                 try:
                     w.close()
@@ -365,6 +367,8 @@ class Gateway:
                 await asyncio.gather(
                     *list(self._tasks), return_exceptions=True
                 )
+            if self._server is not None:
+                await self._server.wait_closed()
         finally:
             done.set()
             loop.stop()
@@ -729,8 +733,8 @@ class Gateway:
         ``{"prompt": [tokens], "completion": [tokens]}``, response
         carries per-token logprobs, their sum, the greedy (argmax)
         token at each position, and the completion-vs-greedy agreement
-        fraction. This is the quality oracle the quant bench gates
-        consume: score the same completion on an fp and a quantized
+        fraction. This is the quality oracle of a quantized pool:
+        score the same completion on an fp and a quantized
         engine and compare. Scoring never perturbs in-flight serving
         state (the engine forward is discard-after-read), but it DOES
         take the engine lock for its forward, like any submit."""

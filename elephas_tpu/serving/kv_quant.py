@@ -84,8 +84,8 @@ def packed_head_dim(head_dim: int, kv_dtype: str) -> int:
 
 def pool_bytes_per_pos(specs, kv_dtype: str) -> int:
     """Bytes one resident position costs across all layers (K and V):
-    the honest per-device KV price the bench's equal-bytes concurrency
-    gate divides by. ``specs`` is ``[(name, heads, head_dim), ...]``."""
+    the honest per-device KV price an equal-bytes comparison of
+    admitted concurrency divides by. ``specs`` is ``[(name, heads, head_dim), ...]``."""
     if kv_dtype == "fp":
         return sum(h * d for _, h, d in specs) * 2 * 4
     # quantized: 1 byte per stored value + one f32 scale per head

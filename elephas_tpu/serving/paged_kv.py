@@ -152,7 +152,7 @@ SlotKVCache`: host-side metadata only, the arrays are functional state
         """Host-side size of the full block pool at its STORED dtype
         — f32 values for ``kv_dtype="fp"``, int8/int4-packed codes
         plus per-(position, head) f32 scales when quantized. This is
-        the per-device KV price the equal-bytes bench gate divides
+        the per-device KV price an equal-bytes comparison divides
         by."""
         from elephas_tpu.serving.kv_quant import pool_bytes_per_pos
 

@@ -1551,8 +1551,8 @@ class PPEngine:
         dispatch — decode window or standalone prefill — schedules
         ``stage_ticks`` stage-ticks of which ``useful`` carried wave
         work; ``stats()['bubble_cumulative']`` is the lifetime idle
-        fraction, the number the bubble-fill bench compares across
-        arms (a filled arm simply never schedules the standalone
+        fraction, the number to compare across a filled and an unfilled
+        arm (a filled arm simply never schedules the standalone
         prefill dispatch's mostly-idle ticks)."""
         self._ticks_sched += int(stage_ticks)
         self._ticks_useful += int(useful)

@@ -1,8 +1,8 @@
 """Speculative decoding drafters + acceptance governance (ISSUE 8).
 
 Plain continuous-batching decode advances every slot ONE token per
-target-model forward — the serving bench's 1.5-2.6x over sequential is
-batching and paging, not per-token speed. Draft-and-verify speculative
+target-model forward: what it gains over sequential calls is batching
+and paging, not per-token speed. Draft-and-verify speculative
 decoding (Leviathan et al. 2023) recovers several tokens per forward:
 a cheap **drafter** proposes up to K continuation tokens per slot, the
 engine scores all of them in ONE batched verify forward (the chunk

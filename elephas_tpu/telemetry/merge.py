@@ -283,7 +283,7 @@ def _default_label(path: str, index: int) -> str:
 
 
 def spans(doc: dict, name: str) -> list[dict]:
-    """Convenience for consumers (bench cross-checks, tests): the
+    """Convenience for consumers (tests): the
     merged document's complete-span events with ``name``."""
     return [
         e for e in doc.get("traceEvents", [])

@@ -501,7 +501,7 @@ def registry():
     """The process registry — the real one, or the no-op null registry
     when :func:`set_null` turned telemetry off. Components capture this
     at construction, so flipping null mode affects components built
-    AFTER the flip (the bench's on-vs-null comparison shape)."""
+    AFTER the flip (an engine built on and one built null coexist)."""
     return _null_registry if _null else _default_registry
 
 

@@ -20,7 +20,7 @@ a production engine:
   harness and tests read them — that is the point.)
 - **Off the per-step hot path.** Rules are evaluated when *you* call
   :meth:`evaluate` — the gateway does so at ``/healthz`` probe
-  cadence, the bench at scrape cadence — never per decode step or per
+  cadence, the chaos harness at its poller's — never per decode step or per
   token. Evaluation is pure host reads of counter/gauge values.
 - **Null mode inert.** The watchdog captures the registry and tracer
   at construction: built under null mode it sees an empty series

@@ -408,7 +408,7 @@ class MeshRunner(KerasIntrospection):
             if d.process_index == pid
         ]
 
-    def _device_state(self, stacked: bool = True, park_master: bool = False):
+    def _device_state(self, park_master: bool = False):
         """Current model state, replicated to ``[W, ...]`` worker shards.
 
         Multi-host: each process materializes only its addressable

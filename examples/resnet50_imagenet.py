@@ -4,8 +4,8 @@ ImageNet-shaped synthetic data (zero-egress environment) through the full
 ``SparkModel.fit`` path: per-step in-XLA ``pmean`` gradient allreduce over
 the worker mesh, mixed-bfloat16 compute on the MXU. On a pod slice, run
 one process per host after ``jax.distributed.initialize`` and the same
-script scales over all chips. ``bench.py`` measures this config's
-steady-state throughput.
+script scales over all chips. ``python3 benchmarks/run.py --workload
+resnet50-fit-staged`` measures this configuration on the chip.
 """
 
 import argparse

@@ -125,3 +125,25 @@ def make_mlp(input_dim: int, num_classes: int, lr: float = 1e-2, seed: int = 7):
         metrics=["accuracy"],
     )
     return model
+
+
+def peak_admitted(scheduler, workload) -> int:
+    """Most requests in flight at once when ``workload`` (``(prompt,
+    max_new_tokens)`` pairs, all submitted up front) runs through
+    ``scheduler``'s own admission, each active request emitting one
+    token a step as in the engine's loop. Host bookkeeping only: what
+    the pool admits is a count, whatever a device would take per step."""
+    for prompt, max_new in workload:
+        scheduler.submit(scheduler.make_request(prompt, max_new))
+    peak = 0
+    while scheduler.has_work:
+        if scheduler.allocator is None:
+            admitted = scheduler.admit()
+        else:
+            admitted, _ = scheduler.admit_paged()
+        assert admitted or scheduler.active, "queue head can never fit"
+        peak = max(peak, len(scheduler.active))
+        for slot in sorted(scheduler.active):
+            if scheduler.on_token(slot, 0):
+                scheduler.reclaim(slot)
+    return peak

@@ -328,14 +328,53 @@ class TestWeightSubscriber:
         }
 
 
+    def test_generations_pushed_between_requests_keep_the_streams(self):
+        """A live deployment tears nothing: the same closed-loop drive
+        runs twice over one engine, the second time with the ledger
+        publishing a new generation (same content, new number) at
+        three points between requests. Every generation applies
+        exactly once with no skip, the engine ends on the ledger's
+        number, and every stream equals its steady-state twin."""
+        from elephas_tpu.serving import InferenceEngine
+
+        model = _lm(seed=1)
+        engine = InferenceEngine(model, num_slots=2)
+        store = _store(model.get_weights())
+        ledger = VersionLedger(store)
+        sub = WeightSubscriber(engine, store)
+        content = [np.asarray(w).copy() for w in model.get_weights()]
+        rng = np.random.default_rng(51)
+        prompts = [
+            rng.integers(1, VOCAB, size=6).tolist() for _ in range(8)
+        ]
+        push_at = {2, 4, 6}
+
+        def drive(pushing):
+            streams = []
+            for i, p in enumerate(prompts):
+                if pushing and i in push_at:
+                    version = ledger.publish([w.copy() for w in content])
+                    assert sub.poll_once() == version
+                out = engine.run([(p, 8)])
+                streams.append(list(out.values())[0].tolist())
+            return streams
+
+        steady = drive(pushing=False)
+        assert drive(pushing=True) == steady
+        assert sub.applies == len(push_at)
+        assert not any(sub.skips.values())
+        assert engine.weight_version == ledger.version == len(push_at)
+        engine.release_telemetry()
+
+
 # -- canary rollout ------------------------------------------------------
 
 
 class _ScriptedWatchdog:
-    """Watchdog stand-in the controller can read deterministically —
-    the real ``slo_burn``-under-traffic path runs in
-    ``bench.py --preset deploy`` (and the rule itself is pinned by
-    ``test_telemetry_fleet``); here the state machine is the subject."""
+    """Watchdog stand-in the controller can read deterministically:
+    here the state machine is the subject. The real ``slo_burn``
+    under traffic is ``test_deadline_misses_burn_and_roll_back``
+    below, and the rule itself is pinned by ``test_telemetry_fleet``."""
 
     def __init__(self):
         self.burning = False
@@ -350,17 +389,17 @@ class _ScriptedWatchdog:
         return {"active": active}
 
 
-def _fleet(tmp_models=None):
+def _fleet(tmp_models=None, poll_every=50, **engine_kw):
     from elephas_tpu.fleet import Router
 
     models = tmp_models or [_lm(seed=1), _lm(seed=1)]
     engines = {
-        "stable": make_engine(models[0]),
-        "canary": make_engine(models[1]),
+        "stable": make_engine(models[0], **engine_kw),
+        "canary": make_engine(models[1], **engine_kw),
     }
     store = _store(models[0].get_weights())
     ledger = VersionLedger(store)
-    router = Router(engines, poll_every=50)
+    router = Router(engines, poll_every=poll_every)
     subs = {
         name: WeightSubscriber(eng, store)
         for name, eng in engines.items()
@@ -430,6 +469,63 @@ class TestCanaryController:
             assert router.canary_status()["share"] == 0.0
             with pytest.raises(RuntimeError, match="roll back"):
                 ctrl.rollback()
+
+    def test_deadline_misses_burn_and_roll_back(self):
+        """The whole loop on real counters: the 0.5 split places
+        traffic on the canary, met deadlines leave the cycle open,
+        then first-token deadlines no engine can meet (a microsecond)
+        burn the SLO on the fleet scraper's view and the controller's
+        own watchdog rolls the cycle back. Exactly one anomaly fires
+        and one clears, every replica lands on the rollback
+        generation, and the split is gone."""
+        from elephas_tpu.serving.policy import FairSharePolicy
+
+        engines, store, ledger, router, subs = _fleet(
+            poll_every=4, policy=FairSharePolicy(),
+        )
+        content = [w.copy() for w in store.get_parameters()]
+        rng = np.random.default_rng(53)
+
+        def burst(deadline_ms):
+            reqs = [
+                router.submit(
+                    rng.integers(1, VOCAB, size=6).tolist(), 4,
+                    ttft_deadline_ms=deadline_ms,
+                )
+                for _ in range(6)
+            ]
+            assert all(r.wait(120) for r in reqs)
+            router.scraper.poll()
+
+        with router:
+            ctrl = CanaryController(
+                router, ledger, subs, canary=["canary"], share=0.5,
+                window=4,
+            )
+            router.scraper.poll()
+            ctrl.watchdog.evaluate()  # the rule's delta baseline
+            candidate = ctrl.begin([w.copy() for w in content])
+            burst(60_000.0)
+            assert router.canary_status()["placements_seen"] >= 1
+            assert ctrl.evaluate() == "canary"  # met: no hair trigger
+            router.set_canary(["canary"], 1.0)
+            burst(0.001)
+            assert ctrl.evaluate() == "idle"
+            assert ctrl.last_outcome == "rolled_back"
+            router.scraper.poll()
+            ctrl.watchdog.evaluate()  # a quiet window clears it
+            report = ctrl.watchdog.report()
+            assert report["fired_total"] == 1
+            assert report["cleared_total"] == 1
+            assert ledger.version > candidate  # a forward generation
+            for sub in subs.values():
+                assert sub.applied_version == ledger.version
+            assert router.canary_status()["share"] == 0.0
+        ctrl.release_telemetry()
+        ctrl.watchdog.release_telemetry()
+        router.release_telemetry()
+        for part in (*subs.values(), ledger, *engines.values()):
+            part.release_telemetry()
 
     def test_constructor_validates_loudly(self):
         engines, store, ledger, router, subs = _fleet()

@@ -1,6 +1,6 @@
 """The example scripts run end-to-end (reference keeps runnable examples;
 SURVEY.md §2 'Examples'). Fast configs only; heavy ones are covered by
-bench.py / their own CLIs."""
+the benchmark's cells / their own CLIs."""
 
 import importlib
 import sys

@@ -70,6 +70,15 @@ def make_engine(lm, **overrides):
     return InferenceEngine(lm, **kw)
 
 
+def throttle(engines, step_s=0.01):
+    """Slow every engine's ``step`` so that a drain or a kill lands
+    provably mid-stream (a fast box must not finish the streams
+    first)."""
+    for eng in engines.values():
+        real = eng.step
+        eng.step = (lambda real=real: (time.sleep(step_s), real())[1])
+
+
 _REF_ENGINE = {}
 
 
@@ -622,14 +631,7 @@ class TestRouter:
         max_new = 16
         refs = [reference_run(lm, p, max_new) for p in prompts]
         engines = {"a": make_engine(lm), "b": make_engine(lm)}
-        # throttle the drivers so the streams are PROVABLY mid-flight
-        # when the drain starts (a fast box must not finish them
-        # first and turn this into an empty-drain test)
-        for eng in engines.values():
-            real = eng.step
-            eng.step = (lambda real=real: (
-                time.sleep(0.01), real()
-            )[1])
+        throttle(engines)  # or this is an empty-drain test
         router = Router(engines, poll_every=2)
         with router:
             reqs = [router.submit(p, max_new) for p in prompts]
@@ -789,6 +791,51 @@ class TestRouter:
         for e in engines.values():
             e.release_telemetry()
 
+    def test_killed_replica_redrives_each_token_exactly_once(self, lm):
+        """A replica abandoned mid-stream (``kill_replica``, the chaos
+        harness's entry: driver stopped, engine state lost) costs its
+        clients nothing: the survivor continues every stream from its
+        last delivered token, each equals the no-kill reference token
+        for token, and one token minted anywhere is one token
+        delivered. Counts only; the trigger is a delivered-token
+        count, not a timer."""
+        from elephas_tpu.telemetry.watch import ReplicaDownRule, Watchdog
+
+        prompts = [[2, 3, 4, 5, 2, 3], [3, 4, 5, 2], [4, 5, 2, 3]]
+        max_new = 16
+        refs = [reference_run(lm, p, max_new) for p in prompts]
+        engines = {"a": make_engine(lm), "b": make_engine(lm)}
+        throttle(engines)
+        router = Router(engines, poll_every=4)
+        watchdog = Watchdog(rules=[ReplicaDownRule()])
+        with router:
+            reqs = [router.submit(p, max_new) for p in prompts]
+            deadline = time.monotonic() + 60
+            while router.tokens_delivered < 6:
+                assert time.monotonic() < deadline, "no token in 60 s"
+                time.sleep(0.002)
+            on_a = sum(r.replica == "a" and not r.done for r in reqs)
+            assert on_a >= 1, "the kill would have found nothing in flight"
+            assert router.kill_replica("a") == on_a
+            assert [x.rule for x in watchdog.evaluate()] == ["replica_down"]
+            assert all(r.wait(120) for r in reqs)
+            for r, ref, p in zip(reqs, refs, prompts):
+                assert r.error is None
+                assert list(p) + r.tokens == ref
+            delivered = router.tokens_delivered
+            assert delivered == sum(len(r) - len(p)
+                                    for r, p in zip(refs, prompts))
+            assert delivered == sum(
+                e.total_generated for e in engines.values()
+            )
+            st = router.stats()
+            assert st["redriven"] == on_a
+            assert st["stale_tokens_dropped"] == 0
+        watchdog.release_telemetry()
+        router.release_telemetry()
+        for e in engines.values():
+            e.release_telemetry()
+
     def test_failed_drain_restores_placement(self, lm):
         """An incomplete drain (here: timeout) must re-admit the
         replica to placement instead of silently shrinking fleet
@@ -894,12 +941,7 @@ class TestReplicaChaos:
         max_new = 20
         refs = [reference_run(lm, p, max_new) for p in prompts]
         engines = {"a": make_engine(lm), "b": make_engine(lm)}
-        # slow the drivers so the kill lands genuinely MID-stream
-        for eng in engines.values():
-            real = eng.step
-            eng.step = (lambda real=real: (
-                time.sleep(0.01), real()
-            )[1])
+        throttle(engines)
         router = Router(engines, poll_every=4)
         watchdog = Watchdog(rules=[ReplicaDownRule()])
         with router:

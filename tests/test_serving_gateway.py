@@ -159,6 +159,13 @@ def test_validation_and_routing_errors_are_loud(gw):
 
 
 def test_metrics_and_stats_routes(gw):
+    # one finished request of this test's own: the shared gateway may
+    # be a fresh one (another worker ran the tests above)
+    resp, _ = _request(gw.port, "POST", "/v1/generate", {
+        "prompt": [2, 3], "max_new_tokens": 2, "tenant": "a",
+        "stream": False,
+    })
+    assert resp.status == 200
     resp, raw = _request(gw.port, "GET", "/metrics")
     assert resp.status == 200
     assert resp.getheader("Content-Type").startswith("text/plain")

@@ -9,9 +9,9 @@ splices guarded by refcounts (shared blocks survive index eviction
 while a live table references them); preempt → host-offload → resume
 round-trips bit-exact; and a request that can NEVER fit the block pool
 is rejected loudly at submit instead of wedging the queue head. The
-capacity claim (>=1.5x admitted concurrency at equal KV bytes) is
-owned by ``bench.py --preset serving`` (longctx section) plus the
-slow-marked smoke at the bottom.
+capacity claim (at equal KV rows the pool runs at least 1.5 times the
+fixed arena's requests at once) is a count on the schedulers'
+admission, below.
 """
 
 import numpy as np
@@ -128,6 +128,40 @@ def test_paged_prefix_index_eviction_frees_only_unreferenced():
     # so the index keeps the reusable prefix instead
     assert idx.stats()["entries"] == 1
     assert a.ref_count(t2[0]) == 2  # entry + live table
+
+
+def test_paged_pool_admits_more_at_equal_kv_bytes():
+    """The capacity claim the block pool exists for, counted on the
+    schedulers' own admission: a fixed arena prices every slot at
+    ``maxlen`` rows, so 4 slots of 128 rows hold 4 requests whatever
+    their length; the same 512 rows as 32 blocks of 16 lease each
+    request only its prompt + budget, and a mixed short/long set (32
+    prompts of 25 and 2 of 76, 6 new tokens each) runs at least 1.5
+    times as many requests at once."""
+    from elephas_tpu.serving.blocks import BlockAllocator
+    from elephas_tpu.serving.scheduler import Scheduler, default_buckets
+    from tests.conftest import peak_admitted
+
+    maxlen, fixed_slots, block_size = 128, 4, 16
+    num_blocks = fixed_slots * maxlen // block_size
+    assert num_blocks * block_size == fixed_slots * maxlen  # equal rows
+    lanes = fixed_slots * 4
+    rng = np.random.default_rng(17)
+    mixed = [
+        (rng.integers(1, 512, size=n).tolist(), 6)
+        for n in [25] * (lanes * 2) + [76] * 2
+    ]
+    buckets = default_buckets(maxlen)
+    fixed = peak_admitted(Scheduler(fixed_slots, buckets), mixed)
+    paged = peak_admitted(
+        Scheduler(
+            lanes, buckets,
+            allocator=BlockAllocator(num_blocks, block_size),
+        ),
+        mixed,
+    )
+    assert fixed == fixed_slots
+    assert paged >= 1.5 * fixed, (paged, fixed)
 
 
 # -- token-exactness ---------------------------------------------------
@@ -535,35 +569,3 @@ def test_paged_stats_match_metrics_scrape(lm):
     ) == s["queue_depth"]
     engine.release_telemetry()
     assert f'engine="{eng_l}"' not in engine.scrape()
-
-
-# -- bench section smoke ----------------------------------------------
-
-
-@pytest.mark.slow  # compiles four engines on the deeper stand-in
-def test_longctx_bench_section_smoke():
-    """The new ``longctx`` bench section runs end-to-end on the same
-    deeper stand-in the serving preset uses (the CI toy is dispatch-
-    bound and trips the credibility floor — by design) and emits a
-    structurally-sane record. The admitted-concurrency gate is
-    deterministic and runs at FULL strength; the TTFT gate runs at a
-    widened smoke slack (2x) so ambient box noise cannot flake the
-    suite — the artifact run keeps the 1.25x default."""
-    import bench
-    from elephas_tpu.models import transformer_lm
-
-    model = transformer_lm(
-        vocab_size=512, maxlen=128, d_model=128, num_heads=4,
-        num_layers=4, dropout=0.0, seed=0,
-    )
-    rec = bench._serving_longctx_section(
-        model, maxlen=128, vocab=512, rounds=2, ttft_slack=2.0,
-    )
-    assert rec["kv_rows_fixed"] == rec["kv_rows_paged"]  # equal bytes
-    assert rec["concurrency_ratio"] >= 1.5
-    assert rec["admitted_concurrency_paged"] > rec[
-        "admitted_concurrency_fixed"
-    ]
-    assert rec["prefix_blocks_shared"] > 0
-    assert rec["ttft_ms_hit_paged"] > 0
-    assert rec["ttft_rounds_paged"] and rec["ttft_rounds_copy"]

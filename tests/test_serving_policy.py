@@ -6,9 +6,8 @@ streams stay bit-exact per request under any policy (including on the
 TP mesh). Fair share bounds cross-tenant service gaps where FIFO does
 not, deadline-EDF orders within the fair-share turn, aging promotes
 any waiter past its bound (no starvation), and overload admission
-control rejects loudly with a deterministic Retry-After. The goodput
-claim under overload is owned by ``bench.py --preset serving`` (the
-gated ``slo`` section).
+control rejects loudly with a deterministic Retry-After. Goodput
+under overload is a time on a device and has no benchmark cell yet.
 """
 
 import re

@@ -32,7 +32,7 @@ import urllib.request
 import numpy as np
 import pytest
 
-from elephas_tpu.fleet import decode_record, encode_record
+from elephas_tpu.fleet import decode_record, encode_record, migration
 from elephas_tpu.serving.kv_quant import (
     KV_DTYPES,
     dequantize_rows,
@@ -53,7 +53,7 @@ def lm():
     """Tiny UNtrained LM — the within-dtype contracts are about
     determinism (a fixed init's argmax is all the parity asserts
     need); cross-dtype quality runs on the trained stand-in in the
-    slow test below."""
+    slow test at the bottom."""
     from elephas_tpu.models import transformer_lm
 
     return transformer_lm(
@@ -224,6 +224,64 @@ class TestQuantizedEngine:
         assert nb_fp / q8.arena.nbytes() > 3.0
         assert nb_fp / q4.arena.nbytes() > 5.0
         for eng in (fp, q8, q4):
+            eng.release_telemetry()
+
+    @pytest.mark.parametrize("kv_dtype", ["int8", "int4"])
+    def test_second_identical_drive_compiles_nothing(self, lm, kv_dtype):
+        """The quantized programs keep the compiled-shape set closed:
+        a mixed-length drive compiles what it touches, the same drive
+        again compiles nothing."""
+        eng = make_engine(lm, kv_dtype=kv_dtype, num_slots=4)
+        workload = [
+            ([2, 3], 4), ([4, 5, 2, 3, 4], 6),
+            ([3, 4, 5, 2, 3, 4, 5, 2, 3], 5),
+        ]
+        eng.run(workload)
+        first = eng.compile_stats()
+        assert first["decode_compiles"] >= 1
+        eng.run(workload)
+        assert eng.compile_stats() == first
+        eng.release_telemetry()
+
+    def test_equal_bytes_pool_admits_more(self, lm):
+        """What the bytes buy, counted: pools sized to the float
+        pool's byte budget through ``pool_bytes_per_pos`` (blocks
+        rounded down) store no more bytes than it, array for array,
+        and on one over-subscribed request set the schedulers run at
+        least twice the float pool's requests at once at int8, and at
+        int4 at least int8's."""
+        from tests.conftest import peak_admitted
+
+        lanes, block_size, blocks_fp = 32, 4, 16
+        probe = make_engine(lm, num_slots=lanes, num_blocks=blocks_fp)
+        budget = probe.arena.nbytes()
+        engines = {"fp": probe}
+        for dt in ("int8", "int4"):
+            per_block = block_size * pool_bytes_per_pos(
+                probe.arena.specs, dt
+            )
+            engines[dt] = make_engine(
+                lm, num_slots=lanes, num_blocks=budget // per_block,
+                kv_dtype=dt,
+            )
+        stored = {
+            dt: sum(
+                np.asarray(leaf).nbytes
+                for leaves in eng._caches.values() for leaf in leaves
+            )
+            for dt, eng in engines.items()
+        }
+        for dt, eng in engines.items():
+            assert stored[dt] == eng.arena.nbytes()  # the price is real
+            assert stored[dt] <= stored["fp"]
+        workload = [([2, 3, 4, 5], 4)] * lanes  # two blocks a request
+        peak = {
+            dt: peak_admitted(eng.scheduler, workload)
+            for dt, eng in engines.items()
+        }
+        assert peak["fp"] == blocks_fp // 2
+        assert peak["int4"] >= peak["int8"] >= 2 * peak["fp"], peak
+        for eng in engines.values():
             eng.release_telemetry()
 
     def test_quant_telemetry_exists_in_every_mode(self, lm):
@@ -409,7 +467,8 @@ class TestMigrationWireV2:
     def test_quantized_roundtrip_bit_exact(self, lm):
         a = make_engine(lm, kv_dtype="int8")
         _, rec = warm_export(a)
-        assert rec["version"] == 2 and rec["kv_dtype"] == "int8"
+        assert rec["version"] == migration.VERSION
+        assert rec["kv_dtype"] == "int8"
         back = decode_record(encode_record(rec))
         assert back["kv_dtype"] == "int8"
         for name, leaves in rec["rows"].items():
@@ -441,8 +500,7 @@ class TestMigrationWireV2:
         """The compressed-state-movement claim, counted: the same
         warm request's record is >2.5x smaller at int8 on this tiny
         stand-in (H=2 Dh=16 rows shrink 3.2x; the JSON header is a
-        larger fraction here than on the bench model, where the gated
-        floor is 3x)."""
+        larger fraction here than at a served model's head geometry)."""
         fp = make_engine(lm)
         q8 = make_engine(lm, kv_dtype="int8")
         _, rec_fp = warm_export(fp)
@@ -487,8 +545,9 @@ class TestMigrationWireV2:
             decode_record(wire + b"\x00\x00")
         # version skew: patch the u16 version field to a future value
         skew = bytearray(wire)
-        skew[4:6] = struct.pack("<H", 3)
-        with pytest.raises(ValueError, match="version 3"):
+        future = migration.VERSION + 1
+        skew[4:6] = struct.pack("<H", future)
+        with pytest.raises(ValueError, match=f"version {future}"):
             decode_record(bytes(skew))
         # engine-level version check (records can arrive as dicts via
         # the in-process router, not only off the wire); one reused
@@ -538,10 +597,10 @@ class TestMigrationWireV2:
 def test_token_agreement_vs_fp_oracle_trained():
     """The quality gate's substance: on the TRAINED d128L4 stand-in
     (periodic data → confident argmax), int8 greedy output agrees with
-    the fp parity oracle >= 0.95 position-for-position, measured the
-    way the bench measures it — score() the fp oracle's own greedy
-    completion on the quantized engine. An untrained model would test
-    agreement between two argmax coin flips."""
+    the fp parity oracle >= 0.95 position-for-position: score() the
+    fp oracle's own greedy completion on the quantized engine. An
+    untrained model would test agreement between two argmax coin
+    flips."""
     from elephas_tpu import SparkModel
     from elephas_tpu.models import transformer_lm
 
@@ -582,34 +641,4 @@ def test_token_agreement_vs_fp_oracle_trained():
         eng.release_telemetry()
     fp.release_telemetry()
     assert agree["int8"] >= 0.95, agree
-    assert agree["int4"] >= 0.80, agree  # reported-not-gated in bench
-
-
-# -- bench section smoke ----------------------------------------------
-
-
-@pytest.mark.slow  # trains the d128L4 stand-in, compiles four engines
-def test_quant_bench_section_smoke():
-    """The ``quant`` bench section runs end-to-end at FULL gate
-    strength — every one of its four gates is deterministic or
-    margin-rich (3.5x concurrency vs the 2x floor, 3.4x wire vs 3x,
-    ~1.0 agreement vs 0.95), so the smoke needs no widened slack —
-    and emits a structurally-sane record."""
-    import bench
-
-    rec = bench._serving_quant_section()
-    # equal-bytes bookkeeping: the quantized pools never exceed the
-    # fp byte budget, and the admission win clears the gate
-    assert rec["pool_bytes_int8"] <= rec["pool_bytes_fp"]
-    assert rec["concurrency_ratio_int8"] >= 2.0
-    assert rec["admitted_concurrency"]["int4"] >= rec[
-        "admitted_concurrency"
-    ]["int8"] >= 2 * rec["admitted_concurrency"]["fp"]
-    # counted wire bytes, monotone in dtype width
-    assert rec["wire_bytes"]["fp"] > rec["wire_bytes"]["int8"] > rec[
-        "wire_bytes"
-    ]["int4"]
-    assert rec["wire_ratio_int8"] >= 3.0
-    assert rec["agreement_int8"] >= 0.95
-    assert 0.0 <= rec["agreement_int4"] <= 1.0
-    assert rec["kv_quant_export_bytes_int8"] > 0
+    assert agree["int4"] >= 0.80, agree

@@ -7,8 +7,8 @@ mid-flight arrivals, and ``steps_per_sync`` windows; the compiled
 shape set stays CLOSED (a second identical workload pass compiles
 nothing new); a collapsed acceptance rate throttles drafting back to
 plain decode and re-probes; and the new telemetry series back stats()
-and the scrape from ONE store. The >=1.3x decode-only tok/s claim is
-owned by ``bench.py --preset serving`` (specdec section).
+and the scrape from ONE store. What speculation buys per token is a
+time on a device and has no benchmark cell yet.
 """
 
 import logging

@@ -4,8 +4,8 @@ Registry semantics (threaded exactness, bucket edges, null no-ops),
 ring-buffer wraparound, the Prometheus golden render, the ``/metrics``
 round-trip on a live HTTP parameter server, the no-drift contract
 between attribute views and the registry, and the chaos harness's
-trace-stream recovery span. The bench-side overhead gate lives in
-``bench.py --preset serving`` (slow smoke in test_serving_prefix).
+trace-stream recovery span. What fit's spans cost on the chip is in
+PERF.md (PR 24).
 """
 
 import http.client
@@ -179,7 +179,7 @@ class TestNullMode:
 
     def test_null_engine_pays_no_registry_series(self, not_null, serving_lm):
         """An engine built under null mode records nothing and scrapes
-        empty — the bench's on-vs-null comparison shape."""
+        empty, beside engines built with telemetry on."""
         from elephas_tpu.serving import InferenceEngine
 
         was = telemetry.set_null(True)

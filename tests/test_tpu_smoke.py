@@ -1,7 +1,7 @@
 """chip_smoke.py off the chip: its phase functions at toy sizes on the
 virtual CPU mesh (the first two rehearsals of a chip run), its refusal
-to pass without a TPU, and the compile-cache helper it and bench.py
-share.
+to pass without a TPU, the backend guard it asks for the chip through,
+and the compile-cache helper.
 
 Named to sort late: the tier-1 command's time limit cuts the suite
 under halfway through, and these two minutes of toy training would
@@ -188,3 +188,70 @@ def test_cache_helper_gives_one_fixed_path_in_the_checkout(tmp_path):
     assert _where(None, str(tmp_path)) == [fixed, fixed]  # another process
     with open(os.path.join(REPO, ".gitignore")) as f:
         assert ".jax_cache/" in f.read().split()
+
+
+class TestBackendGuard:
+    """No path may let a run without a working chip look like a pass.
+    JAX itself picks the CPU silently when no accelerator answers, so
+    the guard's job is the opposite of a fallback: ask directly, and
+    raise naming what was found."""
+
+    REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def test_cpu_env_alone_selects_cpu_in_subprocess(self):
+        """``JAX_PLATFORMS=cpu`` and nothing else yields the CPU in a
+        child — and the child never maps the accelerator's runtime, so
+        it neither needs nor disturbs a chip its parent holds (the
+        only kind of child a process that has touched JAX may
+        start)."""
+        env = {
+            k: v for k, v in os.environ.items()
+            if k not in ("XLA_FLAGS", "JAX_NUM_CPU_DEVICES")
+        }
+        env.update(JAX_PLATFORMS="cpu", KERAS_BACKEND="jax")
+        proc = subprocess.run(
+            [sys.executable, "-c",
+             "from elephas_tpu.utils.backend_guard import device_record;"
+             "import jax.numpy as jnp; jnp.ones(4).sum().block_until_ready();"
+             "print('DEVICE=%r' % device_record());"
+             "print('LIBTPU_MAPPED=%s' % "
+             "('libtpu' in open('/proc/self/maps').read()))"],
+            capture_output=True, text=True, timeout=300, env=env,
+            cwd=self.REPO,
+        )
+        assert proc.returncode == 0, proc.stderr[-1500:]
+        assert (
+            "DEVICE={'platform': 'cpu', 'kind': 'cpu', 'count': 1}"
+            in proc.stdout
+        )
+        assert "LIBTPU_MAPPED=False" in proc.stdout
+
+    def test_failing_probe_propagates(self, monkeypatch):
+        """A backend that dies at initialisation is the caller's error
+        to see: no thread, no timeout, no switch to the CPU."""
+        import jax
+
+        from elephas_tpu.utils import backend_guard
+
+        def dying():
+            raise RuntimeError(
+                "Unable to initialize backend 'tpu': "
+                "make_c_api_client failed: INTERNAL"
+            )
+
+        monkeypatch.setattr(jax, "devices", dying)
+        with pytest.raises(RuntimeError, match="make_c_api_client"):
+            backend_guard.require_accelerator()
+        monkeypatch.undo()
+        assert jax.config.jax_platforms == "cpu"  # conftest's, untouched
+        assert backend_guard.device_record()["count"] == 8
+
+    def test_asking_for_the_chip_on_cpu_raises_naming_the_platform(self):
+        from elephas_tpu.utils import backend_guard
+
+        for want in (None, "tpu"):
+            with pytest.raises(RuntimeError) as ei:
+                backend_guard.require_accelerator(want)
+            assert "found platform 'cpu'" in str(ei.value)
+            assert "8 x cpu" in str(ei.value)
+        assert backend_guard.require_accelerator("cpu")["platform"] == "cpu"
