@@ -79,6 +79,8 @@ def _layers():
     import jax.numpy as jnp
     import keras
 
+    from elephas_tpu.ops.gated_delta import RESOLVE_NAME, gated_delta_rule
+
     register = keras.saving.register_keras_serializable(package="elephas_tpu")
     f32 = jnp.float32
 
@@ -141,15 +143,22 @@ def _layers():
     class _Remat(_SameShape):
         """``call`` is ``_forward``, under ``jax.checkpoint`` where the
         builder asked for it: the backward pass then keeps the layer's
-        input and computes the rest again."""
+        input, what ``_forward`` names with one of ``kept``
+        (``jax.ad_checkpoint.checkpoint_name``), and computes the rest
+        again."""
+
+        kept: tuple = ()
 
         def __init__(self, remat: bool = False, **kwargs):
             super().__init__(**kwargs)
             self.remat = remat
 
         def _rematted(self):
-            return jax.checkpoint(self._forward) if self.remat \
-                else self._forward
+            if not self.remat:
+                return self._forward
+            policy = jax.checkpoint_policies.save_only_these_names(
+                *self.kept) if self.kept else None
+            return jax.checkpoint(self._forward, policy=policy)
 
         def call(self, x):
             return self._rematted()(x)
@@ -231,6 +240,10 @@ def _layers():
 
     @register
     class GatedDeltaNet(_Remat):
+        # the chunks' triangular inverses, 64 KiB a head a chunk: the
+        # dearest product of the scan's chunk-parallel part by far
+        kept = (RESOLVE_NAME,)
+
         def __init__(self, num_key_heads: int, num_value_heads: int,
                      key_head_dim: int, value_head_dim: int,
                      conv_kernel: int = 4, chunk_size: int = 64,
@@ -272,8 +285,6 @@ def _layers():
             self.out_proj = self._weight("out_proj", (value_dim, d), init)
 
         def _forward(self, x):
-            from elephas_tpu.ops.gated_delta import gated_delta_rule
-
             b, s = jnp.shape(x)[0], x.shape[1]
             hk, hv = self.num_key_heads, self.num_value_heads
             dk, dv = self.key_head_dim, self.value_head_dim

@@ -20,15 +20,23 @@ blocks take their operands in the inputs' dtype and accumulate in
 float32. Both are plain ``jax.numpy``, so ``jax.grad`` gives the
 backward pass; the chunk scan's body is rematerialised
 (``jax.checkpoint``), so that what the backward pass keeps a chunk is
-the state alone.
+the state alone. The chunk-parallel part's dearest result, the
+triangular inverses, carries the name ``RESOLVE_NAME``, so that a
+caller whose own ``jax.checkpoint`` recomputes the whole op can keep
+them by policy (``models.qwen3_next.GatedDeltaNet`` does).
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 DEFAULT_CHUNK = 64
+# the name (``jax.ad_checkpoint.checkpoint_name``) of the chunks'
+# triangular inverses, for a caller whose ``jax.checkpoint`` policy
+# keeps them instead of solving again in the backward pass
+RESOLVE_NAME = "gdn_resolve"
 # the C x C triangular system is solved in float32 on the MXU: every
 # float32 product there names its precision
 _SOLVE_PRECISION = jax.lax.Precision.HIGHEST
@@ -79,7 +87,9 @@ def _unit_lower_inverse(lower):
 
 
 def _unit_lower_inverse_fwd(lower):
-    inverse = _unit_lower_inverse(lower)
+    # result and residual are one named value: a policy that keeps the
+    # name keeps both, and the rematerialised pass solves nothing
+    inverse = checkpoint_name(_unit_lower_inverse(lower), RESOLVE_NAME)
     return inverse, inverse
 
 

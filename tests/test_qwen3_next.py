@@ -96,6 +96,42 @@ def test_chunked_scan_backward(s, chunk):
         _close(g, w, 1e-5)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("s", [192, 150])
+def test_chunked_scan_at_the_published_head_width(s, dtype):
+    """128-wide heads in chunks of 64, what the benchmark's cell runs:
+    three chunks, and a sequence that ends inside its third; four heads
+    of which two share a ``q`` and ``k`` as the model's ``jnp.repeat``
+    leaves them. ``o``, the final state and the five gradients, under a
+    loss that reads both results, against the token-by-token rule.
+
+    float32 inputs hold 1e-5 of the largest element. bfloat16 inputs
+    hold 2^-6: every operand of every product is rounded to 8 bits
+    (2^-9 of itself), a dozen such roundings stand between the inputs
+    and a gradient, and the float32 oracle has none of them."""
+    from elephas_tpu.ops import gated_delta_rule, gated_delta_rule_recurrent
+
+    q, k = _scan_inputs(s, heads=2, dk=128, dv=128, seed=2)[:2]
+    v, g, beta = _scan_inputs(s, heads=4, dk=128, dv=128, seed=3)[2:]
+    q, k = jnp.repeat(q, 2, axis=2), jnp.repeat(k, 2, axis=2)
+    args = tuple(t.astype(dtype) for t in (q, k, v)) + (g, beta)
+    exact = tuple(t.astype(jnp.float32) for t in args)
+
+    def results(f, a):
+        def loss(*a):
+            out, state = f(*a)
+            return (jnp.sum(jnp.sin(out.astype(jnp.float32)))
+                    + jnp.sum(jnp.cos(state)))
+        return f(*a) + jax.grad(loss, (0, 1, 2, 3, 4))(*a)
+
+    got = results(gated_delta_rule, args)
+    want = results(gated_delta_rule_recurrent, exact)
+    assert got[0].dtype == jnp.dtype(dtype)
+    tol = 1e-5 if dtype == "float32" else 2.0 ** -6
+    for ours, oracle in zip(got, want):
+        _close(ours, oracle, tol)
+
+
 # -- grouped-query flash attention ------------------------------------------
 
 
@@ -194,6 +230,33 @@ def test_layer_forward_and_gradients(ref, kind, remat):
     _close(got[1], want[1])
     for path in params:
         _close(got[0][path], want[0][path], 5e-4)
+
+
+def test_rematerialised_mixer_keeps_its_triangular_inverses(ref, monkeypatch):
+    """A Gated DeltaNet layer under ``remat`` solves each chunk's
+    triangular system once: its ``jax.checkpoint`` keeps the inverses
+    (``GatedDeltaNet.kept``), so the gradient's program holds fewer
+    float32 products at ``highest`` (the solve's and its backward
+    rule's, nothing else in the layer asks for that precision) than it
+    does with nothing kept, where the forward's are there twice; the
+    gradients are the same numbers."""
+    x = jax.random.normal(jax.random.key(6), (2, SEQ, CFG["hidden_size"]))
+    params = _layer_params(ref, PREFIX["gdn"])
+
+    def gradient_and_solves():
+        layer = _keras_layer("gdn", remat=True)
+        layer.build(x.shape)
+        grad = jax.jit(jax.grad(
+            lambda p, x: jnp.sum(jnp.sin(3.0 * _stateless(layer, p, x)))))
+        solves = grad.lower(params, x).as_text().count("HIGHEST, HIGHEST")
+        return grad(params, x), solves
+
+    kept, solves_kept = gradient_and_solves()
+    monkeypatch.setattr(type(_keras_layer("gdn")), "kept", ())
+    recomputed, solves_recomputed = gradient_and_solves()
+    assert 0 < solves_kept < solves_recomputed
+    for path in params:
+        np.testing.assert_array_equal(kept[path], recomputed[path])
 
 
 def test_norm_and_swiglu(ref):
