@@ -23,6 +23,7 @@ from elephas_tpu.models.switch import (
     switch_transformer_lm,
 )
 from elephas_tpu.models.qwen3_next import qwen3_next_lm
+from elephas_tpu.models.deepseek_v3 import deepseek_v3_lm
 
 __all__ = [
     "mnist_mlp",
@@ -36,6 +37,7 @@ __all__ = [
     "switch_transformer_classifier",
     "switch_transformer_lm",
     "qwen3_next_lm",
+    "deepseek_v3_lm",
     "MoeFFN",
     "FlashMHA",
     "FusedLayerNorm",
@@ -44,6 +46,9 @@ __all__ = [
     "GatedAttention",
     "GatedDeltaNet",
     "SparseMoeBlock",
+    "RMSNorm",
+    "LatentAttention",
+    "DenseMLP",
 ]
 
 
@@ -61,8 +66,9 @@ def __getattr__(name):
         from elephas_tpu.models.switch import MoeFFN
 
         return MoeFFN
-    from elephas_tpu.models import qwen3_next
+    from elephas_tpu.models import deepseek_v3, qwen3_next
 
-    if name in qwen3_next.LAYER_NAMES:
-        return getattr(qwen3_next, name)
+    for module in (qwen3_next, deepseek_v3):
+        if name in module.LAYER_NAMES:
+            return getattr(module, name)
     raise AttributeError(name)

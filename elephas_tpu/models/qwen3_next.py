@@ -118,28 +118,6 @@ def _layers():
         def get_config(self):
             return {**super().get_config(), "epsilon": self.epsilon}
 
-    @register
-    class SwiGLU(_SameShape):
-        def __init__(self, width: int, init_std: float = 0.02, **kwargs):
-            super().__init__(**kwargs)
-            self.width, self.init_std = width, init_std
-
-        def build(self, input_shape):
-            d = int(input_shape[-1])
-            self.gate_up = self._weight(
-                "gate_up", (d, 2 * self.width), normal(self.init_std))
-            self.down = self._weight(
-                "down", (self.width, d), normal(self.init_std))
-
-        def call(self, x):
-            gate, up = jnp.split(jnp.matmul(x, self.gate_up.value), 2, -1)
-            hidden = jax.nn.silu(gate.astype(f32)) * up.astype(f32)
-            return jnp.matmul(hidden.astype(x.dtype), self.down.value)
-
-        def get_config(self):
-            return {**super().get_config(), "width": self.width,
-                    "init_std": self.init_std}
-
     class _Remat(_SameShape):
         """``call`` is ``_forward``, under ``jax.checkpoint`` where the
         builder asked for it: the backward pass then keeps the layer's
@@ -162,6 +140,28 @@ def _layers():
 
         def call(self, x):
             return self._rematted()(x)
+
+    @register
+    class SwiGLU(_Remat):
+        def __init__(self, width: int, init_std: float = 0.02, **kwargs):
+            super().__init__(**kwargs)
+            self.width, self.init_std = width, init_std
+
+        def build(self, input_shape):
+            d = int(input_shape[-1])
+            self.gate_up = self._weight(
+                "gate_up", (d, 2 * self.width), normal(self.init_std))
+            self.down = self._weight(
+                "down", (self.width, d), normal(self.init_std))
+
+        def _forward(self, x):
+            gate, up = jnp.split(jnp.matmul(x, self.gate_up.value), 2, -1)
+            hidden = jax.nn.silu(gate.astype(f32)) * up.astype(f32)
+            return jnp.matmul(hidden.astype(x.dtype), self.down.value)
+
+        def get_config(self):
+            return {**super().get_config(), "width": self.width,
+                    "init_std": self.init_std, "remat": self.remat}
 
     @register
     class GatedAttention(_Remat):
@@ -350,16 +350,37 @@ def _layers():
     class SparseMoeBlock(_Remat):
         """Router over ``num_experts``, the routed part of the experts
         in ``experts_held`` (a ``(first, stop)`` range; all of them when
-        None), a shared expert under a sigmoid gate. ``epoch_counters``
-        tells the epoch runner which variable adds up, call by call,
-        what the block routed, and what its entries are."""
+        None), and a shared expert. What differs between the models
+        that use it is the builder's to set: the router's rule
+        (``scoring_func``, ``routed_scaling_factor`` and, with
+        ``selection_bias``, a non-trainable ``e_score_correction_bias``
+        added for the choice alone; :func:`elephas_tpu.ops.moe.route_top_k`)
+        and whether the shared expert lies under a sigmoid gate
+        (``gated_shared_expert``). ``epoch_counters`` tells the epoch
+        runner which variable adds up, call by call, what the block
+        routed, and what its entries are."""
 
         epoch_counters = {"route_counts": COUNTER_NAMES}
 
         def __init__(self, num_experts: int, experts_per_token: int,
                      expert_width: int, shared_width: int,
-                     experts_held=None, init_std: float = 0.02, **kwargs):
+                     experts_held=None, init_std: float = 0.02,
+                     scoring_func: str = "softmax",
+                     selection_bias: bool = False,
+                     routed_scaling_factor: float = 1.0,
+                     gated_shared_expert: bool = True, **kwargs):
             super().__init__(**kwargs)
+            from elephas_tpu.ops.moe import ROUTER_SCORES
+
+            if scoring_func not in ROUTER_SCORES:
+                raise ValueError(
+                    f"scoring_func {scoring_func!r} is none of "
+                    f"{sorted(ROUTER_SCORES)}"
+                )
+            self.scoring_func, self.selection_bias = (
+                scoring_func, bool(selection_bias))
+            self.routed_scaling_factor = float(routed_scaling_factor)
+            self.gated_shared_expert = bool(gated_shared_expert)
             first, stop = experts_held or (0, num_experts)
             if not 0 <= first < stop <= num_experts:
                 raise ValueError(
@@ -386,7 +407,14 @@ def _layers():
                 "experts_gate_up", (held, d, 2 * self.expert_width), init)
             self.experts_down = self._weight(
                 "experts_down", (held, self.expert_width, d), init)
-            self.shared_gate = self._weight("shared_gate", (d, 1), init)
+            if self.gated_shared_expert:
+                self.shared_gate = self._weight("shared_gate", (d, 1), init)
+            if self.selection_bias:
+                self.e_score_correction_bias = self.add_weight(
+                    name="e_score_correction_bias",
+                    shape=(self.num_experts,), dtype="float32",
+                    initializer="zeros", trainable=False, autocast=False,
+                )
             self.shared_expert = SwiGLU(
                 self.shared_width, self.init_std, name="shared_expert")
             self.shared_expert.build(input_shape)
@@ -401,15 +429,20 @@ def _layers():
 
             b, s, d = jnp.shape(x)[0], x.shape[1], x.shape[2]
             flat = x.reshape(b * s, d)
+            routing = {"score": self.scoring_func,
+                       "scale": self.routed_scaling_factor}
+            if self.selection_bias:
+                routing["select_bias"] = self.e_score_correction_bias.value
             routed, counts = held_experts_ffn(
                 flat, self.router.value, self.experts_gate_up.value,
                 self.experts_down.value, self.experts_held,
-                self.experts_per_token,
+                self.experts_per_token, **routing,
             )
             with jax.named_scope("moe.shared"):
-                gate = jax.nn.sigmoid(
-                    jnp.matmul(flat, self.shared_gate.value).astype(f32))
-                shared = self.shared_expert(flat).astype(f32) * gate
+                shared = self.shared_expert(flat).astype(f32)
+                if self.gated_shared_expert:
+                    shared = shared * jax.nn.sigmoid(jnp.matmul(
+                        flat, self.shared_gate.value).astype(f32))
             y = (routed.astype(f32) + shared).astype(x.dtype)
             return y.reshape(b, s, d), counts
 
@@ -425,7 +458,12 @@ def _layers():
                     "expert_width": self.expert_width,
                     "shared_width": self.shared_width,
                     "experts_held": list(self.experts_held),
-                    "init_std": self.init_std, "remat": self.remat}
+                    "init_std": self.init_std,
+                    "scoring_func": self.scoring_func,
+                    "selection_bias": self.selection_bias,
+                    "routed_scaling_factor": self.routed_scaling_factor,
+                    "gated_shared_expert": self.gated_shared_expert,
+                    "remat": self.remat}
 
     @register
     class LMHead(keras.layers.Layer):
@@ -456,13 +494,15 @@ def _layers():
 
     keras.saving.register_keras_serializable(package="elephas_tpu")(
         next_token_loss)
+    # the two bases ride along for the models that build on these
+    # layers (``models/deepseek_v3.py``); they are no public names
     _LAYERS = {
         cls.__name__: cls for cls in (
             ZeroCentredRMSNorm, SwiGLU, GatedAttention, GatedDeltaNet,
-            SparseMoeBlock, LMHead,
+            SparseMoeBlock, LMHead, _SameShape, _Remat,
         )
     }
-    assert set(_LAYERS) == set(LAYER_NAMES)
+    assert set(LAYER_NAMES) == {n for n in _LAYERS if n[0] != "_"}
     return _LAYERS
 
 

@@ -130,23 +130,27 @@ def _resolve_blocks(block_q, block_k, s_q, s_k):
     return block_q, block_k
 
 
-def _cost(bh, s_q, s_k, d, itemsize):
+def _cost(bh, s_q, s_k, d, itemsize, dv=None):
+    """Forward cost: scores over the ``d``-wide queries and keys, the
+    sum over the ``dv``-wide values (``d`` where not given)."""
+    dv = d if dv is None else dv
     # keras symbolic builds trace with a polymorphic batch dim
     # (_DimExpr); CostEstimate requires concrete ints
-    if not all(type(t) is int for t in (bh, s_q, s_k, d)):
+    if not all(type(t) is int for t in (bh, s_q, s_k, d, dv)):
         return None
     return pl.CostEstimate(
-        flops=4 * bh * s_q * s_k * d,
-        bytes_accessed=(2 * bh * s_q * d + 2 * bh * s_k * d) * itemsize,
+        flops=2 * bh * s_q * s_k * (d + dv),
+        bytes_accessed=bh * (s_q + s_k) * (d + dv) * itemsize,
         transcendentals=bh * s_q * s_k,
     )
 
 
 def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
-    """[BH, S, D] inputs → (out [BH, S, D], lse [BH, S]); ``k`` and
-    ``v`` may have a whole fraction of ``q``'s heads (``[BH / G, S, D]``)."""
+    """[BH, S, D] inputs → (out [BH, S, Dv], lse [BH, S]); ``k`` and
+    ``v`` may have a whole fraction of ``q``'s heads (``[BH / G, S, D]``),
+    and ``v`` a width of its own (``[BH / G, S, Dv]``)."""
     bh, s_q, d = q.shape
-    s_k = k.shape[1]
+    s_k, dv = k.shape[1], v.shape[-1]
     # grouped-query attention: ``group`` consecutive query heads read
     # one key/value head, through the index map (no repeated copy)
     group = bh // k.shape[0]
@@ -166,25 +170,25 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
             pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((None, block_k, d),
                          lambda b, i, j: (b // group, j, 0)),
-            pl.BlockSpec((None, block_k, d),
+            pl.BlockSpec((None, block_k, dv),
                          lambda b, i, j: (b // group, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((None, block_q, dv), lambda b, i, j: (b, i, 0)),
             # lse rides as [BH, 1, S] so the trailing block dims (1, block_q)
             # meet Mosaic's (equal-dim, 128-divisible) tiling rule
             pl.BlockSpec((None, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, s_q, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, s_q), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        cost_estimate=_cost(bh, s_q, s_k, d, q.dtype.itemsize),
+        cost_estimate=_cost(bh, s_q, s_k, d, q.dtype.itemsize, dv),
         interpret=interpret,
     )(q, k, v)
     return out, lse[:, 0, :]
@@ -434,7 +438,7 @@ def _causal_mask(i, j, block_q, block_k):
 def _flash_backward(scale, causal, block_q, block_k, residuals, g):
     q, k, v, out, lse = residuals
     bh, s_q, d = q.shape
-    s_k = k.shape[1]
+    s_k, dv = k.shape[1], v.shape[-1]  # v, out and g are ``dv`` wide
     block_q = min(block_q, s_q)
     block_k = min(block_k, s_k)
     nq, nk = s_q // block_q, s_k // block_k
@@ -442,8 +446,8 @@ def _flash_backward(scale, causal, block_q, block_k, residuals, g):
 
     qb = q.reshape(bh, nq, block_q, d).astype(f32)
     kb = k.reshape(bh, nk, block_k, d).astype(f32)
-    vb = v.reshape(bh, nk, block_k, d).astype(f32)
-    gb = g.reshape(bh, nq, block_q, d).astype(f32)
+    vb = v.reshape(bh, nk, block_k, dv).astype(f32)
+    gb = g.reshape(bh, nq, block_q, dv).astype(f32)
     lseb = lse.reshape(bh, nq, block_q)
     # Δ_i = rowsum(dO ∘ O)
     delta = jnp.sum(g.astype(f32) * out.astype(f32), axis=-1).reshape(
@@ -493,16 +497,17 @@ def _flash_backward(scale, causal, block_q, block_k, residuals, g):
             ) * scale
             return (dk_acc, dv_acc), None
 
-        z = jnp.zeros((bh, block_k, d), f32)
-        (dk_acc, dv_acc), _ = jax.lax.scan(body, (z, z), jnp.arange(nq))
+        zeros = (jnp.zeros((bh, block_k, d), f32),
+                 jnp.zeros((bh, block_k, dv), f32))
+        (dk_acc, dv_acc), _ = jax.lax.scan(body, zeros, jnp.arange(nq))
         return dk_acc, dv_acc
 
-    dk, dv = jax.vmap(dkv_for_block, in_axes=(0, 1, 1), out_axes=1)(
+    d_k, d_v = jax.vmap(dkv_for_block, in_axes=(0, 1, 1), out_axes=1)(
         jnp.arange(nk), kb, vb
     )
-    dk = dk.reshape(bh, s_k, d)
-    dv = dv.reshape(bh, s_k, d)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    d_k = d_k.reshape(bh, s_k, d)
+    d_v = d_v.reshape(bh, s_k, dv)
+    return dq.astype(q.dtype), d_k.astype(k.dtype), d_v.astype(v.dtype)
 
 
 def _flash_backward_packed(scale, causal, block_q, block_k, residuals, g):
@@ -632,6 +637,7 @@ def _flash_backward_kernels(scale, causal, block_q, block_k, interpret,
     q, k, v, out, lse = residuals
     bh, s_q, d = q.shape
     bkv, s_k, _ = k.shape
+    dv = v.shape[-1]  # v, out, dO and dV; q, k, dQ and dK are ``d`` wide
     group = bh // bkv
     block_q, block_k = _resolve_blocks(block_q, block_k, s_q, s_k)
     nq, nk = s_q // block_q, s_k // block_k
@@ -641,14 +647,17 @@ def _flash_backward_kernels(scale, causal, block_q, block_k, interpret,
     lse, delta = lse[:, None, :], delta[:, None, :]  # [BH, 1, S] rows
     params = dict(scale=scale, causal=causal, block_q=block_q,
                   block_k=block_k)
-    concrete = all(type(t) is int for t in (bh, s_q, s_k, d))
+    concrete = all(type(t) is int for t in (bh, s_q, s_k, d, dv))
 
-    def cost(products):
+    def cost(score_wide, value_wide):
+        """``score_wide`` products contract or give the ``d``-wide
+        side, ``value_wide`` the ``dv``-wide one."""
         if not concrete:
             return None
         return pl.CostEstimate(
-            flops=2 * products * bh * s_q * s_k * d,
-            bytes_accessed=(3 * bh * s_q * d + 4 * bkv * s_k * d)
+            flops=2 * bh * s_q * s_k * (score_wide * d + value_wide * dv),
+            bytes_accessed=(bh * s_q * (2 * d + dv)
+                            + bkv * s_k * (2 * d + 2 * dv))
             * q.dtype.itemsize,
             transcendentals=bh * s_q * s_k,
         )
@@ -669,20 +678,20 @@ def _flash_backward_kernels(scale, causal, block_q, block_k, interpret,
     q_side = lambda b, j, g_, i: (b * group + g_, first_i(i, j), 0)  # noqa: E731
     kv_side = lambda b, j, g_, i: (b, j, 0)  # noqa: E731
     row = lambda b, j, g_, i: (b * group + g_, 0, first_i(i, j))  # noqa: E731
-    dk, dv = pl.pallas_call(
+    d_k, d_v = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **params),
         grid=(bkv, nk, group, nq),
         in_specs=[
             pl.BlockSpec((None, block_q, d), q_side),
             pl.BlockSpec((None, block_k, d), kv_side),
-            pl.BlockSpec((None, block_k, d), kv_side),
-            pl.BlockSpec((None, block_q, d), q_side),
+            pl.BlockSpec((None, block_k, dv), kv_side),
+            pl.BlockSpec((None, block_q, dv), q_side),
             pl.BlockSpec((None, 1, block_q), row),
             pl.BlockSpec((None, 1, block_q), row),
         ],
         out_specs=[
             pl.BlockSpec((None, block_k, d), kv_side),
-            pl.BlockSpec((None, block_k, d), kv_side),
+            pl.BlockSpec((None, block_k, dv), kv_side),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -690,9 +699,9 @@ def _flash_backward_kernels(scale, causal, block_q, block_k, interpret,
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), f32),
-            pltpu.VMEM((block_k, d), f32),
+            pltpu.VMEM((block_k, dv), f32),
         ],
-        cost_estimate=cost(4),
+        cost_estimate=cost(2, 2),
         interpret=interpret,
     )(q, k, v, g, lse, delta)
 
@@ -705,8 +714,8 @@ def _flash_backward_kernels(scale, causal, block_q, block_k, interpret,
         in_specs=[
             pl.BlockSpec((None, block_q, d), q_side),
             pl.BlockSpec((None, block_k, d), kv_side),
-            pl.BlockSpec((None, block_k, d), kv_side),
-            pl.BlockSpec((None, block_q, d), q_side),
+            pl.BlockSpec((None, block_k, dv), kv_side),
+            pl.BlockSpec((None, block_q, dv), q_side),
             pl.BlockSpec((None, 1, block_q), row),
             pl.BlockSpec((None, 1, block_q), row),
         ],
@@ -717,10 +726,10 @@ def _flash_backward_kernels(scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_q, 1), f32),
             pltpu.VMEM((block_q, 1), f32),
         ],
-        cost_estimate=cost(3),
+        cost_estimate=cost(2, 1),
         interpret=interpret,
     )(q, k, v, g, lse, delta)
-    return dq, dk, dv
+    return dq, d_k, d_v
 
 
 # -- public op ---------------------------------------------------------
@@ -846,7 +855,9 @@ def flash_attention(
     (or ``[bh, seq, head_dim]``). Differentiable; O(seq) memory.
     ``k`` and ``v`` may have fewer heads than ``q`` (grouped-query
     attention): ``heads_q / heads_kv`` consecutive query heads then
-    share one key/value head.
+    share one key/value head. ``v`` may have a head width of its own
+    (latent attention scores with wider heads than it sums): the
+    result then has ``v``'s.
 
     ``block_q``/``block_k`` default to the module-level
     ``DEFAULT_BLOCK_Q``/``DEFAULT_BLOCK_K`` (resolved at CALL time, so
@@ -870,7 +881,12 @@ def flash_attention(
             f"{h} query heads cannot share {k.shape[1]} key and "
             f"{v.shape[1]} value heads"
         )
-    merged = lambda t, s: t.reshape(-1, s, d)  # noqa: E731
+    if k.shape[-1] != d:
+        raise ValueError(
+            f"queries are {d} wide and keys {k.shape[-1]}: a score needs "
+            f"one width (the values' may differ)"
+        )
+    merged = lambda t, s: t.reshape(-1, s, t.shape[-1])  # noqa: E731
     out = _flash_attention_bhsd(
         merged(q, s_q),
         merged(k, s_k),
@@ -881,7 +897,7 @@ def flash_attention(
         int(block_k),
         bool(interpret),
     )
-    out = out.reshape(b, h, s_q, d)
+    out = out.reshape(b, h, s_q, v.shape[-1])
     return out[0] if squeeze else out
 
 

@@ -234,16 +234,33 @@ def init_moe_params(
 # -- dropless routing over the experts held ------------------------------
 
 
-def route_top_k(x, router_w, k: int):
-    """``(weights [T, k] float32, experts [T, k] int32)``: softmax over
-    ALL of the router's outputs in float32, the ``k`` largest,
-    renormalised to sum to 1."""
+ROUTER_SCORES = {"softmax": jax.nn.softmax, "sigmoid": jax.nn.sigmoid}
+
+
+def route_top_k(x, router_w, k: int, score: str = "softmax",
+                select_bias=None, scale: float = 1.0):
+    """``(weights [T, k] float32, experts [T, k] int32)``: every one of
+    the router's outputs scored in float32 (``score``: a ``softmax``
+    over all of them, or a ``sigmoid`` each), the ``k`` largest chosen,
+    their scores renormalised to sum to 1 and multiplied by ``scale``.
+    ``select_bias [E]`` is added to the scores for the choice alone
+    (a load-balancing term that is no parameter): the weights are the
+    scores without it."""
     logits = jnp.matmul(
         x.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     )
-    weights, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
-    return weights / jnp.sum(weights, axis=-1, keepdims=True), experts
+    scores = ROUTER_SCORES[score](logits)
+    if select_bias is None:
+        weights, experts = jax.lax.top_k(scores, k)
+    else:
+        _, experts = jax.lax.top_k(
+            scores + select_bias.astype(jnp.float32), k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+    # the published sigmoid router's guard against a zero sum; below
+    # float32's last bit of any sum of softmax's k largest
+    weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return weights * scale, experts
 
 
 def _tile(size: int, wanted: int) -> int:
@@ -375,10 +392,13 @@ def _round_up(n: int, to: int) -> int:
     return -(-n // to) * to
 
 
-def held_experts_ffn(x, router_w, w_gate_up, w_down, experts_held, k: int):
+def held_experts_ffn(x, router_w, w_gate_up, w_down, experts_held, k: int,
+                     **routing):
     """The routed part of a sparse block that the experts held here
     give: ``sum_e p_e * down_e(silu(gate_e(x)) * up_e(x))`` over the
-    token's ``k`` chosen experts that lie in ``experts_held``.
+    token's ``k`` chosen experts that lie in ``experts_held``;
+    ``routing`` is the router's rule, as :func:`route_top_k` takes it
+    (``score``, ``select_bias``, ``scale``).
 
     ``x [T, D]``; ``router_w [D, E]`` scores all ``E`` experts;
     ``experts_held = (first, stop)`` is the range of them whose weights
@@ -402,7 +422,7 @@ def held_experts_ffn(x, router_w, w_gate_up, w_down, experts_held, k: int):
     held = stop - first
     tokens = x.shape[0]
     with jax.named_scope("moe.route"):
-        weights, experts = route_top_k(x, router_w, k)
+        weights, experts = route_top_k(x, router_w, k, **routing)
         local = jnp.where(
             (experts >= first) & (experts < stop), experts - first, held
         )

@@ -20,11 +20,16 @@ def _qkv(bh=4, s=256, d=64, seed=0, dtype=jnp.float32):
     return mk(), mk(), mk()
 
 
+@pytest.mark.parametrize("dv", [64, 48])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_matches_reference(causal):
+def test_flash_matches_reference(causal, dv):
+    """``dv`` is the values' width: the scores' own (64), or narrower
+    (latent attention scores 192 wide and sums 128 wide)."""
     q, k, v = _qkv()
+    v = v[..., :dv]
     out = flash_attention(q, k, v, causal=causal, block_q=128, block_k=128)
     ref = attention_reference(q, k, v, causal=causal)
+    assert out.shape == q.shape[:-1] + (dv,)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
@@ -74,6 +79,12 @@ def _backward_cases():
     cases["g8-full-bf16"] = dict(group=8, causal=False, dtype=jnp.bfloat16)
     cases["g2-causal-masked-rows"] = dict(
         group=2, causal=True, masked_rows=True)
+    # values narrower than the scores, in latent attention's ratio
+    # (192 to 128): v, o, dO, dV at ``dv``; q, k, dQ, dK at ``d``
+    for name, more in (("g1-causal", {}), ("g1-full", {}),
+                       ("g2-causal-bf16", dict(dtype=jnp.bfloat16))):
+        cases[name + "-d24-v16"] = dict(
+            group=int(name[1]), causal="causal" in name, d=24, dv=16, **more)
     return cases
 
 
@@ -91,15 +102,15 @@ def test_flash_backward_kernels_match_the_blockwise_rule_and_autodiff(case):
     )
 
     c = {"s_q": 32, "s_k": 32, "block_q": 8, "block_k": 16,
-         "dtype": jnp.float32, "masked_rows": False,
+         "dtype": jnp.float32, "masked_rows": False, "d": 16, "dv": 16,
          **_backward_cases()[case]}
-    group, causal, d, kv_heads = c["group"], c["causal"], 16, 2
+    group, causal, d, kv_heads = c["group"], c["causal"], c["d"], 2
     scale = d ** -0.5
     ks = jax.random.split(jax.random.key(7), 4)
     q = jax.random.normal(ks[0], (kv_heads * group, c["s_q"], d), c["dtype"])
     k = jax.random.normal(ks[1], (kv_heads, c["s_k"], d), c["dtype"])
-    v = jax.random.normal(ks[2], (kv_heads, c["s_k"], d), c["dtype"])
-    g = jax.random.normal(ks[3], q.shape, c["dtype"])
+    v = jax.random.normal(ks[2], (kv_heads, c["s_k"], c["dv"]), c["dtype"])
+    g = jax.random.normal(ks[3], q.shape[:-1] + (c["dv"],), c["dtype"])
     blocks = (c["block_q"], c["block_k"])
 
     out, lse = _flash_forward(q, k, v, scale, causal, *blocks, True)
@@ -136,7 +147,7 @@ def test_flash_backward_kernels_match_the_blockwise_rule_and_autodiff(case):
     # up to 32 terms: 2^-6 of the largest element holds all three
     for name, want in wants:
         for a, w, leaf in zip(got, want, "qkv"):
-            assert a.dtype == c["dtype"]
+            assert a.dtype == c["dtype"] and a.shape == w.shape
             if c["dtype"] == jnp.float32:
                 np.testing.assert_allclose(
                     np.asarray(a), np.asarray(w), atol=1e-5, rtol=1e-5,

@@ -255,6 +255,39 @@ def test_flash_backward_grouped_query_at_8192_positions():
     assert compiled.memory_analysis().temp_size_in_bytes < repeated_f32
 
 
+def test_flash_kernels_at_latent_attentions_two_widths():
+    """Latent attention at the published widths (ISSUE 31): 32 heads
+    score with 192-wide queries and keys and sum 128-wide values over
+    8192 causal positions. The forward kernel, dK/dV and dQ take the
+    two widths as they are: three Mosaic calls, gradients in their
+    operands' shapes, no operand padded to a common width in HBM
+    (nothing of the program is 256 wide, and ``v``, ``o`` and ``dV``
+    stay 128). The temporaries are XLA's four layout copies of the
+    192-wide operands and gradients (it keeps a free-standing 192-wide
+    array sequence-minor; 100 MB each), which hold the output, its
+    gradient and the softmax statistics in turn: bounded by what
+    float32 copies of ``q`` and ``k`` at 256 wide would take."""
+    q = on_chip((32, 8192, 192), jnp.bfloat16)
+    v = on_chip((32, 8192, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = flash_attention(
+            q, k, v, causal=True, block_q=256, block_k=256, interpret=False
+        )
+        assert out.shape == v.shape
+        return jnp.sum(out.astype(jnp.float32))
+
+    grads = jax.jit(jax.grad(loss, (0, 1, 2)))
+    compiled = grads.lower(q, q, v).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert "[32,8192,256]" not in text
+    assert [g.shape for g in jax.eval_shape(grads, q, q, v)] == [
+        q.shape, q.shape, v.shape]
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        2 * 32 * 8192 * 256 * 4)
+
+
 def test_grouped_matmul_over_held_experts_forward_and_backward():
     """20480 slot rows over 32 held experts at hidden 2048 and twice
     the expert width: the grouped product and both of its gradients
