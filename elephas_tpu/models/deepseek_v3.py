@@ -42,8 +42,6 @@ from elephas_tpu.models.transformer import (
 )
 
 _LAYERS = None
-# the flash kernels' query and key blocks, as the hybrid LM's
-ATTN_BLOCK = qwen3_next.ATTN_BLOCK
 LAYER_NAMES = ("RMSNorm", "LatentAttention", "DenseMLP")
 
 
@@ -162,7 +160,6 @@ def _layers():
                 out = flash_attention(
                     heads_first(q), heads_first(k), heads_first(v),
                     causal=True, scale=(nope + rope) ** -0.5,
-                    block_q=ATTN_BLOCK, block_k=ATTN_BLOCK,
                 )
                 out = heads_first(out).reshape(b, s, h * dv)
             with jax.named_scope("mla.proj"):

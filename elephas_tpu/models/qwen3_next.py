@@ -40,9 +40,6 @@ from elephas_tpu.models.transformer import (
 
 _LAYERS = None
 COUNTER_NAMES = ("held_slots", "slots", "max_expert_tokens")
-# the flash kernel's query and key blocks: with 512 the blockwise
-# backward pass's float32 blocks did not fit the chip at 8192 positions
-ATTN_BLOCK = 256
 LAYER_NAMES = ("ZeroCentredRMSNorm", "SwiGLU", "GatedAttention",
                "GatedDeltaNet", "SparseMoeBlock", "LMHead")
 
@@ -223,7 +220,6 @@ def _layers():
                 out = flash_attention(
                     heads_first(q), heads_first(k), heads_first(v),
                     causal=True, scale=hd ** -0.5,
-                    block_q=ATTN_BLOCK, block_k=ATTN_BLOCK,
                 )
                 out = heads_first(out).astype(f32) * jax.nn.sigmoid(
                     gate.astype(f32))

@@ -11,9 +11,12 @@ Backward uses the standard flash recurrences (dV = Pᵀ dO, dS = P∘(dP − Δ)
 …) over O(S·D) residuals (just q/k/v/out/LSE). In the ``[BH, S, D]``
 layout they are two Pallas kernels in the forward's style (dK/dV, then
 dQ): score blocks stay in VMEM, operands reach the MXU in the inputs'
-dtype, causally empty block pairs are skipped, and a key/value head that
-several query heads share is read in place. The packed qkv layout still
-evaluates them blockwise under ``lax.scan``, XLA-fused
+dtype, causally empty block pairs are skipped (their copies too, in
+all three kernels: the index maps stay on the last visible block), and a
+key/value head that several query heads share is read in place. Where a
+caller names no block each kernel takes the largest measured blocks that
+divide the sequences and fit VMEM (``_BLOCK_TABLE``). The packed qkv
+layout still evaluates them blockwise under ``lax.scan``, XLA-fused
 (:func:`_flash_backward`, also the tests' oracle for the kernels). The
 whole op carries a ``jax.custom_vjp`` so it drops into any ``jax.grad``
 training step.
@@ -40,8 +43,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from elephas_tpu.utils import backend_guard
 
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
 
 
@@ -94,6 +95,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         p = jnp.where(m_new <= NEG_INF * 0.5, 0.0, p)
         alpha = jnp.exp(m_prev - m_new)  # [BQ, 1]
         l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        # a float32 product at default precision reaches the MXU in one
+        # bfloat16 pass: casting p to v's dtype instead gave the same
+        # output and the same time on the chip (PR 36's micro record)
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
             p, v_ref[:].astype(jnp.float32),
             dimension_numbers=(((1,), (0,)), ((), ())),
@@ -119,15 +123,77 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         lse_ref[0, :] = (m_ref[:] + jnp.log(safe_l))[:, 0]
 
 
-def _resolve_blocks(block_q, block_k, s_q, s_k):
-    block_q = min(block_q, s_q)
-    block_k = min(block_k, s_k)
-    if s_q % block_q or s_k % block_k:
-        raise ValueError(
-            f"sequence lengths ({s_q}, {s_k}) must be multiples of the "
-            f"block sizes ({block_q}, {block_k})"
-        )
-    return block_q, block_k
+# -- the grid: blocks from the shapes, maps that skip with the mask -------
+
+# Where a caller names no block a kernel takes the first pair (block_q,
+# block_k) of its row that divides the sequences and fits VMEM. The
+# order is what a v5e chip gave at 8192 causal positions for 192- and
+# 256-wide bfloat16 heads (benchmarks/results/flash-forward-micro-PR36
+# .json): a grid step costs about a microsecond whatever it multiplies,
+# so the largest blocks won in all three kernels (forward 54 ms at
+# blocks of 256, 26 at 512, 16 at 1024), and a long key block more than
+# a long query block in the forward kernel.
+_BLOCK_TABLE = {
+    "fwd": ((1024, 1024), (512, 1024), (512, 512), (256, 256), (128, 128)),
+    "bwd": ((1024, 1024), (512, 512), (256, 256), (128, 128)),
+    # no cell runs these two, so they keep the block they had: the
+    # lane-grouped forward kernel, and the packed layout's backward
+    # pass, whose float32 blocks XLA holds in HBM for every head at once
+    "fwd_grouped": ((128, 128),),
+    "blockwise": ((128, 128),),
+}
+# the scoped VMEM each call asks for; the rule fills half of it and
+# leaves the rest to what it does not count (masks, the exponent's
+# temporaries, Mosaic's own scratch)
+_VMEM_LIMIT = 32 * 2**20
+_MOSAIC = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
+
+
+def _vmem_bytes(block_q, block_k, d, dv, itemsize):
+    """One grid step's working set, for any of the three kernels: the
+    operands and results of both blocks at both widths double-buffered,
+    a float32 accumulator of the larger block (with the forward's
+    lane-padded ``m`` and ``l``), and the float32 score and probability
+    blocks. Widths count as VMEM holds them, in whole 128-lane tiles."""
+    wide = -(-d // 128) * 128 + -(-dv // 128) * 128
+    return (2 * itemsize * (block_q + block_k) * wide
+            + 4 * max(block_q, block_k) * (wide + 2 * 128)
+            + 2 * 4 * block_q * block_k)
+
+
+def _resolve_blocks(block_q, block_k, s_q, s_k, d, dv, itemsize, kernel):
+    """The kernel's blocks. A block the caller names rules; one it does
+    not comes from the first pair of the kernel's row that divides the
+    sequences and fits VMEM, and a sequence that no such block divides
+    is one block if that fits."""
+    named = bool(block_q and block_k)
+    for bq, bk in _BLOCK_TABLE[kernel] + ((s_q, s_k),):
+        bq, bk = min(block_q or bq, s_q), min(block_k or bk, s_k)
+        if s_q % bq == 0 and s_k % bk == 0 and (
+                named
+                or _vmem_bytes(bq, bk, d, dv, itemsize) <= _VMEM_LIMIT // 2):
+            return bq, bk
+    raise ValueError(
+        f"sequence lengths ({s_q}, {s_k}) must be multiples of the "
+        f"block sizes ({block_q}, {block_k}), and blocks the op chooses "
+        f"must fit VMEM: pad the sequences to a multiple of 128"
+    )
+
+
+def _visible_maps(causal, block_q, block_k, nq):
+    """``(first_i, last_j)``: for a grid step ``(i, j)`` the nearest
+    query block that sees key block ``j`` and the nearest key block that
+    query block ``i`` sees. An index map that goes through them stays
+    where it is over the steps the causal mask empties, and a block that
+    does not change is not fetched again: a skipped pair's copies are
+    skipped too."""
+    if not causal:
+        return (lambda i, j: i), (lambda i, j: j)
+    first_i = lambda i, j: jnp.minimum(  # noqa: E731
+        jnp.maximum(i, j * block_k // block_q), nq - 1)
+    last_j = lambda i, j: jnp.minimum(  # noqa: E731
+        j, ((i + 1) * block_q - 1) // block_k)
+    return first_i, last_j
 
 
 def _cost(bh, s_q, s_k, d, itemsize, dv=None):
@@ -154,7 +220,8 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
     # grouped-query attention: ``group`` consecutive query heads read
     # one key/value head, through the index map (no repeated copy)
     group = bh // k.shape[0]
-    block_q, block_k = _resolve_blocks(block_q, block_k, s_q, s_k)
+    block_q, block_k = _resolve_blocks(
+        block_q, block_k, s_q, s_k, d, dv, q.dtype.itemsize, "fwd")
     grid = (bh, s_q // block_q, s_k // block_k)
     kernel = functools.partial(
         _fwd_kernel,
@@ -163,15 +230,15 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
         block_q=block_q,
         block_k=block_k,
     )
+    _, last_j = _visible_maps(causal, block_q, block_k, grid[1])
+    kv_side = lambda b, i, j: (b // group, last_j(i, j), 0)  # noqa: E731
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, block_k, d),
-                         lambda b, i, j: (b // group, j, 0)),
-            pl.BlockSpec((None, block_k, dv),
-                         lambda b, i, j: (b // group, j, 0)),
+            pl.BlockSpec((None, block_k, d), kv_side),
+            pl.BlockSpec((None, block_k, dv), kv_side),
         ],
         out_specs=[
             pl.BlockSpec((None, block_q, dv), lambda b, i, j: (b, i, 0)),
@@ -189,6 +256,7 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         cost_estimate=_cost(bh, s_q, s_k, d, q.dtype.itemsize, dv),
+        compiler_params=_MOSAIC,
         interpret=interpret,
     )(q, k, v)
     return out, lse[:, 0, :]
@@ -302,7 +370,8 @@ def _flash_forward_packed(qkv, h, d, scale, causal, block_q, block_k,
         )
     b, s, fused = qkv.shape
     assert fused == 3 * h * d, (qkv.shape, h, d)
-    block_q, block_k = _resolve_blocks(block_q, block_k, s, s)
+    block_q, block_k = _resolve_blocks(
+        block_q, block_k, s, s, d, d, qkv.dtype.itemsize, "fwd")
     grid = (b * h, s // block_q, s // block_k)
 
     kernel = functools.partial(
@@ -312,6 +381,7 @@ def _flash_forward_packed(qkv, h, d, scale, causal, block_q, block_k,
         block_q=block_q,
         block_k=block_k,
     )
+    _, last_j = _visible_maps(causal, block_q, block_k, grid[1])
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -321,11 +391,12 @@ def _flash_forward_packed(qkv, h, d, scale, causal, block_q, block_k,
             ),
             pl.BlockSpec(
                 (None, block_k, d),
-                lambda bh, i, j, h=h: (bh // h, j, h + bh % h),
+                lambda bh, i, j, h=h: (bh // h, last_j(i, j), h + bh % h),
             ),
             pl.BlockSpec(
                 (None, block_k, d),
-                lambda bh, i, j, h=h: (bh // h, j, 2 * h + bh % h),
+                lambda bh, i, j, h=h: (
+                    bh // h, last_j(i, j), 2 * h + bh % h),
             ),
         ],
         out_specs=[
@@ -344,6 +415,7 @@ def _flash_forward_packed(qkv, h, d, scale, causal, block_q, block_k,
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         cost_estimate=_cost(b * h, s, s, d, qkv.dtype.itemsize),
+        compiler_params=_MOSAIC,
         interpret=interpret,
     )(qkv, qkv, qkv)
     return out, lse[:, 0, :]
@@ -364,7 +436,9 @@ def _flash_forward_packed_grouped(qkv, h, d, scale, causal, block_q,
     )
     hp = 128 // d
     ng = h // hp  # lane groups per q/k/v region
-    block_q, block_k = _resolve_blocks(block_q, block_k, s, s)
+    block_q, block_k = _resolve_blocks(
+        block_q, block_k, s, s, hp * d, hp * d, qkv.dtype.itemsize,
+        "fwd_grouped")
     grid = (b * ng, s // block_q, s // block_k)
 
     kernel = functools.partial(
@@ -439,8 +513,8 @@ def _flash_backward(scale, causal, block_q, block_k, residuals, g):
     q, k, v, out, lse = residuals
     bh, s_q, d = q.shape
     s_k, dv = k.shape[1], v.shape[-1]  # v, out and g are ``dv`` wide
-    block_q = min(block_q, s_q)
-    block_k = min(block_k, s_k)
+    block_q, block_k = _resolve_blocks(
+        block_q, block_k, s_q, s_k, d, dv, 4, "blockwise")
     nq, nk = s_q // block_q, s_k // block_k
     f32 = jnp.float32
 
@@ -639,7 +713,9 @@ def _flash_backward_kernels(scale, causal, block_q, block_k, interpret,
     bkv, s_k, _ = k.shape
     dv = v.shape[-1]  # v, out, dO and dV; q, k, dQ and dK are ``d`` wide
     group = bh // bkv
-    block_q, block_k = _resolve_blocks(block_q, block_k, s_q, s_k)
+    # the backward kernels' own blocks: the residuals depend on none
+    block_q, block_k = _resolve_blocks(
+        block_q, block_k, s_q, s_k, d, dv, q.dtype.itemsize, "bwd")
     nq, nk = s_q // block_q, s_k // block_k
     f32 = jnp.float32
     # Δ_i = rowsum(dO ∘ O)
@@ -662,19 +738,7 @@ def _flash_backward_kernels(scale, causal, block_q, block_k, interpret,
             transcendentals=bh * s_q * s_k,
         )
 
-    # A skipped pair's copies are skipped too: the index map stays on the
-    # nearest block the mask leaves something of (the first query block
-    # that sees key block j, the last key block that query block i sees),
-    # and a block that does not change is not fetched again.
-    if causal:
-        first_i = lambda i, j: jnp.minimum(  # noqa: E731
-            jnp.maximum(i, j * block_k // block_q), nq - 1)
-        last_j = lambda i, j: jnp.minimum(  # noqa: E731
-            j, ((i + 1) * block_q - 1) // block_k)
-    else:
-        first_i = lambda i, j: i  # noqa: E731
-        last_j = lambda i, j: j  # noqa: E731
-
+    first_i, last_j = _visible_maps(causal, block_q, block_k, nq)
     q_side = lambda b, j, g_, i: (b * group + g_, first_i(i, j), 0)  # noqa: E731
     kv_side = lambda b, j, g_, i: (b, j, 0)  # noqa: E731
     row = lambda b, j, g_, i: (b * group + g_, 0, first_i(i, j))  # noqa: E731
@@ -702,6 +766,7 @@ def _flash_backward_kernels(scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_k, dv), f32),
         ],
         cost_estimate=cost(2, 2),
+        compiler_params=_MOSAIC,
         interpret=interpret,
     )(q, k, v, g, lse, delta)
 
@@ -727,6 +792,7 @@ def _flash_backward_kernels(scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_q, 1), f32),
         ],
         cost_estimate=cost(2, 1),
+        compiler_params=_MOSAIC,
         interpret=interpret,
     )(q, k, v, g, lse, delta)
     return dq, d_k, d_v
@@ -803,10 +869,6 @@ def flash_attention_qkv(
     consumes — the [B,S,·,H,D]→[·,B,H,S,D] transpose copies (the
     largest copy kernels in the r4 transformer trace, fwd and bwd)
     never exist. Differentiable (custom VJP in the same layout)."""
-    if block_q is None:
-        block_q = DEFAULT_BLOCK_Q
-    if block_k is None:
-        block_k = DEFAULT_BLOCK_K
     if scale is None:
         scale = qkv.shape[-1] ** -0.5
     if interpret is None:
@@ -826,8 +888,8 @@ def flash_attention_qkv(
             qkv_t[2].reshape(b * h, s, d),
             float(scale),
             bool(causal),
-            int(block_q),
-            int(block_k),
+            block_q and int(block_q),
+            block_k and int(block_k),
             bool(interpret),
         )
         return jnp.transpose(out.reshape(b, h, s, d), (0, 2, 1, 3))
@@ -835,8 +897,8 @@ def flash_attention_qkv(
         qkv,
         float(scale),
         bool(causal),
-        int(block_q),
-        int(block_k),
+        block_q and int(block_q),
+        block_k and int(block_k),
         bool(interpret),
     )
 
@@ -859,14 +921,11 @@ def flash_attention(
     (latent attention scores with wider heads than it sums): the
     result then has ``v``'s.
 
-    ``block_q``/``block_k`` default to the module-level
-    ``DEFAULT_BLOCK_Q``/``DEFAULT_BLOCK_K`` (resolved at CALL time, so
-    benchmarks can sweep tile sizes globally without threading
-    arguments through the model builders)."""
-    if block_q is None:
-        block_q = DEFAULT_BLOCK_Q
-    if block_k is None:
-        block_k = DEFAULT_BLOCK_K
+    A ``block_q``/``block_k`` the caller names rules the forward
+    kernel and both backward kernels. Where none is named each kernel
+    takes its own from the shapes: the largest measured blocks that
+    divide the sequences and fit VMEM (``_BLOCK_TABLE``); the result
+    does not depend on them beyond the order of float32 sums."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if interpret is None:
@@ -893,8 +952,8 @@ def flash_attention(
         merged(v, s_k),
         float(scale),
         bool(causal),
-        int(block_q),
-        int(block_k),
+        block_q and int(block_q),
+        block_k and int(block_k),
         bool(interpret),
     )
     out = out.reshape(b, h, s_q, v.shape[-1])

@@ -164,6 +164,178 @@ def test_flash_rejects_ragged_blocks():
         flash_attention(q, k, v, block_q=64, block_k=64)
 
 
+def _forward_cases():
+    """The forward kernel's grid: ``block_q != block_k`` both ways,
+    causal or not, float32 and bfloat16, under two query heads a
+    key/value head; then unequal sequence lengths (a clamped key map
+    must stay inside the keys there are), eight heads a key/value head,
+    values narrower than the scores, and no block named."""
+    cases = {}
+    for dtype in ("f32", "bf16"):
+        for mask in ("causal", "full"):
+            for bq, bk in ((8, 16), (16, 8)):
+                cases[f"{mask}-{dtype}-q{bq}-k{bk}"] = dict(
+                    causal=mask == "causal", block_q=bq, block_k=bk,
+                    dtype=jnp.float32 if dtype == "f32" else jnp.bfloat16)
+    cases["causal-sq16-sk48"] = dict(causal=True, s_q=16, s_k=48)
+    cases["causal-sq48-sk16"] = dict(causal=True, s_q=48, s_k=16)
+    cases["causal-sq48-sk16-q16-k8"] = dict(
+        causal=True, s_q=48, s_k=16, block_q=16, block_k=8)
+    cases["full-sq32-sk48"] = dict(causal=False, s_k=48)
+    cases["causal-g8"] = dict(causal=True, group=8)
+    cases["causal-bf16-d24-v16"] = dict(
+        causal=True, d=24, dv=16, dtype=jnp.bfloat16)
+    cases["full-d24-v16"] = dict(causal=False, d=24, dv=16)
+    cases["causal-rule"] = dict(causal=True, block_q=None, block_k=None)
+    cases["causal-bf16-rule"] = dict(
+        causal=True, block_q=None, block_k=None, dtype=jnp.bfloat16)
+    return cases
+
+
+@pytest.mark.parametrize("case", list(_forward_cases()))
+def test_flash_forward_grid_matches_reference(case):
+    """Whatever the grid, the forward kernel gives the float32 answer:
+    float32 inputs to the tolerance the op has always had, bfloat16
+    inputs to bfloat16's rounding of it (the output's own 2^-9, and
+    ``p``'s as it meets ``v``: at most 2^-9 of the largest value a row
+    could sum)."""
+    c = {"s_q": 32, "s_k": 32, "block_q": 8, "block_k": 16, "group": 2,
+         "dtype": jnp.float32, "d": 16, "dv": 16, **_forward_cases()[case]}
+    kv_heads, group = 2, c["group"]
+    ks = jax.random.split(jax.random.key(11), 3)
+    q = jax.random.normal(ks[0], (1, kv_heads * group, c["s_q"], c["d"]),
+                          c["dtype"])
+    k = jax.random.normal(ks[1], (1, kv_heads, c["s_k"], c["d"]), c["dtype"])
+    v = jax.random.normal(ks[2], (1, kv_heads, c["s_k"], c["dv"]), c["dtype"])
+    out = flash_attention(q, k, v, causal=c["causal"],
+                          block_q=c["block_q"], block_k=c["block_k"])
+    f32 = lambda t: t.astype(jnp.float32)  # noqa: E731
+    want = attention_reference(
+        f32(q), jnp.repeat(f32(k), group, axis=1),
+        jnp.repeat(f32(v), group, axis=1), causal=c["causal"])
+    assert out.dtype == c["dtype"] and out.shape == want.shape
+    if c["dtype"] == jnp.float32:
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(want), atol=2e-5, rtol=2e-5)
+    else:
+        worst = float(jnp.max(jnp.abs(f32(out) - want)))
+        assert worst <= 2.0 ** -8 * float(jnp.max(jnp.abs(f32(v)))), worst
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_forward_row_with_nothing_visible_is_zero(dtype):
+    """A row whose every score is masked away leaves no weight: zeros,
+    and ``lse == NEG_INF``, which ring attention's merge reads as "this
+    chunk adds nothing". The mask alone never empties a row of this
+    kernel (a query always sees key 0), so the scores are driven under
+    ``NEG_INF`` through the keys: the first query block is all ones
+    against keys of -2e30 / d; the other rows are zeros and see a plain
+    mean of the values."""
+    from elephas_tpu.ops.flash_attention import NEG_INF, _flash_forward
+
+    s, d, bq = 32, 16, 8
+    q = jnp.zeros((2, s, d), dtype).at[:, :bq].set(1)
+    k = jnp.full((2, s, d), -2e30 / d, dtype)
+    v = jax.random.normal(jax.random.key(3), (2, s, d), dtype)
+    out, lse = _flash_forward(q, k, v, 1.0, True, bq, 16, True)
+    assert not np.any(np.asarray(out[:, :bq].astype(jnp.float32)))
+    assert np.all(np.asarray(lse[:, :bq]) == NEG_INF)
+    mean = jnp.cumsum(v.astype(jnp.float32), axis=1) / jnp.arange(
+        1, s + 1)[None, :, None]
+    np.testing.assert_allclose(
+        np.asarray(out[:, bq:].astype(jnp.float32)), np.asarray(mean[:, bq:]),
+        atol=2e-2 if dtype == jnp.bfloat16 else 1e-5)
+    np.testing.assert_allclose(
+        np.asarray(lse[:, bq:]),
+        np.tile(np.log(np.arange(bq + 1, s + 1)), (2, 1)), rtol=1e-5)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_forward_and_backward_grids_may_differ(causal, monkeypatch):
+    """Where no block is named the forward kernel and the backward pair
+    take their own from the table: ``out``, ``lse`` and the residuals
+    depend on neither, so a forward grid of (16, 16) under backward
+    kernels at (8, 16) gives what blocks of 16 named for all three
+    give."""
+    import importlib
+
+    fa = importlib.import_module("elephas_tpu.ops.flash_attention")
+    monkeypatch.setitem(fa._BLOCK_TABLE, "fwd", ((16, 16),))
+    monkeypatch.setitem(fa._BLOCK_TABLE, "bwd", ((8, 16),))
+    ks = jax.random.split(jax.random.key(5), 4)
+    q = jax.random.normal(ks[0], (4, 32, 24))
+    k = jax.random.normal(ks[1], (2, 32, 24))
+    v = jax.random.normal(ks[2], (2, 32, 16))
+    g = jax.random.normal(ks[3], (4, 32, 16))
+
+    def grads(block):
+        out, vjp = jax.vjp(lambda q, k, v: fa._flash_attention_bhsd(
+            q, k, v, 24 ** -0.5, causal, block, block, True), q, k, v)
+        return (out,) + vjp(g)
+
+    for got, want, leaf in zip(grads(None), grads(16), ("out", "q", "k", "v")):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), atol=1e-5, rtol=1e-5,
+            err_msg=leaf)
+
+
+def _rule_cases():
+    """``(s_q, s_k, d, dv, itemsize, named) -> blocks`` of the forward
+    kernel and of the backward pair."""
+    return {
+        # the two LM cells' attention at 8192 positions, bfloat16
+        "latent-192-128": ((8192, 8192, 192, 128, 2, (None, None)),
+                           ((1024, 1024), (1024, 1024))),
+        "gated-256": ((8192, 8192, 256, 256, 2, (None, None)),
+                      ((1024, 1024), (1024, 1024))),
+        # float32 operands are twice as wide: the working set of blocks
+        # of 1024 passes half the VMEM the call asks for
+        "gated-256-f32": ((8192, 8192, 256, 256, 4, (None, None)),
+                          ((512, 1024), (512, 512))),
+        # shorter than any block: the sequence is the block
+        "short-100": ((100, 100, 64, 64, 4, (None, None)),
+                      ((100, 100), (100, 100))),
+        "short-q16-k48": ((16, 48, 16, 16, 4, (None, None)),
+                          ((16, 48), (16, 48))),
+        # 1536 = 3 x 512: the largest block that divides it
+        "1536": ((1536, 1536, 128, 128, 2, (None, None)),
+                 ((512, 512), (512, 512))),
+        # queries of 1536 over keys of 2048
+        "q1536-k2048": ((1536, 2048, 128, 128, 2, (None, None)),
+                        ((512, 1024), (512, 512))),
+        # nothing in the table divides 1100: one block, which fits
+        "1100": ((1100, 1100, 128, 128, 2, (None, None)),
+                 ((1100, 1100), (1100, 1100))),
+        # a named block rules all three kernels, beside a chosen one
+        "named-both": ((8192, 8192, 192, 128, 2, (64, 128)),
+                       ((64, 128), (64, 128))),
+        "named-q": ((8192, 8192, 192, 128, 2, (256, None)),
+                    ((256, 1024), (256, 1024))),
+        "named-k": ((8192, 8192, 192, 128, 2, (None, 128)),
+                    ((1024, 128), (1024, 128))),
+    }
+
+
+@pytest.mark.parametrize("case", list(_rule_cases()))
+def test_flash_block_rule(case):
+    from elephas_tpu.ops.flash_attention import _resolve_blocks
+
+    (s_q, s_k, d, dv, itemsize, named), want = _rule_cases()[case]
+    got = tuple(
+        _resolve_blocks(*named, s_q, s_k, d, dv, itemsize, kernel)
+        for kernel in ("fwd", "bwd"))
+    assert got == want
+
+
+def test_flash_block_rule_refuses_what_nothing_divides_and_fits():
+    """8200 positions: no block of the table divides them, and as one
+    block the scores alone would take half a gigabyte of VMEM."""
+    from elephas_tpu.ops.flash_attention import _resolve_blocks
+
+    with pytest.raises(ValueError, match="multiples"):
+        _resolve_blocks(None, None, 8200, 8200, 128, 128, 2, "fwd")
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_attention_matches_reference(causal):
     from jax.sharding import Mesh

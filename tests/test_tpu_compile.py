@@ -288,6 +288,36 @@ def test_flash_kernels_at_latent_attentions_two_widths():
         2 * 32 * 8192 * 256 * 4)
 
 
+@pytest.mark.parametrize("heads,kv_heads,d,dv", [
+    pytest.param(64, 64, 192, 128, id="latent-64x192-128"),
+    pytest.param(32, 4, 256, 256, id="gated-32-over-4x256"),
+])
+def test_flash_kernels_at_the_rules_blocks(heads, kv_heads, d, dv):
+    """The two LM cells' attention of one step with no block named
+    (ISSUE 36): the op takes blocks of 1024 from the shapes for the
+    forward kernel, dK/dV and dQ, and Mosaic takes all three inside the
+    scoped VMEM the calls ask for. The temporaries keep the bound they
+    had at blocks of 256: blocks live in VMEM."""
+    from elephas_tpu.ops.flash_attention import _resolve_blocks
+
+    assert [
+        _resolve_blocks(None, None, 8192, 8192, d, dv, 2, kernel)
+        for kernel in ("fwd", "bwd")
+    ] == [(1024, 1024), (1024, 1024)]
+    q = on_chip((heads, 8192, d), jnp.bfloat16)
+    k = on_chip((kv_heads, 8192, d), jnp.bfloat16)
+    v = on_chip((kv_heads, 8192, dv), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, v).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        heads * 8192 * 256 * 4 * (2 if d != dv else 1))
+
+
 def test_grouped_matmul_over_held_experts_forward_and_backward():
     """20480 slot rows over 32 held experts at hidden 2048 and twice
     the expert width: the grouped product and both of its gradients
