@@ -24,6 +24,7 @@ from elephas_tpu.models.switch import (
 )
 from elephas_tpu.models.qwen3_next import qwen3_next_lm
 from elephas_tpu.models.deepseek_v3 import deepseek_v3_lm
+from elephas_tpu.models.smallthinker import smallthinker_lm
 
 __all__ = [
     "mnist_mlp",
@@ -38,6 +39,7 @@ __all__ = [
     "switch_transformer_lm",
     "qwen3_next_lm",
     "deepseek_v3_lm",
+    "smallthinker_lm",
     "MoeFFN",
     "FlashMHA",
     "FusedLayerNorm",
@@ -49,6 +51,7 @@ __all__ = [
     "RMSNorm",
     "LatentAttention",
     "DenseMLP",
+    "BandedAttention",
 ]
 
 
@@ -66,9 +69,9 @@ def __getattr__(name):
         from elephas_tpu.models.switch import MoeFFN
 
         return MoeFFN
-    from elephas_tpu.models import deepseek_v3, qwen3_next
+    from elephas_tpu.models import deepseek_v3, qwen3_next, smallthinker
 
-    for module in (qwen3_next, deepseek_v3):
+    for module in (qwen3_next, deepseek_v3, smallthinker):
         if name in module.LAYER_NAMES:
             return getattr(module, name)
     raise AttributeError(name)

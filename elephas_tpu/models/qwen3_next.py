@@ -350,11 +350,18 @@ def _layers():
         that use it is the builder's to set: the router's rule
         (``scoring_func``, ``routed_scaling_factor`` and, with
         ``selection_bias``, a non-trainable ``e_score_correction_bias``
-        added for the choice alone; :func:`elephas_tpu.ops.moe.route_top_k`)
-        and whether the shared expert lies under a sigmoid gate
-        (``gated_shared_expert``). ``epoch_counters`` tells the epoch
-        runner which variable adds up, call by call, what the block
-        routed, and what its entries are."""
+        added for the choice alone; :func:`elephas_tpu.ops.moe.route_top_k`),
+        whether the shared expert lies under a sigmoid gate
+        (``gated_shared_expert``) or is absent (``shared_width`` 0: the
+        block is its routed part alone and has no ``shared_expert``
+        variables), and the routed experts' gate activation
+        (``hidden_act``: ``silu`` for SwiGLU experts, ``relu`` for
+        ReGLU). ``layer(x, route_from)`` hands the router a tensor of
+        its own (a router that stands before attention scores the
+        decoder layer's input; the experts take ``x``); ``layer(x)``
+        routes from ``x``. ``epoch_counters`` tells the epoch runner
+        which variable adds up, call by call, what the block routed,
+        and what its entries are."""
 
         epoch_counters = {"route_counts": COUNTER_NAMES}
 
@@ -364,19 +371,27 @@ def _layers():
                      scoring_func: str = "softmax",
                      selection_bias: bool = False,
                      routed_scaling_factor: float = 1.0,
-                     gated_shared_expert: bool = True, **kwargs):
+                     gated_shared_expert: bool = True,
+                     hidden_act: str = "silu", **kwargs):
             super().__init__(**kwargs)
-            from elephas_tpu.ops.moe import ROUTER_SCORES
+            from elephas_tpu.ops.moe import EXPERT_ACTIVATIONS, ROUTER_SCORES
 
             if scoring_func not in ROUTER_SCORES:
                 raise ValueError(
                     f"scoring_func {scoring_func!r} is none of "
                     f"{sorted(ROUTER_SCORES)}"
                 )
+            if hidden_act not in EXPERT_ACTIVATIONS:
+                raise ValueError(
+                    f"hidden_act {hidden_act!r} is none of "
+                    f"{sorted(EXPERT_ACTIVATIONS)}"
+                )
+            self.hidden_act = hidden_act
             self.scoring_func, self.selection_bias = (
                 scoring_func, bool(selection_bias))
             self.routed_scaling_factor = float(routed_scaling_factor)
-            self.gated_shared_expert = bool(gated_shared_expert)
+            self.gated_shared_expert = bool(
+                gated_shared_expert and shared_width)
             first, stop = experts_held or (0, num_experts)
             if not 0 <= first < stop <= num_experts:
                 raise ValueError(
@@ -411,16 +426,17 @@ def _layers():
                     shape=(self.num_experts,), dtype="float32",
                     initializer="zeros", trainable=False, autocast=False,
                 )
-            self.shared_expert = SwiGLU(
-                self.shared_width, self.init_std, name="shared_expert")
-            self.shared_expert.build(input_shape)
+            if self.shared_width:
+                self.shared_expert = SwiGLU(
+                    self.shared_width, self.init_std, name="shared_expert")
+                self.shared_expert.build(input_shape)
             self.route_counts = self.add_weight(
                 name="route_counts", shape=(len(COUNTER_NAMES),),
                 dtype="int32", initializer="zeros", trainable=False,
                 autocast=False,
             )
 
-        def _forward(self, x):
+        def _forward(self, x, route_from=None):
             from elephas_tpu.ops.moe import held_experts_ffn
 
             b, s, d = jnp.shape(x)[0], x.shape[1], x.shape[2]
@@ -429,11 +445,16 @@ def _layers():
                        "scale": self.routed_scaling_factor}
             if self.selection_bias:
                 routing["select_bias"] = self.e_score_correction_bias.value
+            if route_from is not None:
+                routing["route_from"] = route_from.reshape(b * s, d)
             routed, counts = held_experts_ffn(
                 flat, self.router.value, self.experts_gate_up.value,
                 self.experts_down.value, self.experts_held,
-                self.experts_per_token, **routing,
+                self.experts_per_token, activation=self.hidden_act,
+                **routing,
             )
+            if not self.shared_width:
+                return routed.reshape(b, s, d), counts
             with jax.named_scope("moe.shared"):
                 shared = self.shared_expert(flat).astype(f32)
                 if self.gated_shared_expert:
@@ -442,8 +463,9 @@ def _layers():
             y = (routed.astype(f32) + shared).astype(x.dtype)
             return y.reshape(b, s, d), counts
 
-        def call(self, x):
-            y, counts = self._rematted()(x)
+        def call(self, x, route_from=None):
+            inputs = (x,) if route_from is None else (x, route_from)
+            y, counts = self._rematted()(*inputs)
             # outside the rematerialised part: a variable is written once
             self.route_counts.assign(self.route_counts.value + counts)
             return y
@@ -459,6 +481,7 @@ def _layers():
                     "selection_bias": self.selection_bias,
                     "routed_scaling_factor": self.routed_scaling_factor,
                     "gated_shared_expert": self.gated_shared_expert,
+                    "hidden_act": self.hidden_act,
                     "remat": self.remat}
 
     @register

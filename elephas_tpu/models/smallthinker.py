@@ -1,0 +1,211 @@
+"""Window-and-full-attention mixture-of-experts LM (the SmallThinker
+block, as ``SmallThinker-21BA3B-Instruct`` publishes it).
+
+What the block adds to the zoo, beside the layers it shares with
+:mod:`elephas_tpu.models.qwen3_next` (``SparseMoeBlock``, ``LMHead``,
+``next_token_loss``) and :mod:`elephas_tpu.models.deepseek_v3`
+(``RMSNorm``):
+
+- :class:`BandedAttention`: grouped-query causal attention with no
+  gate, no bias and no q/k norm, whose two per-layer settings are the
+  model's own: ``window`` (a query sees itself and the ``window - 1``
+  keys before it; None is full causal attention) and ``rotary`` (the
+  rotary embedding over the whole head, pairs ``(i, i + head_dim /
+  2)``; without it the layer has no position term at all). The flash
+  kernels skip the block pairs below the band
+  (:func:`elephas_tpu.ops.flash_attention.flash_attention`,
+  ``window``), under the scope ``attn.window``; a full layer runs under
+  ``attn.full``.
+- a decoder layer whose router stands before attention: it scores the
+  layer's input ``h`` (the residual stream, un-normed), while the
+  experts take the normed attention result; the shared
+  ``SparseMoeBlock`` is called with both tensors, holds no shared
+  expert (``shared_width`` 0) and its experts are ReGLU
+  (``hidden_act`` ``relu``).
+
+Decoder layer ``l``: ``h1 = h + attn_l(norm(h)); h' = h1 +
+moe(norm(h1), route_from=h)``, with ``sliding_window_layout[l]`` and
+``rope_layout[l]`` choosing the attention's window and rotation; a
+final norm and an untied head. ``fit`` only: a cache whose layers
+differ (serving) is not here.
+"""
+
+from __future__ import annotations
+
+from elephas_tpu.models import deepseek_v3, qwen3_next
+from elephas_tpu.models.qwen3_next import next_token_loss
+from elephas_tpu.models.transformer import (
+    _apply_rope,
+    _dtype_policy_scope,
+    _keras,
+    _rope_tables,
+)
+
+_LAYERS = None
+LAYER_NAMES = ("BandedAttention",)
+
+
+def _layers():
+    """This module's layer class, created lazily (keras under the jax
+    backend first) and registered with Keras's serializer."""
+    global _LAYERS
+    if _LAYERS is not None:
+        return _LAYERS
+    import jax
+    import jax.numpy as jnp
+    import keras
+
+    _Remat = qwen3_next._layers()["_Remat"]
+    register = keras.saving.register_keras_serializable(package="elephas_tpu")
+    f32 = jnp.float32
+
+    @register
+    class BandedAttention(_Remat):
+        def __init__(self, num_heads: int, num_kv_heads: int, head_dim: int,
+                     window: int | None = None, rotary: bool = True,
+                     rope_theta: float = 10000.0, init_std: float = 0.02,
+                     **kwargs):
+            super().__init__(**kwargs)
+            if num_heads % num_kv_heads or head_dim % 2:
+                raise ValueError(
+                    f"{num_heads} query heads over {num_kv_heads} key/value "
+                    f"heads of width {head_dim}"
+                )
+            if window is not None and window < 1:
+                raise ValueError(f"window {window!r} holds no key")
+            self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+            self.head_dim, self.window = head_dim, window
+            self.rotary, self.rope_theta = bool(rotary), rope_theta
+            self.init_std = init_std
+
+        def build(self, input_shape):
+            d, hd = int(input_shape[-1]), self.head_dim
+            init = keras.initializers.RandomNormal(stddev=self.init_std)
+            self.q_proj = self._weight("q_proj", (d, self.num_heads * hd), init)
+            self.k_proj = self._weight(
+                "k_proj", (d, self.num_kv_heads * hd), init)
+            self.v_proj = self._weight(
+                "v_proj", (d, self.num_kv_heads * hd), init)
+            self.o_proj = self._weight("o_proj", (self.num_heads * hd, d), init)
+
+        def _forward(self, x):
+            from elephas_tpu.ops.flash_attention import flash_attention
+
+            b, s = jnp.shape(x)[0], x.shape[1]
+            h, hk, hd = self.num_heads, self.num_kv_heads, self.head_dim
+            with jax.named_scope("attn.proj"):
+                q = jnp.matmul(x, self.q_proj.value).reshape(b, s, h, hd)
+                k = jnp.matmul(x, self.k_proj.value).reshape(b, s, hk, hd)
+                v = jnp.matmul(x, self.v_proj.value).reshape(b, s, hk, hd)
+                if self.rotary:
+                    cos, sin = _rope_tables(s, hd, float(self.rope_theta))
+                    cos, sin = cos[None, :, None], sin[None, :, None]
+                    q, k = (_apply_rope(t.astype(f32), cos, sin).astype(
+                        x.dtype) for t in (q, k))
+            with jax.named_scope(
+                    "attn.full" if self.window is None else "attn.window"):
+                heads_first = lambda t: jnp.transpose(t, (0, 2, 1, 3))  # noqa: E731
+                out = flash_attention(
+                    heads_first(q), heads_first(k), heads_first(v),
+                    causal=True, scale=hd ** -0.5, window=self.window,
+                )
+                out = heads_first(out).reshape(b, s, h * hd)
+            with jax.named_scope("attn.proj"):
+                return jnp.matmul(out, self.o_proj.value)
+
+        def get_config(self):
+            return {**super().get_config(), "num_heads": self.num_heads,
+                    "num_kv_heads": self.num_kv_heads,
+                    "head_dim": self.head_dim, "window": self.window,
+                    "rotary": self.rotary, "rope_theta": self.rope_theta,
+                    "init_std": self.init_std, "remat": self.remat}
+
+    _LAYERS = {"BandedAttention": BandedAttention}
+    return _LAYERS
+
+
+def __getattr__(name):
+    if name in LAYER_NAMES:
+        return _layers()[name]
+    raise AttributeError(name)
+
+
+def smallthinker_lm(
+    vocab_size: int = 1024,
+    maxlen: int = 128,
+    hidden_size: int = 64,
+    num_hidden_layers: int = 4,
+    num_attention_heads: int = 4,
+    num_key_value_heads: int = 2,
+    head_dim: int = 32,
+    sliding_window_size: int = 32,
+    sliding_window_layout=(0, 1, 1, 1),
+    rope_layout=(0, 1, 1, 1),
+    rope_theta: float = 1.5e6,
+    moe_num_primary_experts: int = 16,
+    moe_num_active_primary_experts: int = 2,
+    moe_ffn_hidden_size: int = 32,
+    experts_held=None,
+    rms_norm_eps: float = 1e-6,
+    init_std: float = 0.02,
+    lr: float = 0.01,
+    momentum: float = 0.9,
+    seed: int = 0,
+    dtype_policy: str | None = None,
+    remat: bool = False,
+):
+    """Decoder-only LM that mixes window and full attention: layer
+    ``l`` attends within ``sliding_window_size`` keys where
+    ``sliding_window_layout[l]`` is 1 and over the whole causal past
+    where it is 0, and rotates its queries and keys where
+    ``rope_layout[l]`` is 1 (a layer with 0 has no position term);
+    every layer ends in a sparse block of ReGLU experts with no shared
+    expert, whose softmax router scores the layer's input, before
+    attention. The argument names are the published config's; the two
+    layouts give at least ``num_hidden_layers`` entries.
+
+    ``experts_held = (first, stop)`` and ``remat`` as for
+    :func:`elephas_tpu.models.qwen3_next.qwen3_next_lm`: this chip's
+    share of the routed experts, and every attention layer and sparse
+    block keeping its inputs alone for the backward pass. Compiled with
+    SGD (``lr``, ``momentum``) and next-token cross-entropy over float32
+    logits."""
+    if min(len(sliding_window_layout), len(rope_layout)) < num_hidden_layers:
+        raise ValueError(
+            f"{num_hidden_layers} layers need as many entries of "
+            f"sliding_window_layout and rope_layout"
+        )
+    keras = _keras()
+    keras.utils.set_random_seed(seed)
+    with _dtype_policy_scope(keras, dtype_policy):
+        shared, L = qwen3_next._layers(), _layers()
+        Norm = deepseek_v3._layers()["RMSNorm"]
+        inputs = keras.Input((maxlen,), dtype="int32")
+        x = keras.layers.Embedding(
+            vocab_size, hidden_size, name="embed_tokens",
+            embeddings_initializer=keras.initializers.RandomNormal(
+                stddev=init_std),
+        )(inputs)
+        for i in range(num_hidden_layers):
+            h = Norm(rms_norm_eps, name=f"layer{i}_input_norm")(x)
+            attended = x + L["BandedAttention"](
+                num_attention_heads, num_key_value_heads, head_dim,
+                sliding_window_size if sliding_window_layout[i] else None,
+                bool(rope_layout[i]), rope_theta, init_std, remat=remat,
+                name=f"layer{i}_attn",
+            )(h)
+            h = Norm(rms_norm_eps, name=f"layer{i}_post_norm")(attended)
+            # the router reads the layer's input, the experts ``h``
+            x = attended + shared["SparseMoeBlock"](
+                moe_num_primary_experts, moe_num_active_primary_experts,
+                moe_ffn_hidden_size, 0, experts_held, init_std,
+                hidden_act="relu", remat=remat, name=f"layer{i}_moe",
+            )(h, x)
+        x = Norm(rms_norm_eps, name="final_norm")(x)
+        outputs = shared["LMHead"](vocab_size, init_std, name="lm_head")(x)
+        model = keras.Model(inputs, outputs, name="smallthinker_lm")
+    model.compile(
+        optimizer=keras.optimizers.SGD(lr, momentum=momentum),
+        loss=next_token_loss,
+    )
+    return model

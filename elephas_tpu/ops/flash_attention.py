@@ -13,7 +13,10 @@ layout they are two Pallas kernels in the forward's style (dK/dV, then
 dQ): score blocks stay in VMEM, operands reach the MXU in the inputs'
 dtype, causally empty block pairs are skipped (their copies too, in
 all three kernels: the index maps stay on the last visible block), and a
-key/value head that several query heads share is read in place. Where a
+key/value head that several query heads share is read in place. A
+``window`` narrows the causal mask to a band (a query sees itself and
+the ``window - 1`` keys before it): the block pairs below the band are
+skipped as those above the diagonal are, products and copies both. Where a
 caller names no block each kernel takes the largest measured blocks that
 divide the sequences and fit VMEM (``_BLOCK_TABLE``). The packed qkv
 layout still evaluates them blockwise under ``lax.scan``, XLA-fused
@@ -50,7 +53,8 @@ NEG_INF = -1e30
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, scale: float, causal: bool, block_q: int, block_k: int):
+                *, scale: float, causal: bool, block_q: int, block_k: int,
+                window: int | None = None):
     # refs arrive squeezed to [BQ, D] / [BK, D] / [BQ, D] / [1, BQ]
     # (BlockSpec ``None`` dims), so one kernel serves both the separate
     # [BH, S, D] layout and the packed [B, S, 3, H, D] qkv layout
@@ -83,7 +87,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
             cols = j * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1
             )
-            s = jnp.where(cols <= rows, s, NEG_INF)
+            s = jnp.where(_seen(rows, cols, window), s, NEG_INF)
 
         m_prev = m_ref[:]  # [BQ, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -106,10 +110,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         m_ref[:] = m_new
 
     if causal:
-        # a key block wholly ahead of the query block adds nothing (its
-        # scores are all masked, so the branch above leaves the
-        # accumulators as they are): skip its two products
-        pl.when(j * block_k < (i + 1) * block_q)(_accumulate)
+        # a key block wholly ahead of the query block, or wholly behind
+        # its window, adds nothing (its scores are all masked, so the
+        # branch above leaves the accumulators as they are): skip its
+        # two products
+        pl.when(_pair_seen(i, j, block_q, block_k, window))(_accumulate)
     else:
         _accumulate()
 
@@ -180,19 +185,49 @@ def _resolve_blocks(block_q, block_k, s_q, s_k, d, dv, itemsize, kernel):
     )
 
 
-def _visible_maps(causal, block_q, block_k, nq):
+def _seen(rows, cols, window):
+    """The causal mask over positions ``rows`` (queries) and ``cols``
+    (keys), narrowed to the band of ``window`` keys where one is set."""
+    seen = cols <= rows
+    if window is not None:
+        seen &= rows - cols < window
+    return seen
+
+
+def _pair_seen(i, j, block_q, block_k, window):
+    """Whether the causal mask (and the band) leaves anything of the
+    block pair ``(i, j)``."""
+    seen = j * block_k < (i + 1) * block_q
+    if window is not None:
+        seen &= (j + 1) * block_k + window - 1 > i * block_q
+    return seen
+
+
+def _visible_maps(causal, block_q, block_k, nq, window=None, nk=None):
     """``(first_i, last_j)``: for a grid step ``(i, j)`` the nearest
     query block that sees key block ``j`` and the nearest key block that
     query block ``i`` sees. An index map that goes through them stays
     where it is over the steps the causal mask empties, and a block that
     does not change is not fetched again: a skipped pair's copies are
-    skipped too."""
+    skipped too. With a ``window`` the steps below the band are emptied
+    as well, and the maps stay inside it from both sides (``nk`` key
+    blocks in all)."""
     if not causal:
         return (lambda i, j: i), (lambda i, j: j)
-    first_i = lambda i, j: jnp.minimum(  # noqa: E731
-        jnp.maximum(i, j * block_k // block_q), nq - 1)
-    last_j = lambda i, j: jnp.minimum(  # noqa: E731
-        j, ((i + 1) * block_q - 1) // block_k)
+
+    def first_i(i, j):
+        if window is not None:
+            # the last query that sees the block's last key
+            i = jnp.minimum(i, ((j + 1) * block_k + window - 2) // block_q)
+        return jnp.minimum(jnp.maximum(i, j * block_k // block_q), nq - 1)
+
+    def last_j(i, j):
+        if window is not None:
+            # the first key that the block's first query sees, if any
+            first = jnp.maximum(i * block_q - window + 1, 0) // block_k
+            j = jnp.minimum(jnp.maximum(j, first), nk - 1)
+        return jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
+
     return first_i, last_j
 
 
@@ -211,7 +246,8 @@ def _cost(bh, s_q, s_k, d, itemsize, dv=None):
     )
 
 
-def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
+def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret,
+                   window=None):
     """[BH, S, D] inputs → (out [BH, S, Dv], lse [BH, S]); ``k`` and
     ``v`` may have a whole fraction of ``q``'s heads (``[BH / G, S, D]``),
     and ``v`` a width of its own (``[BH / G, S, Dv]``)."""
@@ -229,8 +265,10 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
         causal=causal,
         block_q=block_q,
         block_k=block_k,
+        window=window,
     )
-    _, last_j = _visible_maps(causal, block_q, block_k, grid[1])
+    _, last_j = _visible_maps(
+        causal, block_q, block_k, grid[1], window, grid[2])
     kv_side = lambda b, i, j: (b // group, last_j(i, j), 0)  # noqa: E731
     out, lse = pl.pallas_call(
         kernel,
@@ -503,13 +541,14 @@ def packed_layout_supported(d: int, h: int) -> bool:
 # -- blockwise backward (flash recurrences, XLA-fused): packed layout ----
 
 
-def _causal_mask(i, j, block_q, block_k):
+def _causal_mask(i, j, block_q, block_k, window=None):
     rows = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
     cols = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    return cols <= rows
+    return _seen(rows, cols, window)
 
 
-def _flash_backward(scale, causal, block_q, block_k, residuals, g):
+def _flash_backward(scale, causal, block_q, block_k, residuals, g,
+                    window=None):
     q, k, v, out, lse = residuals
     bh, s_q, d = q.shape
     s_k, dv = k.shape[1], v.shape[-1]  # v, out and g are ``dv`` wide
@@ -531,7 +570,8 @@ def _flash_backward(scale, causal, block_q, block_k, residuals, g):
     def p_block(i, j, qi, kj, li):
         s = jnp.einsum("bqd,bkd->bqk", qi, kj, preferred_element_type=f32) * scale
         if causal:
-            s = jnp.where(_causal_mask(i, j, block_q, block_k)[None], s, NEG_INF)
+            s = jnp.where(
+                _causal_mask(i, j, block_q, block_k, window)[None], s, NEG_INF)
         p = jnp.exp(s - li[..., None])  # [bh, BQ, BK]
         # fully-masked rows carry lse == NEG_INF; exp(s - lse) would be 1
         return jnp.where(li[..., None] <= NEG_INF * 0.5, 0.0, p)
@@ -608,7 +648,8 @@ def _flash_backward_packed(scale, causal, block_q, block_k, residuals, g):
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
-                    causal: bool, block_q: int, block_k: int):
+                    causal: bool, block_q: int, block_k: int,
+                    window: int | None = None):
     """dK and dV of one key block of one key/value head, summed over the
     query heads that share it (grid axis 2) and the query blocks (axis
     3). Scores are held transposed, ``[BK, BQ]``: ``lse`` and ``delta``
@@ -635,7 +676,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 jnp.int32, (block_k, block_q), 0)
             queries = i * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_k, block_q), 1)
-            st = jnp.where(keys <= queries, st, NEG_INF)
+            st = jnp.where(_seen(queries, keys, window), st, NEG_INF)
         lse = lse_ref[:]  # [1, BQ]
         # fully-masked rows carry lse == NEG_INF; exp(s - lse) would be 1
         pt = jnp.where(lse <= NEG_INF * 0.5, 0.0, jnp.exp(st - lse))
@@ -648,8 +689,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dst.astype(q.dtype), q, nn, preferred_element_type=jnp.float32)
 
     if causal:
-        # a query block wholly behind the key block sees none of it
-        pl.when(j * block_k < (i + 1) * block_q)(_accumulate)
+        # a query block wholly behind the key block, or wholly past its
+        # window, sees none of it
+        pl.when(_pair_seen(i, j, block_q, block_k, window))(_accumulate)
     else:
         _accumulate()
 
@@ -661,7 +703,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_acc, lse_col, delta_col, *, scale: float,
-                   causal: bool, block_q: int, block_k: int):
+                   causal: bool, block_q: int, block_k: int,
+                   window: int | None = None):
     """dQ of one query block, summed over the key blocks (grid axis 2).
     ``lse`` and ``delta`` arrive as ``[1, BQ]`` rows and are turned to
     ``[BQ, 1]`` columns once a query block."""
@@ -681,7 +724,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(
             q, k, nt, preferred_element_type=jnp.float32) * scale
         if causal:
-            s = jnp.where(_causal_mask(i, j, block_q, block_k), s, NEG_INF)
+            s = jnp.where(
+                _causal_mask(i, j, block_q, block_k, window), s, NEG_INF)
         lse = lse_col[:]  # [BQ, 1]
         p = jnp.where(lse <= NEG_INF * 0.5, 0.0, jnp.exp(s - lse))
         dp = jax.lax.dot_general(
@@ -692,7 +736,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32)
 
     if causal:
-        pl.when(j * block_k < (i + 1) * block_q)(_accumulate)
+        pl.when(_pair_seen(i, j, block_q, block_k, window))(_accumulate)
     else:
         _accumulate()
 
@@ -702,12 +746,13 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_backward_kernels(scale, causal, block_q, block_k, interpret,
-                            residuals, g):
+                            residuals, g, window=None):
     """The flash recurrences of :func:`_flash_backward` as two Pallas
     kernels. Operands reach the MXU in the dtype the inputs came in and
     every product accumulates in float32; a block pair the causal mask
-    empties is skipped; a key/value head shared by ``group`` query
-    heads is read in place and its gradient summed in the kernel."""
+    (or the ``window``'s band) empties is skipped; a key/value head
+    shared by ``group`` query heads is read in place and its gradient
+    summed in the kernel."""
     q, k, v, out, lse = residuals
     bh, s_q, d = q.shape
     bkv, s_k, _ = k.shape
@@ -722,7 +767,7 @@ def _flash_backward_kernels(scale, causal, block_q, block_k, interpret,
     delta = jnp.sum(g.astype(f32) * out.astype(f32), axis=-1)
     lse, delta = lse[:, None, :], delta[:, None, :]  # [BH, 1, S] rows
     params = dict(scale=scale, causal=causal, block_q=block_q,
-                  block_k=block_k)
+                  block_k=block_k, window=window)
     concrete = all(type(t) is int for t in (bh, s_q, s_k, d, dv))
 
     def cost(score_wide, value_wide):
@@ -738,7 +783,8 @@ def _flash_backward_kernels(scale, causal, block_q, block_k, interpret,
             transcendentals=bh * s_q * s_k,
         )
 
-    first_i, last_j = _visible_maps(causal, block_q, block_k, nq)
+    first_i, last_j = _visible_maps(
+        causal, block_q, block_k, nq, window, nk)
     q_side = lambda b, j, g_, i: (b * group + g_, first_i(i, j), 0)  # noqa: E731
     kv_side = lambda b, j, g_, i: (b, j, 0)  # noqa: E731
     row = lambda b, j, g_, i: (b * group + g_, 0, first_i(i, j))  # noqa: E731
@@ -801,20 +847,24 @@ def _flash_backward_kernels(scale, causal, block_q, block_k, interpret,
 # -- public op ---------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_attention_bhsd(q, k, v, scale, causal, block_q, block_k, interpret):
-    out, _ = _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_attention_bhsd(q, k, v, scale, causal, block_q, block_k, interpret,
+                          window=None):
+    out, _ = _flash_forward(
+        q, k, v, scale, causal, block_q, block_k, interpret, window)
     return out
 
 
-def _fwd_rule(q, k, v, scale, causal, block_q, block_k, interpret):
-    out, lse = _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret)
+def _fwd_rule(q, k, v, scale, causal, block_q, block_k, interpret, window):
+    out, lse = _flash_forward(
+        q, k, v, scale, causal, block_q, block_k, interpret, window)
     return out, (q, k, v, out, lse)
 
 
-def _bwd_rule(scale, causal, block_q, block_k, interpret, residuals, g):
+def _bwd_rule(scale, causal, block_q, block_k, interpret, window, residuals,
+              g):
     return _flash_backward_kernels(
-        scale, causal, block_q, block_k, interpret, residuals, g
+        scale, causal, block_q, block_k, interpret, residuals, g, window
     )
 
 
@@ -912,6 +962,7 @@ def flash_attention(
     block_q: int | None = None,
     block_k: int | None = None,
     interpret: bool | None = None,
+    window: int | None = None,
 ):
     """Blockwise attention. ``q/k/v``: ``[batch, heads, seq, head_dim]``
     (or ``[bh, seq, head_dim]``). Differentiable; O(seq) memory.
@@ -919,7 +970,11 @@ def flash_attention(
     attention): ``heads_q / heads_kv`` consecutive query heads then
     share one key/value head. ``v`` may have a head width of its own
     (latent attention scores with wider heads than it sums): the
-    result then has ``v``'s.
+    result then has ``v``'s. ``window`` (causal attention only) is a
+    sliding window: query ``i`` sees the keys ``j <= i`` with ``i - j <
+    window``, so ``window`` keys with its own; the block pairs that
+    the band leaves nothing of are skipped, forward and backward. None
+    is plain causal attention, on the grid it has always had.
 
     A ``block_q``/``block_k`` the caller names rules the forward
     kernel and both backward kernels. Where none is named each kernel
@@ -945,6 +1000,11 @@ def flash_attention(
             f"queries are {d} wide and keys {k.shape[-1]}: a score needs "
             f"one width (the values' may differ)"
         )
+    if window is not None and (not causal or int(window) < 1):
+        raise ValueError(
+            f"window {window!r}: a sliding window is a positive number "
+            f"of keys under the causal mask"
+        )
     merged = lambda t, s: t.reshape(-1, s, t.shape[-1])  # noqa: E731
     out = _flash_attention_bhsd(
         merged(q, s_q),
@@ -955,13 +1015,17 @@ def flash_attention(
         block_q and int(block_q),
         block_k and int(block_k),
         bool(interpret),
+        window and int(window),
     )
     out = out.reshape(b, h, s_q, v.shape[-1])
     return out[0] if squeeze else out
 
 
-def attention_reference(q, k, v, causal: bool = False, scale: float | None = None):
-    """Naive O(S²)-memory attention — the correctness oracle for tests."""
+def attention_reference(q, k, v, causal: bool = False,
+                        scale: float | None = None,
+                        window: int | None = None):
+    """Naive O(S²)-memory attention — the correctness oracle for tests.
+    ``window``: as :func:`flash_attention`'s."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     s = jnp.einsum("...qd,...kd->...qk", q, k).astype(jnp.float32) * scale
@@ -969,6 +1033,6 @@ def attention_reference(q, k, v, causal: bool = False, scale: float | None = Non
         s_q, s_k = s.shape[-2], s.shape[-1]
         rows = jax.lax.broadcasted_iota(jnp.int32, (s_q, s_k), 0)
         cols = jax.lax.broadcasted_iota(jnp.int32, (s_q, s_k), 1)
-        s = jnp.where(cols <= rows, s, NEG_INF)
+        s = jnp.where(_seen(rows, cols, window), s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("...qk,...kd->...qd", p, v.astype(jnp.float32)).astype(q.dtype)

@@ -235,6 +235,8 @@ def init_moe_params(
 
 
 ROUTER_SCORES = {"softmax": jax.nn.softmax, "sigmoid": jax.nn.sigmoid}
+# the gate's activation in a held expert: SwiGLU's or ReGLU's
+EXPERT_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def route_top_k(x, router_w, k: int, score: str = "softmax",
@@ -354,7 +356,8 @@ def _combine_slots_bwd(residuals, g):
 _combine_slots.defvjp(_combine_slots_fwd, _combine_slots_bwd)
 
 
-def _held_part(x, weights, local, w_gate_up, w_down, rows: int, held: int):
+def _held_part(x, weights, local, w_gate_up, w_down, rows: int, held: int,
+               activation=jax.nn.silu):
     """The held experts' part for tokens ``x [T, D]`` through a slot
     buffer of ``rows`` rows, which must hold every slot routed to a
     held expert (``local < held``; ``local [T, k]`` is the expert's
@@ -379,7 +382,7 @@ def _held_part(x, weights, local, w_gate_up, w_down, rows: int, held: int):
     with jax.named_scope("moe.experts"):
         gate_up = grouped_matmul(slots, w_gate_up, group_sizes)
         gate, up = jnp.split(gate_up, 2, axis=-1)
-        hidden = (jax.nn.silu(gate.astype(jnp.float32))
+        hidden = (activation(gate.astype(jnp.float32))
                   * up.astype(jnp.float32)).astype(x.dtype)
         out = grouped_matmul(hidden, w_down, group_sizes)
     with jax.named_scope("moe.route"):
@@ -393,12 +396,16 @@ def _round_up(n: int, to: int) -> int:
 
 
 def held_experts_ffn(x, router_w, w_gate_up, w_down, experts_held, k: int,
-                     **routing):
+                     route_from=None, activation: str = "silu", **routing):
     """The routed part of a sparse block that the experts held here
-    give: ``sum_e p_e * down_e(silu(gate_e(x)) * up_e(x))`` over the
+    give: ``sum_e p_e * down_e(act(gate_e(x)) * up_e(x))`` over the
     token's ``k`` chosen experts that lie in ``experts_held``;
     ``routing`` is the router's rule, as :func:`route_top_k` takes it
-    (``score``, ``select_bias``, ``scale``).
+    (``score``, ``select_bias``, ``scale``). ``activation`` names
+    ``act`` (``silu``: SwiGLU experts; ``relu``: ReGLU). The router
+    reads ``route_from [T, D]`` where one is given (a model whose
+    router stands before attention scores the layer's input while its
+    experts take the normed attention result ``x``), else ``x``.
 
     ``x [T, D]``; ``router_w [D, E]`` scores all ``E`` experts;
     ``experts_held = (first, stop)`` is the range of them whose weights
@@ -422,7 +429,8 @@ def held_experts_ffn(x, router_w, w_gate_up, w_down, experts_held, k: int,
     held = stop - first
     tokens = x.shape[0]
     with jax.named_scope("moe.route"):
-        weights, experts = route_top_k(x, router_w, k, **routing)
+        weights, experts = route_top_k(
+            x if route_from is None else route_from, router_w, k, **routing)
         local = jnp.where(
             (experts >= first) & (experts < stop), experts - first, held
         )
@@ -431,7 +439,9 @@ def held_experts_ffn(x, router_w, w_gate_up, w_down, experts_held, k: int,
         routed_here = jnp.sum(group_sizes)
     w_gate_up, w_down = w_gate_up.astype(x.dtype), w_down.astype(x.dtype)
     part = jax.checkpoint(
-        functools.partial(_held_part, held=held), static_argnums=(5,)
+        functools.partial(_held_part, held=held,
+                          activation=EXPERT_ACTIVATIONS[activation]),
+        static_argnums=(5,)
     )
     every = tokens * k
     usual = _round_up(2 * every * held // router_w.shape[-1], 8)

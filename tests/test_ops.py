@@ -336,6 +336,155 @@ def test_flash_block_rule_refuses_what_nothing_divides_and_fits():
         _resolve_blocks(None, None, 8200, 8200, 128, 128, 2, "fwd")
 
 
+def _window_cases():
+    """A sliding window over 48 positions: windows that are no multiple
+    of a block, of one key, of a whole block, and past the sequence;
+    ``block_q != block_k`` both ways, equal blocks and the rule's; one
+    and seven query heads a key/value head; float32 and bfloat16."""
+    cases = {}
+    for window in (1, 5, 13, 16, 29, 48, 100):
+        for bq, bk in ((8, 16), (16, 8)):
+            cases[f"w{window}-g7-q{bq}-k{bk}"] = dict(
+                window=window, block_q=bq, block_k=bk)
+    cases["w13-g1-q16-k16"] = dict(window=13, group=1, block_q=16, block_k=16)
+    cases["w13-g7-rule"] = dict(window=13, block_q=None, block_k=None)
+    cases["w13-g7-bf16-q8-k16"] = dict(
+        window=13, block_q=8, block_k=16, dtype=jnp.bfloat16)
+    cases["w5-g2-d24-v16-q16-k8"] = dict(
+        window=5, group=2, d=24, dv=16, block_q=16, block_k=8)
+    # keys that no query reaches, and queries past every key's window
+    cases["w13-g2-sq16-sk48"] = dict(window=13, group=2, s_q=16)
+    cases["w13-g2-sq48-sk16"] = dict(window=13, group=2, s_k=16)
+    return cases
+
+
+@pytest.mark.parametrize("case", list(_window_cases()))
+def test_flash_window_forward_and_backward_match_reference(case):
+    """The forward kernel, dK/dV and dQ under a ``window`` against
+    ``jax.vjp`` of the naive oracle with the same band, and the
+    blockwise rule (the packed layout's) against the same."""
+    from elephas_tpu.ops.flash_attention import (
+        _flash_backward,
+        _flash_forward,
+    )
+
+    c = {"s_q": 48, "s_k": 48, "group": 7, "dtype": jnp.float32, "d": 16,
+         "dv": 16, "block_q": 8, "block_k": 16, **_window_cases()[case]}
+    group, kv_heads, window = c["group"], 2, c["window"]
+    ks = jax.random.split(jax.random.key(13), 4)
+    shape = lambda heads, s, w: (1, heads, s, w)  # noqa: E731
+    q = jax.random.normal(
+        ks[0], shape(kv_heads * group, c["s_q"], c["d"]), c["dtype"])
+    k = jax.random.normal(ks[1], shape(kv_heads, c["s_k"], c["d"]), c["dtype"])
+    v = jax.random.normal(
+        ks[2], shape(kv_heads, c["s_k"], c["dv"]), c["dtype"])
+    g = jax.random.normal(ks[3], q.shape[:-1] + (c["dv"],), c["dtype"])
+    # a query past every key's window sees nothing: the kernels give it
+    # zeros (the oracle's softmax a mean of the values), so it is left
+    # out of the comparison and carries no gradient
+    rows, cols = np.arange(c["s_q"])[:, None], np.arange(c["s_k"])[None, :]
+    live = jnp.asarray(
+        ((cols <= rows) & (rows - cols < window)).any(axis=1))[:, None]
+    g = jnp.where(live, g, 0)
+    f32 = lambda t: t.astype(jnp.float32)  # noqa: E731
+    repeated = lambda t: jnp.repeat(f32(t), group, axis=1)  # noqa: E731
+
+    out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, block_q=c["block_q"],
+        block_k=c["block_k"]), q, k, v)
+    want, want_vjp = jax.vjp(lambda q, k, v: jnp.where(
+        live, attention_reference(
+            q, repeated(k), repeated(v), causal=True, window=window), 0),
+        f32(q), f32(k), f32(v))
+    got = (out,) + vjp(g)
+    wants = (want,) + want_vjp(f32(g))
+    if window >= c["s_q"] and c["s_q"] == c["s_k"]:
+        # a window that reaches every key is causal attention, bit for bit
+        plain, plain_vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=c["block_q"],
+            block_k=c["block_k"]), q, k, v)
+        for a, b in zip(got, (plain,) + plain_vjp(g)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for a, w, leaf in zip(got, wants, ("out", "dq", "dk", "dv")):
+        assert a.dtype == c["dtype"] and a.shape == w.shape, leaf
+        if c["dtype"] == jnp.float32:
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(w), atol=2e-5, rtol=2e-5,
+                err_msg=leaf)
+        else:
+            worst = float(jnp.max(jnp.abs(f32(a) - w)))
+            assert worst <= 2.0 ** -6 * float(jnp.max(jnp.abs(w))), (
+                leaf, worst)
+    if c["dtype"] == jnp.float32 and c["block_q"]:
+        blocks = (c["block_q"], c["block_k"])
+        merged = lambda t: t.reshape((-1,) + t.shape[2:])  # noqa: E731
+        args = (merged(q), merged(repeated(k)), merged(repeated(v)))
+        o, lse = _flash_forward(
+            *args, c["d"] ** -0.5, True, *blocks, True, window)
+        dq, dk, dv = _flash_backward(
+            c["d"] ** -0.5, True, *blocks, (*args, o, lse), merged(g),
+            window=window)
+        shared = lambda t: t.reshape(  # noqa: E731
+            (1, kv_heads, group) + t.shape[1:]).sum(axis=2)
+        for a, w, leaf in zip((dq[None], shared(dk), shared(dv)), wants[1:],
+                              "qkv"):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(w), atol=2e-5, rtol=2e-5,
+                err_msg="blockwise d" + leaf)
+
+
+@pytest.mark.parametrize("blocks", [(8, 16), (16, 8), (16, 16), (8, 8)])
+@pytest.mark.parametrize("window", [None, 1, 5, 13, 16, 100])
+def test_flash_window_maps_skip_what_the_band_empties(blocks, window):
+    """With no window the index maps are the causal grid's as they
+    were; with one, a step whose pair the band empties stays on a block
+    of a pair it leaves something of (so that nothing is fetched for
+    it), and a pair it leaves something of is read where it is."""
+    from elephas_tpu.ops.flash_attention import _pair_seen, _visible_maps
+
+    bq, bk = blocks
+    nq, nk = 48 // bq, 48 // bk
+    first_i, last_j = _visible_maps(True, bq, bk, nq, window, nk)
+    rows, cols = np.arange(48)[:, None], np.arange(48)[None, :]
+    mask = cols <= rows
+    if window is not None:
+        mask &= rows - cols < window
+    seen = 0
+    for i in range(nq):
+        for j in range(nk):
+            want = mask[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk].any()
+            assert bool(_pair_seen(i, j, bq, bk, window)) == want
+            fi, lj = int(first_i(i, j)), int(last_j(i, j))
+            assert 0 <= fi < nq and 0 <= lj < nk
+            assert bool(_pair_seen(fi, j, bq, bk, window))
+            assert bool(_pair_seen(i, lj, bq, bk, window))
+            if want:
+                assert (fi, lj) == (i, j)
+                seen += 1
+            if window is None:  # the maps of the causal grid, as they were
+                assert fi == min(max(i, j * bk // bq), nq - 1)
+                assert lj == min(j, ((i + 1) * bq - 1) // bk)
+    if window is not None and window <= 16:
+        assert seen < sum(
+            bool(_pair_seen(i, j, bq, bk, None))
+            for i in range(nq) for j in range(nk))
+
+
+def test_flash_window_is_a_positive_count_of_keys_under_the_causal_mask():
+    q, k, v = _qkv(bh=2, s=32, d=16)
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(q, k, v, causal=False, window=8)
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(q, k, v, causal=True, window=0)
+    # no window named: the jaxpr of the op is what it was without the
+    # argument
+    with_none = jax.make_jaxpr(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=None))(q, k, v)
+    without = jax.make_jaxpr(lambda q, k, v: flash_attention(
+        q, k, v, causal=True))(q, k, v)
+    assert str(with_none) == str(without)
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_attention_matches_reference(causal):
     from jax.sharding import Mesh

@@ -318,6 +318,27 @@ def test_flash_kernels_at_the_rules_blocks(heads, kv_heads, d, dv):
         heads * 8192 * 256 * 4 * (2 if d != dv else 1))
 
 
+@pytest.mark.parametrize("window", [4096, None])
+def test_flash_kernels_under_a_window_at_16384_positions(window):
+    """The window-and-full-attention LM's attention of one step (ISSUE
+    37): 28 query heads over 4 key/value heads of 128 at 16384
+    positions, with the 4096-key band and without. Blocks of 1024 by
+    the rule, the clamped maps' integer arithmetic inside Mosaic's
+    index maps, and all three kernels compile; the temporaries stay
+    under one float32 copy of the queries."""
+    q = on_chip((28, 16384, 128), jnp.bfloat16)
+    k = on_chip((4, 16384, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = flash_attention(
+            q, k, v, causal=True, window=window, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, k).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 28 * 16384 * 128 * 4
+
+
 def test_grouped_matmul_over_held_experts_forward_and_backward():
     """20480 slot rows over 32 held experts at hidden 2048 and twice
     the expert width: the grouped product and both of its gradients
