@@ -56,6 +56,8 @@ def _layers():
     import jax.numpy as jnp
     import keras
 
+    from elephas_tpu.ops.flash_attention import LSE_NAME, OUT_NAME
+
     shared = qwen3_next._layers()
     _SameShape, _Remat = shared["_SameShape"], shared["_Remat"]
     register = keras.saving.register_keras_serializable(package="elephas_tpu")
@@ -87,6 +89,16 @@ def _layers():
 
     @register
     class LatentAttention(_Remat):
+        """Latent attention (MLA): queries and keys of ``qk_nope_head_dim
+        + qk_rope_head_dim``, values of ``v_head_dim``, keys and values
+        expanded from a normed latent of ``kv_lora_rank``. Under
+        ``remat`` the backward pass runs the projections, the norm and
+        the rotation again and keeps the flash kernel's result and
+        log-sum-exp (a head's ``[S, v_head_dim]`` in the compute dtype
+        and ``[S]`` in float32)."""
+
+        kept = (OUT_NAME, LSE_NAME)
+
         def __init__(self, num_heads: int, qk_nope_head_dim: int,
                      qk_rope_head_dim: int, v_head_dim: int,
                      kv_lora_rank: int, rope_theta: float = 10000.0,
@@ -225,7 +237,8 @@ def deepseek_v3_lm(
     ``experts_held = (first, stop)`` and ``remat`` as for
     :func:`elephas_tpu.models.qwen3_next.qwen3_next_lm`: this chip's
     share of the routed experts, and every attention and feed-forward
-    layer keeping its input alone for the backward pass. Compiled with
+    layer keeping its input for the backward pass (an attention layer
+    also the flash kernel's result and log-sum-exp). Compiled with
     SGD (``lr``, ``momentum``) and next-token cross-entropy over
     float32 logits."""
     keras = _keras()

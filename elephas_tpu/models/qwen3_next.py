@@ -26,7 +26,8 @@ mixer gated attention where ``(i + 1) % full_attention_interval == 0``
 and Gated DeltaNet elsewhere; then a final norm and an untied head.
 :func:`qwen3_next_lm` returns the compiled model, ready for
 ``SparkModel``. Recomputation in the backward pass is set here
-(``remat``): every mixer and sparse block then keeps its input alone.
+(``remat``): every mixer and sparse block then keeps its input and
+the few results its class names (``_Remat.kept``).
 """
 
 from __future__ import annotations
@@ -76,6 +77,7 @@ def _layers():
     import jax.numpy as jnp
     import keras
 
+    from elephas_tpu.ops.flash_attention import LSE_NAME, OUT_NAME
     from elephas_tpu.ops.gated_delta import RESOLVE_NAME, gated_delta_rule
 
     register = keras.saving.register_keras_serializable(package="elephas_tpu")
@@ -120,7 +122,11 @@ def _layers():
         builder asked for it: the backward pass then keeps the layer's
         input, what ``_forward`` names with one of ``kept``
         (``jax.ad_checkpoint.checkpoint_name``), and computes the rest
-        again."""
+        again. The attention layers keep the flash forward kernel's
+        result and log-sum-exp (its backward kernels' residuals beside
+        q, k and v, which are projected again), so that kernel runs once
+        a layer; a Gated DeltaNet layer its chunks' triangular
+        inverses; the feed-forward layers nothing."""
 
         kept: tuple = ()
 
@@ -162,6 +168,14 @@ def _layers():
 
     @register
     class GatedAttention(_Remat):
+        """Grouped-query causal attention with q/k norms, a partial
+        rotation and a sigmoid output gate. Under ``remat`` the backward
+        pass projects, norms and rotates again and keeps the flash
+        kernel's result and log-sum-exp (a head's ``[S, D]`` in the
+        compute dtype and ``[S]`` in float32)."""
+
+        kept = (OUT_NAME, LSE_NAME)
+
         def __init__(self, num_heads: int, num_kv_heads: int, head_dim: int,
                      rotary_dim: int, rope_theta: float = 10000.0,
                      epsilon: float = 1e-6, init_std: float = 0.02,
@@ -572,7 +586,9 @@ def qwen3_next_lm(
     keeps all its outputs, the block computes its own experts' part,
     and the rest is left out (no exchange on one chip, and nothing in
     its place). With ``remat`` every mixer and sparse block keeps its
-    input alone, and the backward pass computes the rest again.
+    input (an attention layer also the flash kernel's result and
+    log-sum-exp, a Gated DeltaNet layer its triangular inverses), and
+    the backward pass computes the rest again.
     Compiled with SGD (``lr``, ``momentum``)
     and next-token cross-entropy over float32 logits."""
     keras = _keras()

@@ -55,12 +55,23 @@ def _layers():
     import jax.numpy as jnp
     import keras
 
+    from elephas_tpu.ops.flash_attention import LSE_NAME, OUT_NAME
+
     _Remat = qwen3_next._layers()["_Remat"]
     register = keras.saving.register_keras_serializable(package="elephas_tpu")
     f32 = jnp.float32
 
     @register
     class BandedAttention(_Remat):
+        """Grouped-query causal attention, banded (``window``) or full,
+        rotated or not: the module's docstring has the settings. Under
+        ``remat`` the backward pass projects and rotates again and keeps
+        the flash kernel's result and log-sum-exp (a head's ``[S, D]``
+        in the compute dtype and ``[S]`` in float32), whatever the
+        band."""
+
+        kept = (OUT_NAME, LSE_NAME)
+
         def __init__(self, num_heads: int, num_kv_heads: int, head_dim: int,
                      window: int | None = None, rotary: bool = True,
                      rope_theta: float = 10000.0, init_std: float = 0.02,
@@ -167,7 +178,8 @@ def smallthinker_lm(
     ``experts_held = (first, stop)`` and ``remat`` as for
     :func:`elephas_tpu.models.qwen3_next.qwen3_next_lm`: this chip's
     share of the routed experts, and every attention layer and sparse
-    block keeping its inputs alone for the backward pass. Compiled with
+    block keeping its inputs for the backward pass (an attention layer
+    also the flash kernel's result and log-sum-exp). Compiled with
     SGD (``lr``, ``momentum``) and next-token cross-entropy over float32
     logits."""
     if min(len(sliding_window_layout), len(rope_layout)) < num_hidden_layers:

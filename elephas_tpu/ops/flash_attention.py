@@ -9,8 +9,14 @@ instead of O(S²).
 
 Backward uses the standard flash recurrences (dV = Pᵀ dO, dS = P∘(dP − Δ),
 …) over O(S·D) residuals (just q/k/v/out/LSE). In the ``[BH, S, D]``
-layout they are two Pallas kernels in the forward's style (dK/dV, then
-dQ): score blocks stay in VMEM, operands reach the MXU in the inputs'
+layout the forward kernel's two results carry the names ``OUT_NAME``
+and ``LSE_NAME``, so that a caller whose own ``jax.checkpoint``
+recomputes the whole op can keep them by policy and leave the forward
+kernel out of its backward pass (q, k and v it computes again; the
+attention layers of ``models.qwen3_next``, ``models.deepseek_v3`` and
+``models.smallthinker`` do); to every other caller the names are
+nothing. In that layout the recurrences are two Pallas kernels in the
+forward's style (dK/dV, then dQ): score blocks stay in VMEM, operands reach the MXU in the inputs'
 dtype, causally empty block pairs are skipped (their copies too, in
 all three kernels: the index maps stay on the last visible block), and a
 key/value head that several query heads share is read in place. A
@@ -41,12 +47,19 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from elephas_tpu.utils import backend_guard
 
 NEG_INF = -1e30
+# the names (``jax.ad_checkpoint.checkpoint_name``) of the ``[BH, S, D]``
+# forward kernel's result and of its rows' log-sum-exp, for a caller
+# whose ``jax.checkpoint`` policy keeps them instead of running the
+# forward kernel again in the backward pass
+OUT_NAME = "flash_out"
+LSE_NAME = "flash_lse"
 
 
 # -- forward kernel ----------------------------------------------------
@@ -858,6 +871,7 @@ def _flash_attention_bhsd(q, k, v, scale, causal, block_q, block_k, interpret,
 def _fwd_rule(q, k, v, scale, causal, block_q, block_k, interpret, window):
     out, lse = _flash_forward(
         q, k, v, scale, causal, block_q, block_k, interpret, window)
+    out, lse = checkpoint_name(out, OUT_NAME), checkpoint_name(lse, LSE_NAME)
     return out, (q, k, v, out, lse)
 
 

@@ -485,6 +485,53 @@ def test_flash_window_is_a_positive_count_of_keys_under_the_causal_mask():
     assert str(with_none) == str(without)
 
 
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "checkpoint"])
+@pytest.mark.parametrize("case", ["causal", "window", "k192-v128"])
+def test_flash_residual_names_are_nothing_without_a_policy(
+        case, remat, monkeypatch):
+    """The forward kernel's result and log-sum-exp carry names
+    (``OUT_NAME``, ``LSE_NAME``) for a caller whose ``jax.checkpoint``
+    policy keeps them. To every other caller they are nothing:
+    ``jax.grad`` of the op, bare or under a ``jax.checkpoint`` with no
+    policy, lowers to the program it lowers to with the names taken out
+    of the rule, and gives the same arrays; full and banded causal
+    attention over grouped heads, and latent attention's widths (keys
+    of 192, values of 128)."""
+    import importlib
+    import re
+
+    fa = importlib.import_module("elephas_tpu.ops.flash_attention")
+    # the lowering numbers its private functions from a counter of the
+    # process: ``@floor_divide_56`` is ``@floor_divide_57`` a trace later
+    unnumbered = lambda text: re.sub(r"(@\w+?)_\d+\b", r"\1", text)  # noqa: E731
+    d, dv, window = {"causal": (16, 16, None), "window": (16, 16, 13),
+                     "k192-v128": (192, 128, None)}[case]
+    ks = jax.random.split(jax.random.key(39), 3)
+    q = jax.random.normal(ks[0], (1, 4, 48, d))
+    k = jax.random.normal(ks[1], (1, 2, 48, d))
+    v = jax.random.normal(ks[2], (1, 2, 48, dv))
+
+    def gradient_program_and_names():
+        def loss(q, k, v):  # a function of its own for each trace
+            return jnp.sum(jnp.sin(flash_attention(
+                q, k, v, causal=True, window=window)))
+
+        grad = jax.grad(jax.checkpoint(loss) if remat else loss, (0, 1, 2))
+        jaxpr = str(jax.make_jaxpr(grad)(q, k, v))
+        grad = jax.jit(grad)
+        return (grad(q, k, v), unnumbered(grad.lower(q, k, v).as_text()),
+                [f"name={n}" in jaxpr for n in (fa.OUT_NAME, fa.LSE_NAME)])
+
+    named, program, names = gradient_program_and_names()
+    assert names == [True, True]
+    monkeypatch.setattr(fa, "checkpoint_name", lambda value, name: value)
+    bare, bare_program, names = gradient_program_and_names()
+    assert names == [False, False]
+    assert program == bare_program
+    for a, b in zip(named, bare):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_attention_matches_reference(causal):
     from jax.sharding import Mesh

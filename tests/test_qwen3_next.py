@@ -259,6 +259,63 @@ def test_rematerialised_mixer_keeps_its_triangular_inverses(ref, monkeypatch):
         np.testing.assert_array_equal(kept[path], recomputed[path])
 
 
+def _attention_layer(kind):
+    """One rematerialised attention layer of each model's kind, at this
+    file's widths."""
+    from elephas_tpu.models import deepseek_v3, qwen3_next, smallthinker
+
+    if kind == "gated":
+        return qwen3_next.GatedAttention(4, 2, 16, 4, remat=True, name="attn")
+    if kind == "latent":
+        return deepseek_v3.LatentAttention(
+            4, 16, 8, 16, 16, remat=True, name="attn")
+    windowed = kind == "banded-window"
+    return smallthinker.BandedAttention(
+        4, 2, 16, 6 if windowed else None, windowed, remat=True, name="attn")
+
+
+@pytest.mark.parametrize(
+    "kind", ["gated", "latent", "banded-window", "banded-full"])
+def test_rematerialised_attention_runs_the_flash_forward_kernel_once(
+        kind, monkeypatch):
+    """An attention layer under ``remat`` keeps the flash forward
+    kernel's result and log-sum-exp (its ``kept``): the gradient's
+    program, lowered for the TPU, holds that kernel once, and twice with
+    nothing kept, where the backward pass runs it again for the same two
+    arrays; either way both backward kernels are there once and the
+    gradients are the same numbers."""
+    from elephas_tpu.utils import backend_guard
+
+    x = jax.random.normal(jax.random.key(6), (2, SEQ, CFG["hidden_size"]))
+
+    def layer_loss():  # a layer and a function of its own for each trace
+        layer = _attention_layer(kind)
+        layer.build(x.shape)
+        return layer, lambda p, x: jnp.sum(
+            jnp.sin(3.0 * layer.stateless_call(p, [], x)[0]))
+
+    def gradient_and_kernels():
+        layer, loss = layer_loss()
+        params = [0.2 * jax.random.normal(jax.random.key(i), v.shape)
+                  for i, v in enumerate(layer.trainable_variables)]
+        gradient = jax.jit(jax.grad(loss, (0, 1)))(params, x)
+        with monkeypatch.context() as compiled:  # kernels, not interpreted
+            compiled.setattr(backend_guard, "pallas_interpret", lambda: False)
+            text = jax.jit(jax.grad(layer_loss()[1], (0, 1))).trace(
+                params, x).lower(lowering_platforms=("tpu",)).as_text()
+        return gradient, [
+            text.count(f'kernel_name = "{kernel}"') for kernel in
+            ("_fwd_kernel", "_bwd_dkv_kernel", "_bwd_dq_kernel")]
+
+    kept, kernels_kept = gradient_and_kernels()
+    monkeypatch.setattr(type(_attention_layer(kind)), "kept", ())
+    recomputed, kernels_recomputed = gradient_and_kernels()
+    assert kernels_kept == [1, 1, 1]
+    assert kernels_recomputed == [2, 1, 1]
+    for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(recomputed)):
+        np.testing.assert_array_equal(a, b)
+
+
 def test_norm_and_swiglu(ref):
     from elephas_tpu.models import qwen3_next as zoo
 
