@@ -33,7 +33,9 @@ from elephas_tpu import telemetry
 from elephas_tpu.data.rdd import Rdd
 from elephas_tpu.parallel.mesh import worker_mesh
 from elephas_tpu.utils import rdd_utils
-from elephas_tpu.worker import MeshRunner, MODES, FREQUENCIES, reads_model
+from elephas_tpu.worker import (
+    FREQUENCIES, MODES, JaxWork, MeshRunner, reads_model,
+)
 
 logger = logging.getLogger(__name__)
 
@@ -522,7 +524,7 @@ class SparkModel:
         with telemetry.trace_span(
             "fit.call", epochs=int(epochs), batch_size=int(batch_size),
             workers=self.num_workers,
-        ) as call:
+        ) as call, JaxWork(call):  # the call's totals, inner spans' included
             start_epoch = self._resume(checkpoint_dir, resume)
             # cross-process trace context minted at the training edge
             # (ISSUE 13): every event this fit records — the fit.* spans

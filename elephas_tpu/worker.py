@@ -42,6 +42,7 @@ import itertools
 import logging
 import queue
 import threading
+import time
 from concurrent.futures import Future
 from functools import partial
 
@@ -78,6 +79,129 @@ def reads_model(when):
 def _reads_model_at(cb, epoch: int) -> bool:
     when = getattr(cb, "reads_model", True)
     return bool(when(epoch) if callable(when) else when)
+
+
+# -- what JAX did under a span -------------------------------------------
+#
+# JAX reports each trace, lowering and compile to its monitoring
+# listeners as it ends, on the thread that made it. One pair of listeners
+# a process adds them into the frames that this thread has open
+# (`JaxWork`), so a span can say what JAX did under it; a thread with no
+# frame open (a serving thread's compile, everything under null mode)
+# returns at once.
+
+_JAX_STAGE_ARGS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax_trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax_lower_s",
+    # cache retrieval included: a hit is a compile that took that long
+    "/jax/core/compile/backend_compile_duration": "jax_compile_s",
+}
+_JAX_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+_JAX_COUNT_ARGS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+# an event that began this close before another is taken to lie in it
+_JAX_NESTING_SLACK_S = 1e-4
+_jax_frames = threading.local()
+_jax_listening = threading.Lock()
+_jax_listeners = []  # the registered pair, once a process
+
+
+def _on_jax_duration(event: str, seconds: float, fun_name="", **_kw) -> None:
+    frames = getattr(_jax_frames, "open", None)
+    if not frames:
+        return
+    arg = _JAX_STAGE_ARGS.get(event)
+    if arg is None:
+        if event == _JAX_CACHE_LOAD:
+            for frame in frames:
+                frame["jax_cache_load_s"] += seconds
+        return
+    # events nest (a function traced inside another's trace, an eager
+    # operation compiled inside a trace) and the inner one ends first:
+    # each stage gets the seconds of its events less those of the
+    # events inside them, so the stages add up to time that passed once
+    began = time.monotonic() - seconds
+    for frame in frames:
+        inside, ended = 0.0, frame["ended"]
+        while ended and ended[-1][0] >= began - _JAX_NESTING_SLACK_S:
+            inside += ended.pop()[1]
+        ended.append((began, seconds))
+        frame[arg] += max(seconds - inside, 0.0)
+        frame["jax_events"] += 1
+        if seconds > frame["longest_s"]:
+            frame["longest_s"], frame["jax_longest"] = seconds, str(fun_name)
+
+
+def _on_jax_event(event: str, **_kw) -> None:
+    frames = getattr(_jax_frames, "open", None)
+    if not frames:
+        return
+    arg = _JAX_COUNT_ARGS.get(event)
+    if arg is not None:
+        for frame in frames:
+            frame[arg] += 1
+
+
+def _listen_to_jax() -> None:
+    """Register the two listeners, once however often this is called."""
+    with _jax_listening:
+        if not _jax_listeners:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_jax_duration)
+            jax.monitoring.register_event_listener(_on_jax_event)
+            _jax_listeners.extend((_on_jax_duration, _on_jax_event))
+
+
+class JaxWork:
+    """``with trace_span(...) as sp, JaxWork(sp):`` puts on the open
+    span what JAX did on this thread inside the block: seconds of
+    tracing, lowering and compiling (``jax_trace_s``, ``jax_lower_s``,
+    ``jax_compile_s``, the last with ``jax_cache_load_s`` inside it),
+    how many such events (``jax_events``), the compile cache's
+    ``cache_hits`` and ``cache_misses``, and ``jax_longest``, the
+    function of the longest single event. Blocks nest: the root span of
+    a call holds the call's totals. Under null mode nothing is opened."""
+
+    __slots__ = ("_span", "_frame")
+
+    def __init__(self, span):
+        self._span = span
+        self._frame = None
+
+    def __enter__(self):
+        if telemetry.null_mode():
+            return self
+        self._frame = {
+            "jax_trace_s": 0.0, "jax_lower_s": 0.0, "jax_compile_s": 0.0,
+            "jax_cache_load_s": 0.0, "jax_events": 0, "cache_hits": 0,
+            "cache_misses": 0, "jax_longest": "", "longest_s": 0.0,
+            "ended": [],  # (began, seconds) of events no later one holds
+        }
+        frames = getattr(_jax_frames, "open", None)
+        if frames is None:
+            frames = _jax_frames.open = []
+        frames.append(self._frame)
+        return self
+
+    def __exit__(self, *exc):
+        frame = self._frame
+        if frame is not None:
+            _jax_frames.open.pop()  # blocks nest, so the last is this one
+            del frame["longest_s"], frame["ended"]
+            self._span.set(**frame)
+        return False
+
+
+def _fullest_device_stats():
+    """``memory_stats()`` of the local device whose allocator peak is
+    the highest; None where the backend keeps none (the CPU)."""
+    stats = [d.memory_stats() for d in jax.local_devices()]
+    stats = [s for s in stats if s and "bytes_in_use" in s]
+    if not stats:
+        return None
+    return max(stats, key=lambda s: s.get("peak_bytes_in_use", 0))
 
 
 def _pmean_floats(tree, axis_name: str):
@@ -385,10 +509,13 @@ class MeshRunner(KerasIntrospection):
         self.mesh = mesh
         self.num_workers = mesh.devices.size
         self._epoch_fn = None
+        self._signatures = 0  # of _epoch_fn's dispatch cache, last seen
+        self._memory_peak = 0  # the allocator's, at the last fit.memory
         self._counters = None  # found on first use (_counter_layers)
         self._eval_fn = None
         self._predict_fn = None
         model.optimizer.build(model.trainable_variables)
+        _listen_to_jax()
 
     # -- state plumbing ------------------------------------------------
 
@@ -507,7 +634,7 @@ class MeshRunner(KerasIntrospection):
         Returns the state and its size (``variables``, and one
         replica's ``bytes``, from shapes alone): the args that carry
         the per-variable detail, which must never become ring events."""
-        with telemetry.trace_span("fit.device_state") as sp:
+        with telemetry.trace_span("fit.device_state") as sp, JaxWork(sp):
             state = self._device_state(park_master=True)
             leaves = [leaf for part in state for leaf in part]
             size = {
@@ -605,6 +732,24 @@ class MeshRunner(KerasIntrospection):
         telemetry.emit("fit.counters", epoch=int(epoch), layers=by_layer)
         return now
 
+    def _emit_memory(self, epoch: int) -> None:
+        """One ``fit.memory`` event an epoch, where :meth:`_emit_counters`
+        is called: the allocator's ``bytes_in_use`` and
+        ``peak_bytes_in_use`` on the fullest local device, and whether
+        the peak rose since the last such event (``peak_rose``): in
+        which epoch the peak is set, and what the state between epochs
+        holds. Nothing where the backend keeps no such statistics."""
+        stats = _fullest_device_stats()
+        if stats is None:
+            return
+        peak = int(stats.get("peak_bytes_in_use", 0))
+        telemetry.emit(
+            "fit.memory", epoch=int(epoch),
+            bytes_in_use=int(stats["bytes_in_use"]), peak_bytes_in_use=peak,
+            peak_rose=peak > self._memory_peak,
+        )
+        self._memory_peak = peak
+
     # -- loss helpers --------------------------------------------------
 
     def _loss_and_updates(self, tv, ntv, x, y):
@@ -678,6 +823,25 @@ class MeshRunner(KerasIntrospection):
         )
         return jax.jit(sharded, donate_argnums=(0, 1, 2))
 
+    def _dispatch_epoch(self, state, mvs, xb, yb, **where):
+        """The epoch function's call under its ``fit.epoch_dispatch``
+        span, which says what JAX did under it (:class:`JaxWork`), how
+        many ``signatures`` the function's dispatch cache then holds and
+        whether this call added one (``new_signature``): the call met
+        arguments that no earlier call had shown JAX, and was traced
+        again."""
+        with telemetry.trace_span("fit.epoch_dispatch", **where) as sp, \
+                JaxWork(sp):
+            out = self._epoch_fn(*state, mvs, xb, yb)
+            # JAX's own counter, not a public one: an epoch function
+            # without it (a stand-in that a test wraps) counts as 0
+            count = getattr(self._epoch_fn, "_cache_size", None)
+            signatures = count() if count is not None else 0
+            sp.set(signatures=signatures,
+                   new_signature=signatures > self._signatures)
+        self._signatures = signatures
+        return out
+
     def run_epochs(
         self,
         partitions: list[tuple[np.ndarray, np.ndarray]],
@@ -722,11 +886,12 @@ class MeshRunner(KerasIntrospection):
         history: dict[str, list[float]] = {"loss": []}
         for epoch in range(epochs):
             mvs = self._zero_metric_state(metric_objects)
-            with span("fit.epoch_dispatch", epoch=epoch):
-                tv, ntv, ov, mvs, loss = self._epoch_fn(tv, ntv, ov, mvs, xb, yb)
+            tv, ntv, ov, mvs, loss = self._dispatch_epoch(
+                (tv, ntv, ov), mvs, xb, yb, epoch=epoch)
             with span("fit.loss_wait", epoch=epoch):
                 epoch_loss = float(np.asarray(loss))  # replicated: direct read
             counted = self._emit_counters(epoch, ntv, counted)
+            self._emit_memory(epoch)
             history["loss"].append(epoch_loss)
             self._history_from_metrics(history, metric_objects, mvs)
             if verbose:
@@ -810,10 +975,8 @@ class MeshRunner(KerasIntrospection):
                 xs, ys, steps = got
                 xb, yb = self._shard_local_data(xs), self._shard_local_data(ys)
                 zero_mvs = self._zero_metric_state(metric_objects)
-                with span("fit.epoch_dispatch", epoch=epoch, block=block):
-                    tv, ntv, ov, block_mvs, loss = self._epoch_fn(
-                        tv, ntv, ov, zero_mvs, xb, yb
-                    )
+                tv, ntv, ov, block_mvs, loss = self._dispatch_epoch(
+                    (tv, ntv, ov), zero_mvs, xb, yb, epoch=epoch, block=block)
                 mvs = (
                     block_mvs
                     if mvs is None
@@ -827,6 +990,7 @@ class MeshRunner(KerasIntrospection):
                     / total_steps
                 )
             counted = self._emit_counters(epoch, ntv, counted)
+            self._emit_memory(epoch)
             history["loss"].append(epoch_loss)
             self._history_from_metrics(history, metric_objects, mvs)
             if verbose:

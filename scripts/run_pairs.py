@@ -16,11 +16,16 @@ set up in half the time of alternating ones.
 
 Each run prints, after the harness's lines, ``[epochs]`` with the seconds
 between the measured call's consecutive ``fit.epoch`` events (the
-harness's ``readings`` are these, as rates, to one decimal). The records
-go to ``chiprun_out/PR<n>/<call>.jsonl`` in the shape of
+harness's ``readings`` are these, as rates, to one decimal). An untraced
+run of a tree that has ``benchmarks/harness/epoch_spans.py`` (PR 40) also
+reads the ring's epoch-by-epoch metrics, which the harness reads in a
+traced run only: their ``[dispatch]``, ``[first_epoch]`` and ``[memory]``
+lines, and ``[spans]`` with the values. The records go to
+``chiprun_out/PR<n>/<call>.jsonl`` in the shape of
 ``benchmarks/results/sets/``: the result line whole, and beside it the
-numbers of the ``[fit]``, ``[check]``, ``[scopes]`` and ``[epochs]``
-lines.
+numbers of the ``[fit]``, ``[check]``, ``[scopes]``, ``[epochs]`` and
+``[spans]`` lines; each run's ``fit.*`` ring events go beside its log as
+``<stem>.ring.json``.
 """
 
 import argparse
@@ -35,9 +40,29 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+def read_epoch_spans(stamps) -> dict:
+    """The checkout's own readers of ``epoch_spans.METRICS`` (PR 40) on
+    the window that ``stamps`` (monotonic seconds of the measured call's
+    ``fit.epoch`` events) open and close; a reader that finds nothing
+    is left out."""
+    from benchmarks.harness import epoch_spans
+    from benchmarks.harness import manifest as mf
+
+    run = {"window": {"t0": stamps[0], "t1": stamps[-1]}}
+    out = {}
+    for name in epoch_spans.METRICS:
+        value = mf.load_module("metrics", name).read(run)
+        if value is not None:
+            out[name] = float(value)
+    return out
+
+
 def child(argv) -> int:
     """``benchmarks/run.py`` of the checkout this process stands in,
     then the epochs' seconds from the program's own ring."""
+    ring_out = None
+    if argv[:1] == ["--ring"]:
+        ring_out, argv = argv[1], argv[2:]
     sys.argv = ["benchmarks/run.py"] + argv
     try:
         runpy.run_path("benchmarks/run.py", run_name="__main__")
@@ -53,6 +78,15 @@ def child(argv) -> int:
             if e["args"].get("trace") == last]
     print("[epochs] seconds=" + json.dumps(
         [round(b - a, 4) for a, b in zip(call, call[1:])]), flush=True)
+    untraced = argv[argv.index("--trace") + 1] == "0"
+    if untraced and len(call) >= 2 and os.path.isfile(
+            os.path.join("benchmarks", "harness", "epoch_spans.py")):
+        print("[spans] values=" + json.dumps(read_epoch_spans(call)),
+              flush=True)
+    if ring_out:
+        with open(ring_out, "w") as f:
+            json.dump([e for e in telemetry.default_tracer().events()
+                       if e["name"].startswith("fit.")], f)
     return rc
 
 
@@ -94,15 +128,16 @@ def main() -> int:
     for order, run in enumerate(args.runs, 1):
         side, cell, seed, trace = run.split(":")
         tree = os.path.join(ROOT, trees[side])
+        stem = f"{args.call}-{order:02d}-{side}-{cell}-t{trace}"
         t0 = time.monotonic()
         done = subprocess.run(
             [sys.executable, os.path.abspath(__file__), "--child",
+             "--ring", os.path.join(out_dir, stem + ".ring.json"),
              "--workload", cell, "--seed", seed, "--seconds", str(seconds),
              "--trace", trace],
             cwd=tree, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
             text=True)
         run_s = round(time.monotonic() - t0, 1)
-        stem = f"{args.call}-{order:02d}-{side}-{cell}-t{trace}"
         with open(os.path.join(out_dir, stem + ".log"), "w") as f:
             f.write(done.stdout)
         lines = [ln for ln in done.stdout.splitlines()
@@ -116,6 +151,7 @@ def main() -> int:
             "check": fields(done.stdout, "check"),
             "scopes": fields(done.stdout, "scopes").get("ms_per_step"),
             "epoch_seconds": fields(done.stdout, "epochs").get("seconds"),
+            "spans": fields(done.stdout, "spans").get("values"),
         }
         with open(os.path.join(out_dir, args.call + ".jsonl"), "a") as f:
             f.write(json.dumps(record) + "\n")

@@ -710,17 +710,23 @@ class TestOneClock:
 
 
 FIT_EPOCHS = 17
+# what JAX did under a span (ISSUE 40): worker.JaxWork sets them all
+JAX_WORK_ARGS = {
+    "jax_trace_s", "jax_lower_s", "jax_compile_s", "jax_cache_load_s",
+    "jax_events", "cache_hits", "cache_misses", "jax_longest",
+}
 # a span's name -> the args it must carry (ISSUE 24's table)
 PER_CALL_SPANS = {
-    "fit.call": {"epochs", "batch_size", "workers"},
+    "fit.call": {"epochs", "batch_size", "workers"} | JAX_WORK_ARGS,
     "fit.partition_arrays": {"rows", "bytes"},
     "fit.to_mesh": set(),
     "fit.stack_batches": {"bytes"},
     "fit.shard_data": {"bytes"},
-    "fit.device_state": {"variables", "bytes"},
+    "fit.device_state": {"variables", "bytes"} | JAX_WORK_ARGS,
 }
 PER_EPOCH_SPANS = {
-    "fit.epoch_dispatch": {"epoch"},
+    "fit.epoch_dispatch": (
+        {"epoch", "signatures", "new_signature"} | JAX_WORK_ARGS),
     "fit.loss_wait": {"epoch"},
     "fit.write_back": {"epoch", "variables", "bytes", "final"},
     "fit.callbacks": {"epoch", "count"},
@@ -855,6 +861,200 @@ class TestFitSpans:
             ]
             # one wait for each block and one that finds the end
             assert runs and waits == list(range(len(runs) + 1))
+
+
+def _dispatches(events):
+    return [e for e in events if e["name"] == "fit.epoch_dispatch"]
+
+
+class TestJaxWorkOnSpans:
+    """ISSUE 40: what JAX did under ``fit.epoch_dispatch``,
+    ``fit.device_state`` and ``fit.call``, whether a dispatch met a new
+    signature, and the allocator once an epoch. Counts only: the CPU
+    mesh says nothing about time."""
+
+    def test_first_dispatch_holds_the_epoch_programs_trace_lower_compile(
+        self, staged_fit_events
+    ):
+        first, *steady = _dispatches(staged_fit_events)
+        args = first["args"]
+        assert args["epoch"] == 0 and args["new_signature"] is True
+        assert args["signatures"] == 1
+        assert args["jax_events"] >= 3  # a trace, a lowering, a compile
+        assert "per_worker" in args["jax_longest"]
+        for key in ("jax_trace_s", "jax_lower_s", "jax_compile_s"):
+            assert args[key] > 0.0, key
+        # the stages are exclusive of what nests in them, so they add
+        # up to time that passed once, under the span
+        staged = sum(args[k] for k in (
+            "jax_trace_s", "jax_lower_s", "jax_compile_s"))
+        assert staged <= first["dur"]
+        assert args["jax_cache_load_s"] <= args["jax_compile_s"]
+        assert len(steady) == FIT_EPOCHS - 1
+
+    def test_steady_dispatches_record_no_jax_work_and_no_new_signature(
+        self, staged_fit_events
+    ):
+        for e in _dispatches(staged_fit_events)[1:]:
+            args = e["args"]
+            assert args["jax_events"] == 0, args
+            assert args["jax_longest"] == ""
+            assert args["jax_trace_s"] == args["jax_compile_s"] == 0.0
+            assert args["new_signature"] is False
+            assert args["signatures"] == 1
+
+    def test_root_span_holds_the_calls_totals(self, staged_fit_events):
+        (root,) = [e for e in staged_fit_events if e["name"] == "fit.call"]
+        inner = [
+            e for e in staged_fit_events
+            if e["name"] in ("fit.epoch_dispatch", "fit.device_state")
+        ]
+        for key in ("jax_events", "cache_hits", "cache_misses"):
+            assert root["args"][key] >= sum(e["args"][key] for e in inner)
+        assert root["args"]["jax_events"] >= 3
+        assert root["args"]["jax_compile_s"] >= sum(
+            e["args"]["jax_compile_s"] for e in inner)
+
+    def test_signatures_never_fall_and_rise_where_new_signature_says(self):
+        """Two calls on one object, one worker (where set-up's call and
+        a longer one show the epoch function different arguments
+        today): whatever the count does, the two args agree."""
+        x, y = _toy_rows()
+        sm = _toy_spark_model(num_workers=1)
+        tracer = telemetry.default_tracer()
+        since = tracer.seq
+        sm.fit((x, y), epochs=1, batch_size=8)
+        sm.fit((x, y), epochs=4, batch_size=8)
+        found = _dispatches(_own_events(tracer, since))
+        assert [e["args"]["epoch"] for e in found] == [0, 0, 1, 2, 3]
+        seen = 0
+        for e in found:
+            args = e["args"]
+            assert args["signatures"] >= seen
+            assert args["new_signature"] is (args["signatures"] > seen)
+            # a call that added no entry traced, lowered, compiled nothing
+            if not args["new_signature"]:
+                assert args["jax_events"] == 0
+            seen = args["signatures"]
+        assert found[0]["args"]["new_signature"] is True
+        assert seen == sm._get_runner()._epoch_fn._cache_size()
+
+    def test_streamed_dispatches_carry_the_args(self):
+        x, y = _toy_rows()
+        tracer = telemetry.default_tracer()
+        since = tracer.seq
+        _toy_spark_model().fit(
+            (x, y), epochs=2, batch_size=8, stream_block_steps=2
+        )
+        evs = _own_events(tracer, since)
+        found = _dispatches(evs)
+        assert len(found) > 2  # a dispatch a block
+        for e in found:
+            assert PER_EPOCH_SPANS["fit.epoch_dispatch"] | {"block"} <= set(
+                e["args"])
+        assert found[0]["args"]["jax_events"] >= 3
+        assert all(e["args"]["jax_events"] == 0 for e in found[1:])
+        for name in ("fit.device_state", "fit.call"):
+            (e,) = [s for s in evs if s["name"] == name]
+            assert PER_CALL_SPANS[name] <= set(e["args"]), name
+
+    def test_another_threads_compile_leaves_the_spans_args_alone(self):
+        import jax
+        import jax.numpy as jnp
+
+        from elephas_tpu.worker import JaxWork
+
+        def compile_something(scale):
+            jax.jit(lambda a: a * scale + 1)(jnp.ones(3)).block_until_ready()
+
+        tr = telemetry.EventTracer(capacity=8)
+        with tr.span("fit.epoch_dispatch") as sp, JaxWork(sp):
+            other = threading.Thread(target=compile_something, args=(3.5,))
+            other.start()
+            other.join(timeout=60)
+            assert not other.is_alive()
+        with tr.span("fit.epoch_dispatch") as sp, JaxWork(sp):
+            compile_something(4.5)  # this thread's own: counted
+        theirs, own = (e["args"] for e in tr.events())
+        assert theirs["jax_events"] == 0 and theirs["jax_longest"] == ""
+        assert theirs["jax_compile_s"] == 0.0
+        assert own["jax_events"] >= 3 and own["jax_compile_s"] > 0.0
+
+    def test_listeners_are_registered_once_a_process(self):
+        from jax._src import monitoring
+
+        from elephas_tpu import worker
+
+        x, y = _toy_rows()
+        for _ in range(3):  # runners and calls
+            sm = _toy_spark_model()
+            sm.fit((x, y), epochs=1, batch_size=8)
+            sm.fit((x, y), epochs=1, batch_size=8)
+        durations = monitoring.get_event_duration_listeners()
+        events = monitoring.get_event_listeners()
+        assert durations.count(worker._on_jax_duration) == 1
+        assert events.count(worker._on_jax_event) == 1
+
+    def test_null_mode_records_nothing_and_opens_no_frame(self, not_null):
+        from elephas_tpu import worker
+
+        x, y = _toy_rows()
+        tracer = telemetry.default_tracer()
+        telemetry.set_null(True)
+        try:
+            since = tracer.seq
+            opened = []
+            sp = telemetry.trace_span("fit.epoch_dispatch")
+            with sp, worker.JaxWork(sp):
+                opened.append(list(getattr(worker._jax_frames, "open", [])))
+            history = _toy_spark_model().fit((x, y), epochs=2, batch_size=8)
+        finally:
+            telemetry.set_null(False)
+        assert opened == [[]]
+        assert len(history["loss"]) == 2
+        assert _own_events(tracer, since) == []
+        # and the listeners, with no frame open, return at their first line
+        worker._on_jax_duration(
+            "/jax/core/compile/backend_compile_duration", 1.0, fun_name="f")
+        worker._on_jax_event("/jax/compilation_cache/cache_hits")
+
+    def test_one_memory_event_an_epoch_where_the_backend_keeps_statistics(
+        self, monkeypatch
+    ):
+        from elephas_tpu import worker
+
+        x, y = _toy_rows()
+        tracer = telemetry.default_tracer()
+        since = tracer.seq
+        _toy_spark_model().fit((x, y), epochs=2, batch_size=8)
+        # the CPU backend keeps none: no event, and nothing raised
+        assert worker._fullest_device_stats() is None
+        assert not [e for e in _own_events(tracer, since)
+                    if e["name"] == "fit.memory"]
+        peaks = iter([700, 900, 900, 950])
+        monkeypatch.setattr(
+            worker, "_fullest_device_stats",
+            lambda: {"bytes_in_use": 512, "peak_bytes_in_use": next(peaks)},
+        )
+        since = tracer.seq
+        _toy_spark_model().fit((x, y), epochs=4, batch_size=8)
+        evs = _own_events(tracer, since)
+        found = [e["args"] for e in evs if e["name"] == "fit.memory"]
+        assert [a["epoch"] for a in found] == [0, 1, 2, 3]
+        assert [a["peak_rose"] for a in found] == [True, True, False, True]
+        assert [a["peak_bytes_in_use"] for a in found] == [700, 900, 900, 950]
+        assert all(a["bytes_in_use"] == 512 for a in found)
+        # each lies between its epoch's loss read and its fit.epoch event
+        for a, e in zip(found, [e for e in evs if e["name"] == "fit.memory"]):
+            (wait,) = [s for s in evs if s["name"] == "fit.loss_wait"
+                       and s["args"]["epoch"] == a["epoch"]]
+            (mark,) = [s for s in evs if s["name"] == "fit.epoch"
+                       and s["args"]["epoch"] == a["epoch"]]
+            assert wait["seq"] < e["seq"] < mark["seq"]
+        # the ring's budget holds with the event in it
+        per_epoch = [e for e in evs if "epoch" in e["args"]
+                     and not e["args"].get("final")]
+        assert len(per_epoch) <= 12 * 4
 
 
 def _host_annotations(trace_dir, prefixes):
