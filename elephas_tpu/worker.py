@@ -813,15 +813,28 @@ class MeshRunner(KerasIntrospection):
                 loss,
             )
 
+        # tv, ntv, ov; the metric states and the loss. One tuple for the
+        # shard_map and for jit, so the two cannot drift: named, the
+        # state leaves under the sharding object _device_state gave it
+        # and a call fed its own outputs meets the dispatch cache's one
+        # entry. Left to JAX, a one-device mesh hands leaves back as
+        # P(): the same placement, another cache key, and a dispatch of
+        # 0.4-1.4 s on the host with the chip idle (PERF.md, PR 41)
+        out_specs = (P("workers"), P("workers"), P("workers"), P(), P())
         sharded = jax.shard_map(
             per_worker,
             mesh=self.mesh,
             in_specs=(P("workers"), P("workers"), P("workers"), P(),
                       P("workers"), P("workers")),
-            out_specs=(P("workers"), P("workers"), P("workers"), P(), P()),
+            out_specs=out_specs,
             check_vma=False,
         )
-        return jax.jit(sharded, donate_argnums=(0, 1, 2))
+        return jax.jit(
+            sharded,
+            donate_argnums=(0, 1, 2),
+            out_shardings=tuple(
+                NamedSharding(self.mesh, spec) for spec in out_specs),
+        )
 
     def _dispatch_epoch(self, state, mvs, xb, yb, **where):
         """The epoch function's call under its ``fit.epoch_dispatch``

@@ -916,9 +916,10 @@ class TestJaxWorkOnSpans:
             e["args"]["jax_compile_s"] for e in inner)
 
     def test_signatures_never_fall_and_rise_where_new_signature_says(self):
-        """Two calls on one object, one worker (where set-up's call and
-        a longer one show the epoch function different arguments
-        today): whatever the count does, the two args agree."""
+        """Two calls on one object, one worker (where, before ISSUE 41
+        named the epoch function's output shardings, set-up's call and
+        a longer one showed it different arguments): the two args agree
+        dispatch by dispatch, and the count ends where it began."""
         x, y = _toy_rows()
         sm = _toy_spark_model(num_workers=1)
         tracer = telemetry.default_tracer()
@@ -937,7 +938,55 @@ class TestJaxWorkOnSpans:
                 assert args["jax_events"] == 0
             seen = args["signatures"]
         assert found[0]["args"]["new_signature"] is True
-        assert seen == sm._get_runner()._epoch_fn._cache_size()
+        assert seen == 1 == sm._get_runner()._epoch_fn._cache_size()
+
+    @pytest.mark.parametrize(
+        "stream", [{}, {"stream_block_steps": 2}], ids=["staged", "streamed"])
+    @pytest.mark.parametrize("num_workers", [1, 2])
+    def test_the_state_comes_back_under_the_sharding_it_went_in_with(
+        self, monkeypatch, num_workers, stream
+    ):
+        """ISSUE 41: the epoch program names its outputs' shardings, so
+        a dispatch fed the last one's outputs shows the dispatch cache
+        the arguments that ``_device_state`` showed it: one signature
+        for the life of the runner, whatever the mesh's size. A
+        ``new_signature`` after a runner's first dispatch is a
+        regression (on the chip it cost 0.4-1.4 s of host time)."""
+        import jax
+
+        from elephas_tpu import worker
+
+        shardings = []  # one a dispatch: the state's leaves, in and out
+        inner = worker.MeshRunner._dispatch_epoch
+
+        def watched(self, state, *rest, **where):
+            # read before the call: it donates the state's buffers
+            went_in = [leaf.sharding for leaf in jax.tree.leaves(state)]
+            out = inner(self, state, *rest, **where)
+            shardings.append((
+                went_in, [leaf.sharding for leaf in jax.tree.leaves(out[:3])]))
+            return out
+
+        monkeypatch.setattr(worker.MeshRunner, "_dispatch_epoch", watched)
+        x, y = _toy_rows()
+        sm = _toy_spark_model(num_workers=num_workers)
+        tracer = telemetry.default_tracer()
+        since = tracer.seq
+        sm.fit((x, y), epochs=1, batch_size=8, **stream)
+        sm.fit((x, y), epochs=4, batch_size=8, **stream)
+        found = _dispatches(_own_events(tracer, since))
+        assert len(found) == len(shardings) >= 5
+        assert [e["args"]["signatures"] for e in found] == [1] * len(found)
+        assert [e["args"]["new_signature"] for e in found] == (
+            [True] + [False] * (len(found) - 1))
+        runner = sm._get_runner()
+        given = {leaf.sharding
+                 for part in runner._device_state() for leaf in part}
+        assert len(given) == 1  # what _device_state gives every leaf
+        for went_in, came_back in shardings:
+            assert len(went_in) == len(came_back) > 4
+            assert set(went_in) == set(came_back) == given
+        assert runner._epoch_fn._cache_size() == 1
 
     def test_streamed_dispatches_carry_the_args(self):
         x, y = _toy_rows()
