@@ -1,0 +1,12 @@
+"""Device time a training step under the program's ssm.scan scope (the selective scan of the Mamba-2 layers with the steps' softplus: forward, recomputation and backward), from the traced run's .xplane.pb."""
+
+from benchmarks.harness import xplane_ops
+
+LAYER = "kernels"
+UNIT = "ms"
+SOURCE = "device_trace"
+MOVES = "fit_examples_per_s_per_chip"
+
+
+def read(run):
+    return xplane_ops.scope_ms_per_step(run, "ssm.scan")

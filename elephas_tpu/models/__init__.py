@@ -7,6 +7,15 @@ build those models inline. Here the same architectures are first-class
 builders so the benchmark suite (the five configurations listed in
 BASELINE.json) and the examples share one definition. All builders return *compiled* Keras-3 (jax backend)
 models ready to wrap in ``SparkModel``.
+
+The four sparse LMs (``qwen3_next_lm``, ``deepseek_v3_lm``,
+``smallthinker_lm``, ``nemotron_h_lm``) are built from blocks that this
+package also exports: the norms ``ZeroCentredRMSNorm`` and ``RMSNorm``;
+the feed-forwards ``SwiGLU``, ``DenseMLP`` and ``UngatedMLP``; the
+mixers ``GatedAttention``, ``LatentAttention``, ``BandedAttention``,
+``GatedDeltaNet`` (the gated delta rule) and ``Mamba2Mixer`` (the
+state-space scan); and ``SparseMoeBlock``, the one sparse block of all
+four (gated or ungated experts, with or without a shared expert).
 """
 
 from elephas_tpu.models.mlp import mnist_mlp
@@ -25,6 +34,7 @@ from elephas_tpu.models.switch import (
 from elephas_tpu.models.qwen3_next import qwen3_next_lm
 from elephas_tpu.models.deepseek_v3 import deepseek_v3_lm
 from elephas_tpu.models.smallthinker import smallthinker_lm
+from elephas_tpu.models.nemotron_h import nemotron_h_lm
 
 __all__ = [
     "mnist_mlp",
@@ -40,11 +50,13 @@ __all__ = [
     "qwen3_next_lm",
     "deepseek_v3_lm",
     "smallthinker_lm",
+    "nemotron_h_lm",
     "MoeFFN",
     "FlashMHA",
     "FusedLayerNorm",
     "ZeroCentredRMSNorm",
     "SwiGLU",
+    "UngatedMLP",
     "GatedAttention",
     "GatedDeltaNet",
     "SparseMoeBlock",
@@ -52,6 +64,7 @@ __all__ = [
     "LatentAttention",
     "DenseMLP",
     "BandedAttention",
+    "Mamba2Mixer",
 ]
 
 
@@ -69,9 +82,10 @@ def __getattr__(name):
         from elephas_tpu.models.switch import MoeFFN
 
         return MoeFFN
-    from elephas_tpu.models import deepseek_v3, qwen3_next, smallthinker
+    from elephas_tpu.models import (
+        deepseek_v3, nemotron_h, qwen3_next, smallthinker)
 
-    for module in (qwen3_next, deepseek_v3, smallthinker):
+    for module in (qwen3_next, deepseek_v3, smallthinker, nemotron_h):
         if name in module.LAYER_NAMES:
             return getattr(module, name)
     raise AttributeError(name)

@@ -14,12 +14,16 @@ from Keras layers the zoo lacked:
   beta/decay projections, a causal depthwise convolution, the chunked
   gated delta rule (:mod:`elephas_tpu.ops.gated_delta`), a gated
   per-head norm and the output projection.
+- :class:`UngatedMLP`: ``down(act(up(x)))``, no biases (``relu2``: the
+  squared ReLU), the ungated sparse block's shared expert.
 - :class:`SparseMoeBlock`: a router over ALL experts, the routed part
   that the experts HELD here give (``experts_held``, a range; stacked
   weights; no token dropped; :func:`elephas_tpu.ops.moe.held_experts_ffn`)
-  and a shared expert under its sigmoid gate. It counts what it routes
-  in a non-trainable ``route_counts`` variable that the epoch runner
-  reads with the loss.
+  and a shared expert (here under its sigmoid gate). Experts and shared
+  expert are gated, ``down(act(gate(x)) * up(x))``, or with
+  ``gated_experts`` false ungated, ``down(act(up(x)))``. It counts what
+  it routes in a non-trainable ``route_counts`` variable that the epoch
+  runner reads with the loss.
 
 Decoder layer ``i``: ``x += mixer_i(norm(x)); x += moe(norm(x))``, the
 mixer gated attention where ``(i + 1) % full_attention_interval == 0``
@@ -41,7 +45,7 @@ from elephas_tpu.models.transformer import (
 
 _LAYERS = None
 COUNTER_NAMES = ("held_slots", "slots", "max_expert_tokens")
-LAYER_NAMES = ("ZeroCentredRMSNorm", "SwiGLU", "GatedAttention",
+LAYER_NAMES = ("ZeroCentredRMSNorm", "SwiGLU", "UngatedMLP", "GatedAttention",
                "GatedDeltaNet", "SparseMoeBlock", "LMHead")
 
 
@@ -165,6 +169,33 @@ def _layers():
         def get_config(self):
             return {**super().get_config(), "width": self.width,
                     "init_std": self.init_std, "remat": self.remat}
+
+    @register
+    class UngatedMLP(_Remat):
+        def __init__(self, width: int, init_std: float = 0.02,
+                     hidden_act: str = "relu2", **kwargs):
+            super().__init__(**kwargs)
+            self.width, self.init_std = width, init_std
+            self.hidden_act = hidden_act
+
+        def build(self, input_shape):
+            d = int(input_shape[-1])
+            self.up = self._weight(
+                "up", (d, self.width), normal(self.init_std))
+            self.down = self._weight(
+                "down", (self.width, d), normal(self.init_std))
+
+        def _forward(self, x):
+            from elephas_tpu.ops.moe import EXPERT_ACTIVATIONS
+
+            hidden = EXPERT_ACTIVATIONS[self.hidden_act](
+                jnp.matmul(x, self.up.value).astype(f32))
+            return jnp.matmul(hidden.astype(x.dtype), self.down.value)
+
+        def get_config(self):
+            return {**super().get_config(), "width": self.width,
+                    "init_std": self.init_std,
+                    "hidden_act": self.hidden_act, "remat": self.remat}
 
     @register
     class GatedAttention(_Remat):
@@ -368,9 +399,13 @@ def _layers():
         whether the shared expert lies under a sigmoid gate
         (``gated_shared_expert``) or is absent (``shared_width`` 0: the
         block is its routed part alone and has no ``shared_expert``
-        variables), and the routed experts' gate activation
-        (``hidden_act``: ``silu`` for SwiGLU experts, ``relu`` for
-        ReGLU). ``layer(x, route_from)`` hands the router a tensor of
+        variables), the experts' activation (``hidden_act``: ``silu``
+        for SwiGLU experts, ``relu`` for ReGLU, ``relu2`` the squared
+        ReLU) and whether an expert has a gate at all
+        (``gated_experts``; without one the routed experts are
+        ``down_e(act(up_e x))`` over an ``experts_up`` stack and the
+        shared expert an :class:`UngatedMLP` of the same activation).
+        ``layer(x, route_from)`` hands the router a tensor of
         its own (a router that stands before attention scores the
         decoder layer's input; the experts take ``x``); ``layer(x)``
         routes from ``x``. ``epoch_counters`` tells the epoch runner
@@ -386,7 +421,8 @@ def _layers():
                      selection_bias: bool = False,
                      routed_scaling_factor: float = 1.0,
                      gated_shared_expert: bool = True,
-                     hidden_act: str = "silu", **kwargs):
+                     hidden_act: str = "silu", gated_experts: bool = True,
+                     **kwargs):
             super().__init__(**kwargs)
             from elephas_tpu.ops.moe import EXPERT_ACTIVATIONS, ROUTER_SCORES
 
@@ -400,7 +436,7 @@ def _layers():
                     f"hidden_act {hidden_act!r} is none of "
                     f"{sorted(EXPERT_ACTIVATIONS)}"
                 )
-            self.hidden_act = hidden_act
+            self.hidden_act, self.gated_experts = hidden_act, bool(gated_experts)
             self.scoring_func, self.selection_bias = (
                 scoring_func, bool(selection_bias))
             self.routed_scaling_factor = float(routed_scaling_factor)
@@ -428,8 +464,11 @@ def _layers():
             init = normal(self.init_std)
             self.router = self._weight(
                 "router", (d, self.num_experts), init, False)
-            self.experts_gate_up = self._weight(
-                "experts_gate_up", (held, d, 2 * self.expert_width), init)
+            # a gated expert's gate and up side by side, or its up alone
+            name, columns = (("experts_gate_up", 2) if self.gated_experts
+                             else ("experts_up", 1))
+            self.experts_in = self._weight(
+                name, (held, d, columns * self.expert_width), init)
             self.experts_down = self._weight(
                 "experts_down", (held, self.expert_width, d), init)
             if self.gated_shared_expert:
@@ -442,7 +481,10 @@ def _layers():
                 )
             if self.shared_width:
                 self.shared_expert = SwiGLU(
-                    self.shared_width, self.init_std, name="shared_expert")
+                    self.shared_width, self.init_std, name="shared_expert"
+                ) if self.gated_experts else UngatedMLP(
+                    self.shared_width, self.init_std, self.hidden_act,
+                    name="shared_expert")
                 self.shared_expert.build(input_shape)
             self.route_counts = self.add_weight(
                 name="route_counts", shape=(len(COUNTER_NAMES),),
@@ -462,10 +504,10 @@ def _layers():
             if route_from is not None:
                 routing["route_from"] = route_from.reshape(b * s, d)
             routed, counts = held_experts_ffn(
-                flat, self.router.value, self.experts_gate_up.value,
+                flat, self.router.value, self.experts_in.value,
                 self.experts_down.value, self.experts_held,
                 self.experts_per_token, activation=self.hidden_act,
-                **routing,
+                gated=self.gated_experts, **routing,
             )
             if not self.shared_width:
                 return routed.reshape(b, s, d), counts
@@ -496,6 +538,7 @@ def _layers():
                     "routed_scaling_factor": self.routed_scaling_factor,
                     "gated_shared_expert": self.gated_shared_expert,
                     "hidden_act": self.hidden_act,
+                    "gated_experts": self.gated_experts,
                     "remat": self.remat}
 
     @register
@@ -531,7 +574,8 @@ def _layers():
     # layers (``models/deepseek_v3.py``); they are no public names
     _LAYERS = {
         cls.__name__: cls for cls in (
-            ZeroCentredRMSNorm, SwiGLU, GatedAttention, GatedDeltaNet,
+            ZeroCentredRMSNorm, SwiGLU, UngatedMLP, GatedAttention,
+            GatedDeltaNet,
             SparseMoeBlock, LMHead, _SameShape, _Remat,
         )
     }

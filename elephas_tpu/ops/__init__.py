@@ -13,6 +13,8 @@ package holds the ops where hand-scheduling beats the compiler:
 - :mod:`elephas_tpu.ops.gated_delta` — the gated delta rule of
   recurrent-state (linear-attention) layers, chunked, forward and
   backward.
+- :mod:`elephas_tpu.ops.ssd` — the selective scan of state-space
+  (Mamba-2) layers, chunked, forward and backward.
 - :mod:`elephas_tpu.ops.moe` — expert-parallel and dropless
   held-experts mixture-of-experts FFNs.
 """
@@ -24,10 +26,12 @@ from elephas_tpu.ops.gated_delta import (
     gated_delta_rule,
     gated_delta_rule_recurrent,
 )
+from elephas_tpu.ops.ssd import ssd_chunked, ssd_recurrent
 from elephas_tpu.ops.moe import grouped_matmul, held_experts_ffn
 
 __all__ = [
     "flash_attention", "ring_attention", "ulysses_attention",
     "gated_delta_rule", "gated_delta_rule_recurrent",
+    "ssd_chunked", "ssd_recurrent",
     "grouped_matmul", "held_experts_ffn",
 ]

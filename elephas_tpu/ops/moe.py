@@ -235,8 +235,10 @@ def init_moe_params(
 
 
 ROUTER_SCORES = {"softmax": jax.nn.softmax, "sigmoid": jax.nn.sigmoid}
-# the gate's activation in a held expert: SwiGLU's or ReGLU's
-EXPERT_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+# a held expert's activation: on the gate of a gated expert (SwiGLU's,
+# ReGLU's), on the one product of an ungated one (squared ReLU)
+EXPERT_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+                      "relu2": lambda t: jnp.square(jax.nn.relu(t))}
 
 
 def route_top_k(x, router_w, k: int, score: str = "softmax",
@@ -273,6 +275,22 @@ def _tile(size: int, wanted: int) -> int:
     return wanted
 
 
+def _lane_tile(size: int, wanted: int) -> int:
+    """A tile of a contracted or output width: :func:`_tile`'s where
+    that is at least two lanes' worth (256). Else a multiple of 128
+    (the backward products lay each tile over the other width too, so
+    "the whole width" will not do): the largest up to ``wanted`` that
+    divides ``size`` (2688 = 3 x 896, where the power of two would be
+    128 and a grid step hardly any work), or for a width that is no
+    multiple of 128 the one that pads it least (1856 = 3 x 640 - 64;
+    the kernel masks what lies past the edge)."""
+    tile = _tile(size, wanted)
+    if tile >= 256:
+        return tile
+    lanes = range(wanted // 128, 0, -1)
+    return 128 * min(lanes, key=lambda t: (-size % (128 * t), -t))
+
+
 def grouped_matmul(lhs, rhs, group_sizes, kernel: bool | None = None):
     """``out[r] = lhs[r] @ rhs[group of r]`` for rows sorted by group:
     ``lhs [M, K]``, ``rhs [G, K, N]``, ``group_sizes [G]`` int32. Rows
@@ -293,7 +311,7 @@ def grouped_matmul(lhs, rhs, group_sizes, kernel: bool | None = None):
 
     m, k_dim = lhs.shape
     n_dim = rhs.shape[-1]
-    tiling = (_tile(m, 256), _tile(k_dim, 1024), _tile(n_dim, 1024))
+    tiling = (_tile(m, 256), _lane_tile(k_dim, 1024), _lane_tile(n_dim, 1024))
     # the kernel visits only the tiles that hold a group's rows: what it
     # leaves of the output (and, on the way back, of lhs's gradient) is
     # not written at all, so both sides are selected, never multiplied
@@ -357,11 +375,12 @@ _combine_slots.defvjp(_combine_slots_fwd, _combine_slots_bwd)
 
 
 def _held_part(x, weights, local, w_gate_up, w_down, rows: int, held: int,
-               activation=jax.nn.silu):
+               activation=jax.nn.silu, gated: bool = True):
     """The held experts' part for tokens ``x [T, D]`` through a slot
     buffer of ``rows`` rows, which must hold every slot routed to a
     held expert (``local < held``; ``local [T, k]`` is the expert's
-    index among the held, ``held`` for an absent one)."""
+    index among the held, ``held`` for an absent one). An ungated
+    expert's first product is its ``up`` alone."""
     tokens, k = local.shape
     with jax.named_scope("moe.route"):
         flat = local.reshape(tokens * k)
@@ -381,9 +400,12 @@ def _held_part(x, weights, local, w_gate_up, w_down, rows: int, held: int,
         slots = _take_slots(x, token_of_row, position)
     with jax.named_scope("moe.experts"):
         gate_up = grouped_matmul(slots, w_gate_up, group_sizes)
-        gate, up = jnp.split(gate_up, 2, axis=-1)
-        hidden = (activation(gate.astype(jnp.float32))
-                  * up.astype(jnp.float32)).astype(x.dtype)
+        if gated:
+            gate, up = jnp.split(gate_up, 2, axis=-1)
+            hidden = (activation(gate.astype(jnp.float32))
+                      * up.astype(jnp.float32)).astype(x.dtype)
+        else:
+            hidden = activation(gate_up.astype(jnp.float32)).astype(x.dtype)
         out = grouped_matmul(hidden, w_down, group_sizes)
     with jax.named_scope("moe.route"):
         y = _combine_slots(out, weights, position, token_of_row,
@@ -396,22 +418,26 @@ def _round_up(n: int, to: int) -> int:
 
 
 def held_experts_ffn(x, router_w, w_gate_up, w_down, experts_held, k: int,
-                     route_from=None, activation: str = "silu", **routing):
+                     route_from=None, activation: str = "silu",
+                     gated: bool = True, **routing):
     """The routed part of a sparse block that the experts held here
-    give: ``sum_e p_e * down_e(act(gate_e(x)) * up_e(x))`` over the
-    token's ``k`` chosen experts that lie in ``experts_held``;
-    ``routing`` is the router's rule, as :func:`route_top_k` takes it
-    (``score``, ``select_bias``, ``scale``). ``activation`` names
-    ``act`` (``silu``: SwiGLU experts; ``relu``: ReGLU). The router
-    reads ``route_from [T, D]`` where one is given (a model whose
-    router stands before attention scores the layer's input while its
-    experts take the normed attention result ``x``), else ``x``.
+    give: ``sum_e p_e * expert_e(x)`` over the token's ``k`` chosen
+    experts that lie in ``experts_held``, an expert being gated,
+    ``down_e(act(gate_e(x)) * up_e(x))``, or with ``gated`` false
+    ungated, ``down_e(act(up_e(x)))``; ``routing`` is the router's
+    rule, as :func:`route_top_k` takes it (``score``, ``select_bias``,
+    ``scale``). ``activation`` names ``act`` (``silu``: SwiGLU experts;
+    ``relu``: ReGLU; ``relu2``: the squared ReLU of an ungated expert).
+    The router reads ``route_from [T, D]`` where one is given (a model
+    whose router stands before attention scores the layer's input while
+    its experts take the normed attention result ``x``), else ``x``.
 
     ``x [T, D]``; ``router_w [D, E]`` scores all ``E`` experts;
     ``experts_held = (first, stop)`` is the range of them whose weights
     are here, stacked: ``w_gate_up [E_held, D, 2 * I]`` (gate columns
-    first) and ``w_down [E_held, I, D]``. What the absent experts would
-    add is left out; nothing stands in for it.
+    first; an ungated expert's ``up`` alone, ``[E_held, D, I]``) and
+    ``w_down [E_held, I, D]``. What the absent experts would add is
+    left out; nothing stands in for it.
 
     No token is dropped whatever the imbalance. The slots routed here
     are counted first. Up to twice what uniform routing would send,
@@ -439,7 +465,7 @@ def held_experts_ffn(x, router_w, w_gate_up, w_down, experts_held, k: int,
         routed_here = jnp.sum(group_sizes)
     w_gate_up, w_down = w_gate_up.astype(x.dtype), w_down.astype(x.dtype)
     part = jax.checkpoint(
-        functools.partial(_held_part, held=held,
+        functools.partial(_held_part, held=held, gated=gated,
                           activation=EXPERT_ACTIVATIONS[activation]),
         static_argnums=(5,)
     )

@@ -370,3 +370,48 @@ def test_chunked_gated_delta_rule_fits_at_published_widths():
 
     kernels_in(jax.grad(jax.checkpoint(loss), (0, 1, 2, 3, 4)),
                qk, qk, qk, gate, gate)
+
+
+@pytest.mark.parametrize("k,n", [(2688, 1856), (1856, 2688)])
+def test_grouped_matmul_at_widths_that_are_no_power_of_two(k, n):
+    """12288 slot rows over 8 held experts at hidden 2688 and expert
+    width 1856 (21 x 128 and 14.5 x 128): the tiles are multiples of
+    128 that divide 2688 or pad 1856 least, laid over both widths by
+    the backward products, and all three kernels compile (a tile of 64,
+    the power-of-two rule's, or of the whole 1856 is refused)."""
+    from elephas_tpu.ops.moe import _lane_tile, grouped_matmul
+
+    assert (_lane_tile(2688, 1024), _lane_tile(1856, 1024)) == (896, 640)
+    # the widths of the three LMs the benchmark held before keep theirs
+    assert [_lane_tile(w, 1024) for w in (2048, 512, 768, 2560)] == [
+        1024, 512, 256, 512]
+    rows = on_chip((12288, k), jnp.bfloat16)
+    experts = on_chip((8, k, n), jnp.bfloat16)
+    sizes = on_chip((8,), jnp.int32)
+
+    def loss(rows, experts, sizes):
+        out = grouped_matmul(rows, experts, sizes, kernel=True)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    assert kernels_in(jax.grad(loss, (0, 1)), rows, experts, sizes) >= 3
+
+
+def test_chunked_ssd_scan_fits_at_published_widths():
+    """64 heads of 64 over a 128-wide state, B and C in 8 groups, 2 x
+    8192 tokens in chunks of 128, forward and backward under a
+    checkpoint: compiles, and its temporaries stay under 4 GiB (a
+    layer's decay factors are 537 MB in float32)."""
+    from elephas_tpu.ops.ssd import ssd_chunked
+
+    x = on_chip((2, 8192, 64, 64), jnp.bfloat16)
+    dt = on_chip((2, 8192, 64), jnp.float32)
+    head = on_chip((64,), jnp.float32)
+    bc = on_chip((2, 8192, 8, 128), jnp.bfloat16)
+
+    def loss(x, dt, a_neg, b_in, c_in, d_skip):
+        return jnp.sum(ssd_chunked(
+            x, dt, a_neg, b_in, c_in, d_skip, 128)[0].astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(jax.checkpoint(loss), range(6))).lower(
+        x, dt, head, bc, bc, head).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
