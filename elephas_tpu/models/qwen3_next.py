@@ -44,7 +44,8 @@ from elephas_tpu.models.transformer import (
 )
 
 _LAYERS = None
-COUNTER_NAMES = ("held_slots", "slots", "max_expert_tokens")
+COUNTER_NAMES = ("held_slots", "slots", "max_expert_tokens", "calls",
+                 "blocked_calls")
 LAYER_NAMES = ("ZeroCentredRMSNorm", "SwiGLU", "UngatedMLP", "GatedAttention",
                "GatedDeltaNet", "SparseMoeBlock", "LMHead")
 
@@ -83,6 +84,7 @@ def _layers():
 
     from elephas_tpu.ops.flash_attention import LSE_NAME, OUT_NAME
     from elephas_tpu.ops.gated_delta import RESOLVE_NAME, gated_delta_rule
+    from elephas_tpu.ops.moe import ROUTE_NAME
 
     register = keras.saving.register_keras_serializable(package="elephas_tpu")
     f32 = jnp.float32
@@ -130,7 +132,10 @@ def _layers():
         result and log-sum-exp (its backward kernels' residuals beside
         q, k and v, which are projected again), so that kernel runs once
         a layer; a Gated DeltaNet layer its chunks' triangular
-        inverses; the feed-forward layers nothing."""
+        inverses; a sparse block what its routing decided (the chosen
+        experts and the slot buffer's plan, a few integers a token
+        slot), so that top-k and the ordering run once a layer;
+        the dense feed-forward layers nothing."""
 
         kept: tuple = ()
 
@@ -413,6 +418,7 @@ def _layers():
         and what its entries are."""
 
         epoch_counters = {"route_counts": COUNTER_NAMES}
+        kept = (ROUTE_NAME,)
 
         def __init__(self, num_experts: int, experts_per_token: int,
                      expert_width: int, shared_width: int,

@@ -21,18 +21,26 @@ single-device oracle used by the tests.
 :func:`held_experts_ffn` is the other construction, for experts as they
 are deployed today (hundreds of small gated experts, many a token): the
 router scores ALL experts, the chip computes the part of the result
-that the experts it HOLDS give, and no token is ever dropped: token
-slots are sorted by expert into a buffer sized for the worst case and
-go through one grouped matrix product (:func:`grouped_matmul`) whose
-work follows the rows that are really there.
+that the experts it HOLDS give, and no token is ever dropped: the token
+slots routed to a held expert (a tenth of them, say) are placed by
+expert in a buffer of twice what uniform routing would send
+(:class:`RoutePlan`: one sort of the slots, one of the buffer's rows,
+no scatter, and nothing the size of every slot times a row), go
+through one grouped matrix product (:func:`grouped_matmul`) whose work
+follows the rows that are really there, and come back to their tokens
+as one sum over the buffer's rows on the MXU
+(:func:`_sum_rows_by_token`). Past that buffer the same program takes
+the tokens in blocks.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from elephas_tpu.utils import backend_guard
 
@@ -234,11 +242,41 @@ def init_moe_params(
 # -- dropless routing over the experts held ------------------------------
 
 
+# the name (``jax.ad_checkpoint.checkpoint_name``) of what a sparse
+# block's routing decided: the experts the router chose and the leaves
+# of the one-buffer :class:`RoutePlan`. A caller whose own
+# ``jax.checkpoint`` keeps it (``save_only_these_names``) runs top-k and
+# the ordering once a layer, not again in the backward pass
+ROUTE_NAME = "moe_route"
+
 ROUTER_SCORES = {"softmax": jax.nn.softmax, "sigmoid": jax.nn.sigmoid}
 # a held expert's activation: on the gate of a gated expert (SwiGLU's,
 # ReGLU's), on the one product of an ungated one (squared ReLU)
 EXPERT_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
                       "relu2": lambda t: jnp.square(jax.nn.relu(t))}
+
+
+@jax.custom_vjp
+def _chosen(scores, experts):
+    """``scores [T, E]`` at ``experts [T, k]``. Its transpose is ``k``
+    compares and sums over ``[T, E]``, where JAX's own is a scatter-add
+    of every slot, which costs the chip twice that."""
+    return jnp.take_along_axis(scores, experts, axis=-1)
+
+
+def _chosen_fwd(scores, experts):
+    return _chosen(scores, experts), (experts, scores.shape[-1])
+
+
+def _chosen_bwd(residuals, g):
+    experts, count = residuals
+    every = jnp.arange(count, dtype=experts.dtype)
+    # slot by slot, so that nothing of [T, k, E] is ever written
+    return sum(jnp.where(experts[:, j, None] == every, g[:, j, None], 0)
+               for j in range(experts.shape[1])), None
+
+
+_chosen.defvjp(_chosen_fwd, _chosen_bwd)
 
 
 def route_top_k(x, router_w, k: int, score: str = "softmax",
@@ -255,12 +293,13 @@ def route_top_k(x, router_w, k: int, score: str = "softmax",
         precision=jax.lax.Precision.HIGHEST,
     )
     scores = ROUTER_SCORES[score](logits)
-    if select_bias is None:
-        weights, experts = jax.lax.top_k(scores, k)
-    else:
-        _, experts = jax.lax.top_k(
-            scores + select_bias.astype(jnp.float32), k)
-        weights = jnp.take_along_axis(scores, experts, axis=-1)
+    chooses = scores if select_bias is None else (
+        scores + select_bias.astype(jnp.float32))
+    # the choice carries a name, and the weights are read through it
+    # (top-k's own values are these, bit for bit): a rematerialising
+    # caller that keeps the name does not choose again
+    experts = checkpoint_name(jax.lax.top_k(chooses, k)[1], ROUTE_NAME)
+    weights = _chosen(scores, experts)
     # the published sigmoid router's guard against a zero sum; below
     # float32's last bit of any sum of softmax's k largest
     weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
@@ -320,97 +359,305 @@ def grouped_matmul(lhs, rhs, group_sizes, kernel: bool | None = None):
     return jnp.where(held, out, 0)
 
 
-def _slot_rows(rows, position):
-    """``[rows[position[:, j]] for j]`` as float32, with a zero row
-    where ``position == len(rows)`` (a slot outside the buffer):
-    ``position [T, k]`` -> ``k`` arrays ``[T, D]``."""
-    padded = jnp.concatenate([rows, jnp.zeros_like(rows[:1])], axis=0)
-    return [padded[position[:, j]].astype(jnp.float32)
-            for j in range(position.shape[1])]
+# tokens a tile of the row-to-token sum (one MXU pass is 128 deep)
+_TOKEN_TILE = 128
+
+
+class RoutePlan(NamedTuple):
+    """Which token slots sit where in a buffer of ``R`` rows sorted by
+    expert (``grouped_matmul``'s order), and in which order the
+    buffer's rows follow the tokens: rank ``q`` is the ``q``-th held
+    slot counted token by token. A row or a rank past the slots held
+    has weight 0."""
+
+    token_of_row: jax.Array    # [R] the token a buffer row came from
+    weight_of_row: jax.Array   # [R] float32, the router's weight of it
+    group_sizes: jax.Array     # [held] rows an expert
+    row_of_rank: jax.Array     # [R] a rank's buffer row, R past the held
+    token_of_rank: jax.Array   # [R] its token, T past the held
+    choice_of_rank: jax.Array  # [R] which of its token's k slots it is
+    weight_of_rank: jax.Array  # [R] float32, its weight
+    tile_ranks: jax.Array      # [tiles] ranks a tile of _TOKEN_TILE tokens
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "held"))
+def _route_plan(local, weights, rows: int, held: int) -> RoutePlan:
+    """The plan for ``local [T, k]`` (a slot's expert among the held,
+    ``held`` for an absent one) and a buffer of ``rows`` rows, which is
+    right where the buffer holds every held slot. One stable sort of
+    the slots by expert, weights riding along, says what each row
+    holds; one sort of the buffer's ``rows`` slot numbers brings the
+    rows to token order; the experts' rows are counted by compares (a
+    ``bincount`` of every slot is a scatter, which costs the chip more
+    than sorting them). Nothing is gathered at ``T * k`` and nothing
+    has a row's width."""
+    tokens, k = local.shape
+    every = tokens * k
+    weights = jax.lax.stop_gradient(weights).astype(jnp.float32)
+    in_held = local < held
+    group_sizes = sum(
+        jnp.sum(local[:, j, None] == jnp.arange(held, dtype=jnp.int32),
+                axis=0, dtype=jnp.int32) for j in range(k))
+    # absent experts sort behind the held: the buffer's rows come first
+    by_expert, slot_of_row, weight_of_row = (a[:rows] for a in jax.lax.sort(
+        (local.reshape(every), jnp.arange(every, dtype=jnp.int32),
+         weights.reshape(every)), num_keys=1, is_stable=True))
+    is_held = by_expert < held
+    weight_of_row = jnp.where(is_held, weight_of_row, 0.0)
+    # token order is the order of the slots' numbers
+    slot_of_rank, row_of_rank, weight_of_rank = jax.lax.sort(
+        (jnp.where(is_held, slot_of_row, every),
+         jnp.where(is_held, jnp.arange(rows, dtype=jnp.int32), rows),
+         weight_of_row), num_keys=1)
+    tiles = -(-tokens // _TOKEN_TILE)
+    tile_ranks = jnp.sum(jnp.pad(
+        jnp.sum(in_held, axis=1, dtype=jnp.int32),
+        (0, tiles * _TOKEN_TILE - tokens)).reshape(tiles, _TOKEN_TILE), axis=1)
+    return RoutePlan(slot_of_row // k, weight_of_row, group_sizes,
+                     row_of_rank, slot_of_rank // k, slot_of_rank % k,
+                     weight_of_rank, tile_ranks)
+
+
+def _split_bf16(values):
+    """``values`` float32 as three bfloat16 terms that add up to it
+    exactly (8 + 8 + 8 bits of significand), on a new last axis.
+    ``reduce_precision`` and not a cast there and back, which a
+    compiler may take for the identity."""
+    terms, rest = [], values
+    for _ in range(3):
+        term = jax.lax.reduce_precision(rest, exponent_bits=8,
+                                        mantissa_bits=7)
+        terms.append(term.astype(jnp.bfloat16))
+        rest = rest - term
+    return jnp.stack(terms, axis=-1)
+
+
+def _sum_ranks_kernel(metadata, marks, values, out, acc, *, terms: int,
+                      row_tile: int, lane_tile: int):
+    """One grid step of :func:`_sum_rows_by_token`: the ranks of one
+    row tile that belong to one tile of tokens, ``marks^T @ values``
+    added to the accumulator; the ``terms`` slabs of the accumulator are
+    added up as the token tile is left (megablox ``tgmm``'s scheme, the
+    terms besides)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import (
+        _get_group_size, _get_store_mask)
+
+    step = pl.program_id(1)
+    tile_of_step = metadata[1]
+    tile = tile_of_step[step]
+    last = pl.num_programs(1) - 1
+    before = tile_of_step[jnp.where(step > 0, step - 1, 0)]
+    after = tile_of_step[jnp.where(step < last, step + 1, last)]
+
+    @pl.when((step == 0) | (before != tile))
+    def _zero():
+        acc[...] = jnp.zeros_like(acc)
+
+    @pl.when(_get_group_size(grid_id=step, group_metadata=metadata) > 0)
+    def _add():
+        mine = functools.partial(
+            _get_store_mask, grid_id=step, group_metadata=metadata,
+            tm=row_tile)
+        marked = jnp.where(mine(tn=marks.shape[1]), marks[...], 0)
+        rows = jnp.where(mine(tn=lane_tile), values[...], 0)
+        acc[...] += jax.lax.dot(marked.swapaxes(0, 1), rows,
+                                preferred_element_type=jnp.float32)
+
+    @pl.when((step == last) | (after != tile))
+    def _store():
+        total = acc[:_TOKEN_TILE]
+        for term in range(1, terms):
+            total += acc[term * _TOKEN_TILE:(term + 1) * _TOKEN_TILE]
+        out[...] = total.astype(out.dtype)
+
+
+def _sum_rows_by_token(values, plan: RoutePlan, tokens: int, weights=None,
+                       dtype=jnp.float32, kernel: bool | None = None):
+    """``y[t] = sum of weights[q] * values[q] over the ranks q of token
+    t``, added up in float32 and given in ``dtype``: ``values [R, N]``
+    in token order (``plan.row_of_rank``'s), ``weights [R]`` float32 or
+    None for ones. A token's ranks lie
+    together, so the sum is the grouped product's transposed form
+    (megablox ``tgmm``'s scheme) over tiles of ``_TOKEN_TILE`` tokens:
+    on the other side of the product stands a one-hot of the token
+    within its tile that carries the weight, as three bfloat16 terms
+    that add up to it exactly, whose three products the kernel adds in
+    its accumulator. The MXU adds, nothing is scattered, and the rows
+    are read once. On the ``cpu`` backend it is a segment sum;
+    ``kernel`` as in :func:`grouped_matmul`."""
+    if kernel is None:
+        kernel = not backend_guard.pallas_interpret()
+    if not kernel:
+        values = values.astype(jnp.float32)
+        if weights is not None:
+            values = weights[:, None] * values
+        return jax.ops.segment_sum(
+            values, plan.token_of_rank, num_segments=tokens,
+            indices_are_sorted=True).astype(dtype)
+    return _sum_rows_on_the_mxu(values, plan, weights, tokens=tokens,
+                                dtype=jnp.dtype(dtype))
+
+
+# jitted like megablox's ``gmm``: a model's layers call it at the same
+# shapes, and are traced and lowered (the kernel with them) once, not
+# once a call: that is seconds of every process's set-up
+@functools.partial(jax.jit, static_argnames=("tokens", "dtype"))
+def _sum_rows_on_the_mxu(values, plan: RoutePlan, weights, *, tokens: int,
+                         dtype):
+    """:func:`_sum_rows_by_token` as a Pallas kernel."""
+    rows, width = values.shape
+    if values.dtype != jnp.bfloat16:
+        # a float32 model: the weighted rows themselves as three terms
+        # side by side, and the three sums added
+        if weights is not None:
+            values = weights[:, None] * values
+        y = _sum_rows_on_the_mxu(
+            _split_bf16(values.astype(jnp.float32)).swapaxes(1, 2).reshape(
+                rows, 3 * width), plan, None, tokens=tokens,
+            dtype=jnp.dtype(jnp.float32))
+        return sum(jnp.split(y, 3, axis=-1)).astype(dtype)
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import (
+        make_group_metadata)
+
+    onehot = (plan.token_of_rank[:, None] % _TOKEN_TILE
+              == jnp.arange(_TOKEN_TILE, dtype=jnp.int32))       # [R, tile]
+    if weights is None:
+        terms, marks = 1, onehot.astype(jnp.bfloat16)
+    else:
+        terms = 3
+        marks = jnp.where(onehot[:, None, :],
+                          _split_bf16(weights)[:, :, None], 0
+                          ).reshape(rows, terms * _TOKEN_TILE)
+    lanes = _round_up(width, 128)
+    if lanes != width:
+        values = jnp.pad(values, ((0, 0), (0, lanes - width)))
+    row_tile, lane_tile = _tile(rows, 256), _lane_tile(lanes, 1024)
+    tiles = plan.tile_ranks.shape[0]
+    metadata, steps = make_group_metadata(
+        group_sizes=plan.tile_ranks, m=rows, tm=row_tile,
+        start_group=jnp.int32(0), num_nonzero_groups=tiles,
+        visit_empty_groups=True)
+    y = pl.pallas_call(
+        functools.partial(_sum_ranks_kernel, terms=terms, row_tile=row_tile,
+                          lane_tile=lane_tile),
+        out_shape=jax.ShapeDtypeStruct((tiles, _TOKEN_TILE, lanes), dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            in_specs=[
+                pl.BlockSpec((row_tile, terms * _TOKEN_TILE),
+                             lambda n, s, meta: (meta[2][s], 0)),
+                pl.BlockSpec((row_tile, lane_tile),
+                             lambda n, s, meta: (meta[2][s], n)),
+            ],
+            out_specs=pl.BlockSpec((None, _TOKEN_TILE, lane_tile),
+                                   lambda n, s, meta: (meta[1][s], 0, n)),
+            grid=(pl.cdiv(lanes, lane_tile), steps),
+            scratch_shapes=[pltpu.VMEM(
+                (terms * _TOKEN_TILE, lane_tile), jnp.float32)],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name="moe_sum_rows_by_token",
+    )(metadata, marks, values)
+    return y.reshape(tiles * _TOKEN_TILE, lanes)[:tokens, :width]
 
 
 @jax.custom_vjp
-def _take_slots(x, token_of_row, position):
-    """``x[token_of_row]``: the rows of the slot buffer, ``[R, D]``.
-    ``position [T, k]`` says where each token slot sits in the buffer
-    (``R`` where it does not), so that the transpose is ``k`` gathers
-    and a sum, never a scatter."""
-    return x[token_of_row]
+def _take_slots(x, plan: RoutePlan):
+    """``x[plan.token_of_row]``: the rows of the slot buffer, ``[R, D]``.
+    The transpose sums each token's rows (:func:`_sum_rows_by_token`),
+    never a scatter."""
+    return x[plan.token_of_row]
 
 
-def _take_slots_fwd(x, token_of_row, position):
-    return x[token_of_row], position
+def _take_slots_fwd(x, plan):
+    return x[plan.token_of_row], (plan, x.shape[0])
 
 
-def _take_slots_bwd(position, g):
-    return sum(_slot_rows(g, position)).astype(g.dtype), None, None
+def _take_slots_bwd(residuals, g):
+    plan, tokens = residuals
+    by_rank = jnp.take(g, plan.row_of_rank, axis=0, mode="clip")
+    return _sum_rows_by_token(by_rank, plan, tokens, dtype=g.dtype), None
 
 
 _take_slots.defvjp(_take_slots_fwd, _take_slots_bwd)
 
 
 @jax.custom_vjp
-def _combine_slots(out, weights, position, token_of_row, weight_of_row):
-    """``y[t] = sum_j weights[t, j] * out[position[t, j]]`` in float32
-    (a slot outside the buffer adds nothing); gathers both ways."""
-    return sum(weights[:, j, None] * rows
-               for j, rows in enumerate(_slot_rows(out, position)))
+def _combine_slots(out, weights, plan: RoutePlan):
+    """``y[t] = sum_j weights[t, j] * out[row of slot (t, j)]`` summed
+    in float32 (a slot outside the buffer adds nothing), in ``out``'s
+    dtype: ``out``'s rows brought to token order and summed a token
+    under their weights. ``weights``' gradient is a dot a row, brought
+    to ``[T, k]`` by the same sum over a one-hot of the slot's ``j``."""
+    by_rank = jnp.take(out, plan.row_of_rank, axis=0, mode="clip")
+    return _sum_rows_by_token(by_rank, plan, weights.shape[0],
+                              weights=plan.weight_of_rank, dtype=out.dtype)
 
 
-def _combine_slots_fwd(out, weights, position, token_of_row, weight_of_row):
-    y = _combine_slots(out, weights, position, token_of_row, weight_of_row)
-    return y, (out, position, token_of_row, weight_of_row)
+def _combine_slots_fwd(out, weights, plan):
+    return _combine_slots(out, weights, plan), (out, plan, weights.shape)
 
 
 def _combine_slots_bwd(residuals, g):
-    out, position, token_of_row, weight_of_row = residuals
-    d_out = (weight_of_row[:, None] * g[token_of_row]).astype(out.dtype)
-    d_weights = jnp.stack(
-        [jnp.sum(g * rows, axis=-1) for rows in _slot_rows(out, position)],
-        axis=1)
-    return d_out, d_weights, None, None, None
+    out, plan, (tokens, k) = residuals
+    g_rows = g[plan.token_of_row].astype(jnp.float32)
+    d_out = (plan.weight_of_row[:, None] * g_rows).astype(out.dtype)
+    dot = jnp.sum(g_rows * out.astype(jnp.float32), axis=-1)
+    dot = jnp.take(dot, plan.row_of_rank, mode="fill", fill_value=0.0)
+    which = (plan.choice_of_rank[:, None]
+             == jnp.arange(k, dtype=jnp.int32)).astype(jnp.bfloat16)
+    d_weights = _sum_rows_by_token(which, plan, tokens, weights=dot)
+    return d_out, d_weights, None
 
 
 _combine_slots.defvjp(_combine_slots_fwd, _combine_slots_bwd)
 
 
-def _held_part(x, weights, local, w_gate_up, w_down, rows: int, held: int,
+def _held_part(x, weights, plan: RoutePlan, w_gate_up, w_down,
                activation=jax.nn.silu, gated: bool = True):
-    """The held experts' part for tokens ``x [T, D]`` through a slot
-    buffer of ``rows`` rows, which must hold every slot routed to a
-    held expert (``local < held``; ``local [T, k]`` is the expert's
-    index among the held, ``held`` for an absent one). An ungated
-    expert's first product is its ``up`` alone."""
-    tokens, k = local.shape
+    """The held experts' part for tokens ``x [T, D]`` through the slot
+    buffer that ``plan`` lays out, which must hold every slot routed to
+    a held expert. An ungated expert's first product is its ``up``
+    alone."""
     with jax.named_scope("moe.route"):
-        flat = local.reshape(tokens * k)
-        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-        # absent experts sort behind the held: the buffer's rows are the
-        # first of the order
-        in_buffer = order[:rows]
-        token_of_row = in_buffer // k
-        position = jnp.minimum(
-            jnp.argsort(order).astype(jnp.int32), rows
-        ).reshape(tokens, k)
-        weight_of_row = jnp.where(
-            flat[in_buffer] < held, weights.reshape(tokens * k)[in_buffer],
-            0.0,
-        )
-        group_sizes = jnp.bincount(flat, length=held + 1)[:held]
-        slots = _take_slots(x, token_of_row, position)
+        slots = _take_slots(x, plan)
     with jax.named_scope("moe.experts"):
-        gate_up = grouped_matmul(slots, w_gate_up, group_sizes)
+        gate_up = grouped_matmul(slots, w_gate_up, plan.group_sizes)
         if gated:
             gate, up = jnp.split(gate_up, 2, axis=-1)
             hidden = (activation(gate.astype(jnp.float32))
                       * up.astype(jnp.float32)).astype(x.dtype)
         else:
             hidden = activation(gate_up.astype(jnp.float32)).astype(x.dtype)
-        out = grouped_matmul(hidden, w_down, group_sizes)
+        out = grouped_matmul(hidden, w_down, plan.group_sizes)
     with jax.named_scope("moe.route"):
-        y = _combine_slots(out, weights, position, token_of_row,
-                           weight_of_row)
-    return y.astype(x.dtype)
+        return _combine_slots(out, weights, plan)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _compute_copy(weights, dtype):
+    """``weights`` in the compute ``dtype``, for the branches of
+    ``held_experts_ffn``'s ``lax.cond`` to close over. Its gradient
+    crosses an ``optimization_barrier`` before it is widened again:
+    without one the compiler may move that cast into the branches, and
+    a layer's expert gradients then wait for the optimizer in float32
+    (in the hybrid LM's epoch program 0.2 GB a layer more, PR 43)."""
+    return weights.astype(dtype)
+
+
+def _compute_copy_fwd(weights, dtype):
+    return weights.astype(dtype), jnp.zeros((), weights.dtype)
+
+
+def _compute_copy_bwd(dtype, like, g):
+    return (jax.lax.optimization_barrier(g).astype(like.dtype),)
+
+
+_compute_copy.defvjp(_compute_copy_fwd, _compute_copy_bwd)
 
 
 def _round_up(n: int, to: int) -> int:
@@ -439,60 +686,72 @@ def held_experts_ffn(x, router_w, w_gate_up, w_down, experts_held, k: int,
     ``w_down [E_held, I, D]``. What the absent experts would add is
     left out; nothing stands in for it.
 
-    No token is dropped whatever the imbalance. The slots routed here
-    are counted first. Up to twice what uniform routing would send,
-    they go through one buffer of that size; past it (every token may
-    choose ``k`` held experts) the tokens are taken in blocks, each
-    through a buffer that holds all of its ``k`` slots a token. Both
-    are the same program at two sizes, chosen by a ``lax.cond`` on the
-    count, and each is rematerialised in the backward pass, so that
-    neither keeps more than its inputs.
+    No token is dropped whatever the imbalance. Up to twice what uniform
+    routing would send, the slots routed here go through one buffer of
+    that size, laid out by a :class:`RoutePlan` that is built from the
+    held slots alone, before the choice of path, and carries
+    :data:`ROUTE_NAME` as the router's choice does: a caller that
+    rematerialises the block and keeps that name orders the slots once.
+    Past that size (every token may choose ``k`` held experts) the
+    tokens are taken in blocks, each through a buffer that holds all of
+    its ``k`` slots a token, under a plan of its own. Both are the same
+    program at two sizes, chosen by a ``lax.cond`` on the count, and
+    each is rematerialised in the backward pass, so that neither keeps
+    the buffer, the experts' products or the hidden rows.
 
-    Returns ``(y [T, D] in x's dtype, counts int32 [3])``; ``counts``
+    Returns ``(y [T, D] in x's dtype, counts int32 [5])``; ``counts``
     is (token slots routed to held experts, token slots in all, the
-    fullest held expert's tokens)."""
+    fullest held expert's tokens, 1 for the call, 1 where the call went
+    in blocks)."""
     first, stop = experts_held
     held = stop - first
     tokens = x.shape[0]
+    every = tokens * k
+    usual = min(_round_up(2 * every * held // router_w.shape[-1], 8), every)
+    named = functools.partial(
+        jax.tree.map, lambda leaf: checkpoint_name(leaf, ROUTE_NAME))
     with jax.named_scope("moe.route"):
         weights, experts = route_top_k(
             x if route_from is None else route_from, router_w, k, **routing)
         local = jnp.where(
             (experts >= first) & (experts < stop), experts - first, held
         )
-        group_sizes = jnp.bincount(
-            local.reshape(-1), length=held + 1)[:held].astype(jnp.int32)
-        routed_here = jnp.sum(group_sizes)
-    w_gate_up, w_down = w_gate_up.astype(x.dtype), w_down.astype(x.dtype)
-    part = jax.checkpoint(
-        functools.partial(_held_part, held=held, gated=gated,
-                          activation=EXPERT_ACTIVATIONS[activation]),
-        static_argnums=(5,)
-    )
-    every = tokens * k
-    usual = _round_up(2 * every * held // router_w.shape[-1], 8)
+        plan = named(_route_plan(local, weights, usual, held))
+        routed_here = jnp.sum(plan.group_sizes)
+    w_gate_up = _compute_copy(w_gate_up, x.dtype)
+    w_down = _compute_copy(w_down, x.dtype)
+    part = jax.checkpoint(functools.partial(
+        _held_part, gated=gated, activation=EXPERT_ACTIVATIONS[activation]))
 
-    def in_blocks(x, weights, local):
+    def in_blocks(x, weights, local, plan):
         blocks = max(1, every // max(usual, 1))
         while tokens % blocks:
             blocks -= 1
         size = tokens // blocks
         split = lambda t: t.reshape((blocks, size) + t.shape[1:])  # noqa: E731
-        y = jax.lax.map(
-            lambda b: part(b[0], b[1], b[2], w_gate_up, w_down, size * k),
-            (split(x), split(weights), split(local)),
-        )
+
+        def block(b):
+            with jax.named_scope("moe.route"):
+                own = _route_plan(b[2], b[1], size * k, held)
+            return part(b[0], b[1], own, w_gate_up, w_down)
+
+        y = jax.lax.map(block, (split(x), split(weights), split(local)))
         return y.reshape(tokens, -1)
 
-    if usual >= every:
-        y = part(x, weights, local, w_gate_up, w_down, every)
+    if usual == every:
+        y = part(x, weights, plan, w_gate_up, w_down)
+        blocked = jnp.int32(0)
     else:
+        blocked = (routed_here > usual).astype(jnp.int32)
         y = jax.lax.cond(
-            routed_here <= usual,
-            lambda *a: part(*a, w_gate_up, w_down, usual),
-            in_blocks, x, weights, local,
+            blocked,
+            in_blocks,
+            lambda x, weights, local, plan: part(
+                x, weights, plan, w_gate_up, w_down),
+            x, weights, local, plan,
         )
     counts = jnp.stack([
-        routed_here, jnp.int32(every), jnp.max(group_sizes),
+        routed_here, jnp.int32(every), jnp.max(plan.group_sizes),
+        jnp.int32(1), blocked,
     ]).astype(jnp.int32)
     return y, counts

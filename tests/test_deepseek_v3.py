@@ -281,7 +281,7 @@ def test_no_token_dropped_when_all_choose_one_held_expert(ref):
     got, ntv = _stateless(layer, params, x)
     _close(got, want)
     counts = [v for v in ntv if v.dtype == jnp.int32][0]
-    held_slots, slots, fullest = (int(v) for v in counts)
+    held_slots, slots, fullest = (int(v) for v in counts[:3])
     assert slots == 2 * SEQ * 6 and fullest == 2 * SEQ
     assert held_slots >= 2 * SEQ
 

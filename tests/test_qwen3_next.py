@@ -391,7 +391,7 @@ def test_no_token_dropped_when_all_choose_one_held_expert(ref):
     got, ntv = layer.stateless_call(
         tv, [v.value for v in layer.non_trainable_variables], x)
     _close(got, want)
-    held_slots, slots, fullest = (int(v) for v in ntv[0])
+    held_slots, slots, fullest = (int(v) for v in ntv[0][:3])
     assert slots == 2 * SEQ * 2 and fullest == 2 * SEQ
     assert held_slots >= 2 * SEQ
 
@@ -435,6 +435,197 @@ def test_held_experts_ffn_paths(held, bias):
     want_g = jax.grad(loss(plain), (0, 1, 2, 3))(*args)
     for g, w in zip(got_g, want_g):
         _close(g, w, 1e-5)
+
+
+def _brute_force_plan(local, weights, rows, held):
+    """The plan slot by slot on the host: the held slots in the order of
+    their experts, token order within an expert."""
+    tokens, k = local.shape
+    slots = [(int(local[t, j]), t, j) for t in range(tokens)
+             for j in range(k) if local[t, j] < held]
+    row_of_slot = {}
+    token_of_row, weight_of_row = np.zeros(rows, int), np.zeros(rows)
+    for row, (_, t, j) in enumerate(sorted(slots)):  # stable in (t, j)
+        row_of_slot[t, j], token_of_row[row] = row, t
+        weight_of_row[row] = weights[t, j]
+    row_of_rank, token_of_rank = np.full(rows, rows), np.full(rows, tokens)
+    choice_of_rank, weight_of_rank = np.zeros(rows, int), np.zeros(rows)
+    tile_ranks = np.zeros(-(-tokens // 128), int)
+    for rank, (_, t, j) in enumerate(slots):  # token order
+        row_of_rank[rank], token_of_rank[rank] = row_of_slot[t, j], t
+        choice_of_rank[rank], weight_of_rank[rank] = j, weights[t, j]
+        tile_ranks[t // 128] += 1
+    sizes = np.bincount(local.reshape(-1), minlength=held + 1)[:held]
+    return dict(
+        token_of_row=token_of_row, weight_of_row=weight_of_row,
+        group_sizes=sizes, row_of_rank=row_of_rank,
+        token_of_rank=token_of_rank, choice_of_rank=choice_of_rank,
+        weight_of_rank=weight_of_rank, tile_ranks=tile_ranks), len(slots)
+
+
+@pytest.mark.parametrize("case", [
+    "mixed", "one-token-holds-all-k", "one-held-expert", "no-slot-held",
+    "buffer-of-every-slot"])
+def test_route_plan_against_brute_force(case):
+    """The slot buffer's plan (rows by expert, token order within one;
+    the buffer's order and token order inverse to each other;
+    ``group_sizes`` a count) for tokens with every slot held, with none,
+    a held range of one expert and no held slot at all."""
+    from elephas_tpu.ops.moe import _route_plan
+
+    tokens, k, experts, held, first = 150, 4, 12, 5, 3
+    rng = np.random.default_rng(5)
+    chosen = np.stack([rng.permutation(experts)[:k] for _ in range(tokens)])
+    if case == "one-token-holds-all-k":
+        chosen[7] = first + np.arange(k)[::-1]
+        chosen[8:20] = np.arange(k) + first + held  # and twelve hold none
+    elif case == "one-held-expert":
+        held = 1
+    elif case == "no-slot-held":
+        chosen = np.where(
+            (chosen >= first) & (chosen < first + held), experts, chosen)
+    local = np.where((chosen >= first) & (chosen < first + held),
+                     chosen - first, held).astype(np.int32)
+    weights = rng.random((tokens, k)).astype(np.float32)
+    routed = int((local < held).sum())
+    rows = tokens * k if case == "buffer-of-every-slot" else routed + 3
+    want, n = _brute_force_plan(local, weights, rows, held)
+    got = jax.jit(_route_plan, static_argnums=(2, 3))(
+        jnp.asarray(local), jnp.asarray(weights), rows, held)._asdict()
+    assert n == routed and (n == 0) == (case == "no-slot-held")
+    # past the slots held a row or a rank has no weight, whatever it names
+    for name in ("token_of_row", "choice_of_rank"):
+        got[name], want[name] = got[name][:n], want[name][:n]
+    assert sorted(got) == sorted(want)
+    for name in want:
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+    # the buffer's order and token order are inverse to each other
+    assert sorted(got["row_of_rank"][:n]) == list(range(n))
+    np.testing.assert_array_equal(
+        got["token_of_row"][got["row_of_rank"][:n]],
+        got["token_of_rank"][:n])
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "ungated"])
+@pytest.mark.parametrize("k", [2, 6, 10])
+def test_held_experts_ffn_gradients_with_a_router_tensor(k, gated):
+    """Forward and every gradient (tokens, the router's tensor, router,
+    both expert stacks) against the plain sum over the held experts, for
+    2, 6 and 10 experts a token, gated and ungated."""
+    from elephas_tpu.ops import held_experts_ffn
+
+    t, d, e, width, held = 64, 16, 32, 8, (8, 16)
+    n = held[1] - held[0]
+    ks = jax.random.split(jax.random.key(13 + k), 5)
+    x = jax.random.normal(ks[0], (t, d))
+    seen = jax.random.normal(ks[1], (t, d))
+    router = jax.random.normal(ks[2], (d, e))
+    w_in = jax.random.normal(ks[3], (n, d, (2 if gated else 1) * width)) * 0.3
+    down = jax.random.normal(ks[4], (n, width, d)) * 0.3
+    act = "silu" if gated else "relu2"
+
+    def plain(x, seen, router, w_in, down):
+        p = jax.nn.softmax(seen @ router, -1)
+        top, chosen = jax.lax.top_k(p, k)
+        top = top / top.sum(-1, keepdims=True)
+        y = 0.0
+        for i in range(n):
+            w = jnp.sum(jnp.where(chosen == held[0] + i, top, 0.0), -1)
+            if gated:
+                gate, up = jnp.split(x @ w_in[i], 2, -1)
+                hidden = jax.nn.silu(gate) * up
+            else:
+                hidden = jnp.square(jax.nn.relu(x @ w_in[i]))
+            y = y + w[:, None] * (hidden @ down[i])
+        return y
+
+    def ours(x, seen, router, w_in, down):
+        return held_experts_ffn(x, router, w_in, down, held, k,
+                                route_from=seen, activation=act,
+                                gated=gated)[0]
+
+    args = (x, seen, router, w_in, down)
+    _close(ours(*args), plain(*args), 1e-5)
+    loss = lambda f: lambda *a: jnp.sum(jnp.sin(f(*a)))  # noqa: E731
+    for g, w in zip(jax.grad(loss(ours), range(5))(*args),
+                    jax.grad(loss(plain), range(5))(*args)):
+        _close(g, w, 1e-5)
+
+
+def test_rematerialised_sparse_block_routes_once():
+    """A sparse block under ``remat`` keeps what its routing decided
+    (its ``kept``): the gradient's program holds one top-k, one sort of
+    every token slot (the plan's; the chip sorts them in less time than
+    it counts them by scatter) and no ``k`` passes of ``[T, D]``
+    gathers; with nothing kept it chooses and sorts twice, to the same
+    numbers."""
+    import re
+
+    from elephas_tpu.models import qwen3_next as zoo
+
+    tokens, d, experts, k = 256, 32, 64, 6  # a buffer of 384 rows
+    x = jax.random.normal(jax.random.key(3), (1, tokens, d))
+
+    def gradient_and_text():
+        layer = zoo.SparseMoeBlock(experts, k, 16, 16, (8, 16), remat=True,
+                                   name="moe")
+        layer.build(x.shape)
+        params = [0.2 * jax.random.normal(jax.random.key(i), v.shape)
+                  for i, v in enumerate(layer.trainable_variables)]
+        counts = [v.value for v in layer.non_trainable_variables]
+        loss = lambda p, x: jnp.sum(  # noqa: E731
+            jnp.sin(layer.stateless_call(p, counts, x)[0]))
+        fn = jax.jit(jax.grad(loss, (0, 1)))
+        return fn(params, x), fn.lower(params, x).as_text()
+
+    def sorts_of_every_slot(text):
+        return [
+            int(np.prod([int(n) for n in m.group(1).split("x")]))
+            for m in re.finditer(
+                r'"stablehlo\.sort"\(.*?\(tensor<([0-9x]+)x[a-z]', text, re.S)
+        ].count(tokens * k)
+
+    kept, text = gradient_and_text()
+    assert text.count("chlo.top_k") == 1
+    assert sorts_of_every_slot(text) == 1
+    wide = re.findall(
+        rf'"stablehlo\.gather".*-> tensor<{tokens}x{d}x', text)
+    assert len(wide) <= 4  # two a direction
+    with pytest.MonkeyPatch.context() as nothing_kept:
+        nothing_kept.setattr(zoo.SparseMoeBlock, "kept", ())
+        recomputed, text = gradient_and_text()
+    assert text.count("chlo.top_k") == 2
+    assert sorts_of_every_slot(text) == 2
+    for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(recomputed)):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("bias,blocked", [(0.0, 0), (60.0, 1)],
+                         ids=["uniform", "all-choose-one-held-expert"])
+def test_sparse_block_counts_its_calls_and_the_blocked_ones(bias, blocked):
+    """``route_counts`` says how often the block ran and how often more
+    slots were routed to the held experts than the one buffer holds:
+    never under a uniform router, every call where all tokens choose
+    one held expert (the router of the test above)."""
+    from elephas_tpu.models import qwen3_next as zoo
+
+    layer = _keras_layer("moe")
+    x = jax.random.normal(jax.random.key(11), (2, SEQ, 32)).at[..., 0].set(1.0)
+    layer.build(x.shape)
+    names = [v.path.split("/")[-1] for v in layer.trainable_variables]
+    params = [0.2 * jax.random.normal(jax.random.key(i), v.shape)
+              for i, v in enumerate(layer.trainable_variables)]
+    router = names.index("router")
+    params[router] = params[router].at[0, 5].add(bias)
+    counts = [v.value for v in layer.non_trainable_variables]
+    for calls in (1, 2):
+        _, counts = layer.stateless_call(params, counts, x)
+        counted = dict(zip(zoo.COUNTER_NAMES, np.asarray(counts[0])))
+        assert counted["calls"] == calls
+        assert counted["blocked_calls"] == calls * blocked
+        assert counted["slots"] == calls * 2 * SEQ * 2
+    usual = 2 * (2 * SEQ * 2) * 4 // 16
+    assert (counted["held_slots"] > calls * usual) == bool(blocked)
 
 
 def test_sparse_block_refuses_a_range_outside_the_experts():

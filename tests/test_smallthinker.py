@@ -281,7 +281,7 @@ def test_reglu_experts_against_relu_written_out(ref):
         precision=ref.HI)
     _close(got, want)
     _close(got, ref._reglu(x, gate_up[0], down[0], _ident, _mm(ref)))
-    assert counts.tolist() == [40, 40, 40]
+    assert counts.tolist() == [40, 40, 40, 1, 0]
     silu, _ = held_experts_ffn(x, router, gate_up, down, (0, 2), 1)
     assert np.abs(np.asarray(silu) - np.asarray(got)).max() > 1e-2
     with pytest.raises(KeyError):
@@ -336,7 +336,7 @@ def test_no_token_dropped_when_all_choose_one_held_expert(ref):
     got, ntv = _stateless(layer, params, x, route_from)
     _close(got, want)
     counts = [v for v in ntv if v.dtype == jnp.int32][0]
-    held_slots, slots, fullest = (int(v) for v in counts)
+    held_slots, slots, fullest = (int(v) for v in counts[:3])
     assert slots == 2 * SEQ * 6 and fullest == 2 * SEQ
     assert held_slots >= 2 * SEQ
 

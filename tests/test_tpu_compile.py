@@ -396,6 +396,31 @@ def test_grouped_matmul_at_widths_that_are_no_power_of_two(k, n):
     assert kernels_in(jax.grad(loss, (0, 1)), rows, experts, sizes) >= 3
 
 
+@pytest.mark.parametrize("rows,width,weighted", [
+    (20480, 2048, True), (24576, 2560, False), (12288, 2688, True),
+    (12288, 6, True)])
+def test_rows_summed_by_token_on_the_mxu(rows, width, weighted):
+    """The sparse block's row-to-token sum at the cells' buffers and
+    widths (2688 is 21 x 128; 6 is a weight gradient's ``k`` columns,
+    padded to a lane tile): one Mosaic kernel, with the weights as three
+    bfloat16 terms or without."""
+    from elephas_tpu.ops.moe import RoutePlan, _sum_rows_by_token
+
+    tokens = 16384
+    ints = lambda *shape: on_chip(shape, jnp.int32)  # noqa: E731
+    plan = RoutePlan(
+        ints(rows), on_chip((rows,), jnp.float32), ints(8), ints(rows),
+        ints(rows), ints(rows), on_chip((rows,), jnp.float32),
+        ints(tokens // 128))
+
+    def summed(values, plan):
+        return _sum_rows_by_token(
+            values, plan, tokens, dtype=jnp.bfloat16, kernel=True,
+            weights=plan.weight_of_rank if weighted else None)
+
+    assert kernels_in(summed, on_chip((rows, width), jnp.bfloat16), plan) == 1
+
+
 def test_chunked_ssd_scan_fits_at_published_widths():
     """64 heads of 64 over a 128-wide state, B and C in 8 groups, 2 x
     8192 tokens in chunks of 128, forward and backward under a
