@@ -8,14 +8,14 @@ builders so the benchmark suite (the five configurations listed in
 BASELINE.json) and the examples share one definition. All builders return *compiled* Keras-3 (jax backend)
 models ready to wrap in ``SparkModel``.
 
-The four sparse LMs (``qwen3_next_lm``, ``deepseek_v3_lm``,
-``smallthinker_lm``, ``nemotron_h_lm``) are built from blocks that this
-package also exports: the norms ``ZeroCentredRMSNorm`` and ``RMSNorm``;
+The five sparse LMs (``qwen3_next_lm``, ``deepseek_v3_lm``,
+``smallthinker_lm``, ``nemotron_h_lm``, ``laguna_lm``) are built from
+blocks that this package also exports: the norms ``ZeroCentredRMSNorm`` and ``RMSNorm``;
 the feed-forwards ``SwiGLU``, ``DenseMLP`` and ``UngatedMLP``; the
 mixers ``GatedAttention``, ``LatentAttention``, ``BandedAttention``,
 ``GatedDeltaNet`` (the gated delta rule) and ``Mamba2Mixer`` (the
 state-space scan); and ``SparseMoeBlock``, the one sparse block of all
-four (gated or ungated experts, with or without a shared expert).
+five (gated or ungated experts, with or without a shared expert).
 """
 
 from elephas_tpu.models.mlp import mnist_mlp
@@ -35,6 +35,7 @@ from elephas_tpu.models.qwen3_next import qwen3_next_lm
 from elephas_tpu.models.deepseek_v3 import deepseek_v3_lm
 from elephas_tpu.models.smallthinker import smallthinker_lm
 from elephas_tpu.models.nemotron_h import nemotron_h_lm
+from elephas_tpu.models.laguna import laguna_lm
 
 __all__ = [
     "mnist_mlp",
@@ -51,6 +52,7 @@ __all__ = [
     "deepseek_v3_lm",
     "smallthinker_lm",
     "nemotron_h_lm",
+    "laguna_lm",
     "MoeFFN",
     "FlashMHA",
     "FusedLayerNorm",

@@ -43,6 +43,10 @@ from elephas_tpu.models.transformer import (
 
 _LAYERS = None
 LAYER_NAMES = ("BandedAttention",)
+# the keys of a published ``rope_parameters`` group that YaRN reads, in
+# the order ``transformer._rope_tables`` takes them
+YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast",
+             "beta_slow", "attention_factor")
 
 
 def _layers():
@@ -75,19 +79,31 @@ def _layers():
         def __init__(self, num_heads: int, num_kv_heads: int, head_dim: int,
                      window: int | None = None, rotary: bool = True,
                      rope_theta: float = 10000.0, init_std: float = 0.02,
-                     **kwargs):
+                     gating: str | None = None,
+                     rotary_dim: int | None = None,
+                     yarn: dict | None = None, **kwargs):
             super().__init__(**kwargs)
-            if num_heads % num_kv_heads or head_dim % 2:
+            rotary_dim = head_dim if rotary_dim is None else rotary_dim
+            if num_heads % num_kv_heads or rotary_dim % 2 or not (
+                    0 < rotary_dim <= head_dim):
                 raise ValueError(
                     f"{num_heads} query heads over {num_kv_heads} key/value "
-                    f"heads of width {head_dim}"
+                    f"heads of width {head_dim}, {rotary_dim} of it rotated"
                 )
             if window is not None and window < 1:
                 raise ValueError(f"window {window!r} holds no key")
+            if gating not in (None, "per-head"):
+                raise ValueError(
+                    f"gating {gating!r} is neither None nor 'per-head'")
+            if yarn is not None and set(yarn) != set(YARN_KEYS):
+                raise ValueError(
+                    f"yarn names {sorted(yarn)}, not {sorted(YARN_KEYS)}")
             self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
             self.head_dim, self.window = head_dim, window
             self.rotary, self.rope_theta = bool(rotary), rope_theta
-            self.init_std = init_std
+            self.init_std, self.gating = init_std, gating
+            self.rotary_dim = rotary_dim
+            self.yarn = None if yarn is None else dict(yarn)
 
         def build(self, input_shape):
             d, hd = int(input_shape[-1]), self.head_dim
@@ -98,6 +114,18 @@ def _layers():
             self.v_proj = self._weight(
                 "v_proj", (d, self.num_kv_heads * hd), init)
             self.o_proj = self._weight("o_proj", (self.num_heads * hd, d), init)
+            if self.gating:
+                self.g_proj = self._weight("g_proj", (d, self.num_heads), init)
+
+        def _rotate(self, t, cos, sin, dtype):
+            """The first ``rotary_dim`` of each head of ``t [B, S, heads,
+            D]`` turned in float32, the rest passed on as it is."""
+            rot = self.rotary_dim
+            if rot == self.head_dim:
+                return _apply_rope(t.astype(f32), cos, sin).astype(dtype)
+            turned = _apply_rope(t[..., :rot].astype(f32), cos, sin)
+            return jnp.concatenate(
+                [turned, t[..., rot:].astype(f32)], axis=-1).astype(dtype)
 
         def _forward(self, x):
             from elephas_tpu.ops.flash_attention import flash_attention
@@ -108,11 +136,14 @@ def _layers():
                 q = jnp.matmul(x, self.q_proj.value).reshape(b, s, h, hd)
                 k = jnp.matmul(x, self.k_proj.value).reshape(b, s, hk, hd)
                 v = jnp.matmul(x, self.v_proj.value).reshape(b, s, hk, hd)
+                if self.gating:  # one logit a head and token
+                    gate = jnp.matmul(x, self.g_proj.value)
                 if self.rotary:
-                    cos, sin = _rope_tables(s, hd, float(self.rope_theta))
+                    cos, sin = _rope_tables(
+                        s, self.rotary_dim, float(self.rope_theta),
+                        self.yarn and tuple(self.yarn[k] for k in YARN_KEYS))
                     cos, sin = cos[None, :, None], sin[None, :, None]
-                    q, k = (_apply_rope(t.astype(f32), cos, sin).astype(
-                        x.dtype) for t in (q, k))
+                    q, k = (self._rotate(t, cos, sin, x.dtype) for t in (q, k))
             with jax.named_scope(
                     "attn.full" if self.window is None else "attn.window"):
                 heads_first = lambda t: jnp.transpose(t, (0, 2, 1, 3))  # noqa: E731
@@ -120,7 +151,14 @@ def _layers():
                     heads_first(q), heads_first(k), heads_first(v),
                     causal=True, scale=hd ** -0.5, window=self.window,
                 )
-                out = heads_first(out).reshape(b, s, h * hd)
+                out = heads_first(out)
+                if not self.gating:
+                    out = out.reshape(b, s, h * hd)
+            if self.gating:
+                with jax.named_scope("attn.gate"):
+                    out = out.astype(f32) * jax.nn.sigmoid(
+                        gate.astype(f32))[..., None]
+                    out = out.astype(x.dtype).reshape(b, s, h * hd)
             with jax.named_scope("attn.proj"):
                 return jnp.matmul(out, self.o_proj.value)
 
@@ -129,7 +167,9 @@ def _layers():
                     "num_kv_heads": self.num_kv_heads,
                     "head_dim": self.head_dim, "window": self.window,
                     "rotary": self.rotary, "rope_theta": self.rope_theta,
-                    "init_std": self.init_std, "remat": self.remat}
+                    "init_std": self.init_std, "gating": self.gating,
+                    "rotary_dim": self.rotary_dim, "yarn": self.yarn,
+                    "remat": self.remat}
 
     _LAYERS = {"BandedAttention": BandedAttention}
     return _LAYERS

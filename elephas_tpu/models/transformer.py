@@ -275,17 +275,42 @@ def _positions(maxlen: int, d_model: int) -> np.ndarray:
 
 
 @functools.lru_cache(maxsize=8)
-def _rope_tables(maxlen: int, head_dim: int, theta: float = 10000.0):
+def _rope_tables(maxlen: int, head_dim: int, theta: float = 10000.0,
+                 yarn: tuple | None = None):
     """cos/sin tables ``[S, D]`` for rotary position embeddings
-    (half-split / GPT-NeoX convention; ``head_dim`` must be even;
-    ``theta`` is the base of the frequencies).
+    (half-split / GPT-NeoX convention; ``head_dim`` is the rotated
+    width, the head's own or the part of it a caller rotates, and must
+    be even; ``theta`` is the base of the frequencies).
     Cached so every attention layer shares ONE host table (and jax sees
     one constant object) instead of L identical copies — at long-context
-    sequence lengths the table is large (code-review r4)."""
+    sequence lengths the table is large (code-review r4).
+
+    ``yarn = (factor, original_max_position_embeddings, beta_fast,
+    beta_slow, attention_factor)`` scales the frequencies as YaRN does
+    (arXiv:2309.00071, "NTK-by-parts"): a pair that turns more than
+    ``beta_fast`` times over the original context keeps its frequency,
+    one that turns less than ``beta_slow`` times is slowed ``factor``
+    times, a linear ramp between them; cos and sin are multiplied by
+    ``attention_factor`` (None: ``0.1 ln(factor) + 1``). None is the
+    plain table."""
     inv = 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
+    scale = 1.0
+    if yarn is not None:
+        factor, original, beta_fast, beta_slow, scale = yarn
+
+        def pair_turning(n):  # the pair that turns n times in ``original``
+            return (head_dim * np.log(original / (2 * np.pi * n))
+                    / (2 * np.log(theta)))
+
+        low = max(np.floor(pair_turning(beta_fast)), 0)
+        high = min(np.ceil(pair_turning(beta_slow)), head_dim - 1)
+        ramp = np.clip(
+            (np.arange(head_dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+        inv = ramp * inv / factor + (1 - ramp) * inv
+        scale = 0.1 * np.log(factor) + 1.0 if scale is None else scale
     ang = np.arange(maxlen)[:, None] * inv[None, :]  # [S, D/2]
-    cos = np.concatenate([np.cos(ang), np.cos(ang)], axis=-1)
-    sin = np.concatenate([np.sin(ang), np.sin(ang)], axis=-1)
+    cos = scale * np.concatenate([np.cos(ang), np.cos(ang)], axis=-1)
+    sin = scale * np.concatenate([np.sin(ang), np.sin(ang)], axis=-1)
     return cos.astype(np.float32), sin.astype(np.float32)
 
 

@@ -24,7 +24,8 @@ key/value head that several query heads share is read in place. A
 the ``window - 1`` keys before it): the block pairs below the band are
 skipped as those above the diagonal are, products and copies both. Where a
 caller names no block each kernel takes the largest measured blocks that
-divide the sequences and fit VMEM (``_BLOCK_TABLE``). The packed qkv
+divide the sequences and fit VMEM, a query block no longer than the band
+first (``_BLOCK_TABLE``, ``_resolve_blocks``). The packed qkv
 layout still evaluates them blockwise under ``lax.scan``, XLA-fused
 (:func:`_flash_backward`, also the tests' oracle for the kernels). The
 whole op carries a ``jax.custom_vjp`` so it drops into any ``jax.grad``
@@ -160,6 +161,9 @@ _BLOCK_TABLE = {
     "fwd_grouped": ((128, 128),),
     "blockwise": ((128, 128),),
 }
+# under a band the rule prefers no query block shorter than this: the
+# shortest the chip measured ahead of the row's order
+_SHORTEST_BAND_BLOCK = 512
 # the scoped VMEM each call asks for; the rule fills half of it and
 # leaves the rest to what it does not count (masks, the exponent's
 # temporaries, Mosaic's own scratch)
@@ -179,13 +183,31 @@ def _vmem_bytes(block_q, block_k, d, dv, itemsize):
             + 2 * 4 * block_q * block_k)
 
 
-def _resolve_blocks(block_q, block_k, s_q, s_k, d, dv, itemsize, kernel):
+def _resolve_blocks(block_q, block_k, s_q, s_k, d, dv, itemsize, kernel,
+                    window=None):
     """The kernel's blocks. A block the caller names rules; one it does
     not comes from the first pair of the kernel's row that divides the
     sequences and fits VMEM, and a sequence that no such block divides
-    is one block if that fits."""
+    is one block if that fits. Under a ``window`` the row's pairs whose
+    query block reaches past the band come after those whose does not,
+    the shortest overreach first, and a band shorter than 512 keys
+    counts as 512: no band puts a query block under 512 ahead of the
+    row's order. Measured on the chip (72 heads over 8 at 8192
+    positions under a 512-key band, ``benchmarks/results/
+    flash-band-micro-PR44.json``): the grid steps over every pair of
+    blocks and a skipped step costs a fifth of a microsecond, so small
+    blocks lose what they save (256-blocks 98 ms for the three kernels
+    and 128-blocks 296 against 45 at 1024), while a query block of the
+    band's 512 wins in each (forward ``(512, 1024)`` 13.1 ms against
+    13.8 at 1024-blocks, dK/dV and dQ at 512-blocks 15.0 and 13.3
+    against 16.1 and 15.0). A band of 1024 keys or more leaves the row
+    in its order; no band under 512 was measured."""
     named = bool(block_q and block_k)
-    for bq, bk in _BLOCK_TABLE[kernel] + ((s_q, s_k),):
+    row = _BLOCK_TABLE[kernel]
+    if window is not None:
+        band = max(window, _SHORTEST_BAND_BLOCK)
+        row = tuple(sorted(row, key=lambda pair: max(pair[0] - band, 0)))
+    for bq, bk in row + ((s_q, s_k),):
         bq, bk = min(block_q or bq, s_q), min(block_k or bk, s_k)
         if s_q % bq == 0 and s_k % bk == 0 and (
                 named
@@ -270,7 +292,7 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret,
     # one key/value head, through the index map (no repeated copy)
     group = bh // k.shape[0]
     block_q, block_k = _resolve_blocks(
-        block_q, block_k, s_q, s_k, d, dv, q.dtype.itemsize, "fwd")
+        block_q, block_k, s_q, s_k, d, dv, q.dtype.itemsize, "fwd", window)
     grid = (bh, s_q // block_q, s_k // block_k)
     kernel = functools.partial(
         _fwd_kernel,
@@ -566,7 +588,7 @@ def _flash_backward(scale, causal, block_q, block_k, residuals, g,
     bh, s_q, d = q.shape
     s_k, dv = k.shape[1], v.shape[-1]  # v, out and g are ``dv`` wide
     block_q, block_k = _resolve_blocks(
-        block_q, block_k, s_q, s_k, d, dv, 4, "blockwise")
+        block_q, block_k, s_q, s_k, d, dv, 4, "blockwise", window)
     nq, nk = s_q // block_q, s_k // block_k
     f32 = jnp.float32
 
@@ -773,7 +795,7 @@ def _flash_backward_kernels(scale, causal, block_q, block_k, interpret,
     group = bh // bkv
     # the backward kernels' own blocks: the residuals depend on none
     block_q, block_k = _resolve_blocks(
-        block_q, block_k, s_q, s_k, d, dv, q.dtype.itemsize, "bwd")
+        block_q, block_k, s_q, s_k, d, dv, q.dtype.itemsize, "bwd", window)
     nq, nk = s_q // block_q, s_k // block_k
     f32 = jnp.float32
     # Δ_i = rowsum(dO ∘ O)
@@ -993,8 +1015,10 @@ def flash_attention(
     A ``block_q``/``block_k`` the caller names rules the forward
     kernel and both backward kernels. Where none is named each kernel
     takes its own from the shapes: the largest measured blocks that
-    divide the sequences and fit VMEM (``_BLOCK_TABLE``); the result
-    does not depend on them beyond the order of float32 sums."""
+    divide the sequences and fit VMEM, under a ``window`` those whose
+    query block is no longer than the band first (``_BLOCK_TABLE``,
+    ``_resolve_blocks``); the result does not depend on them beyond
+    the order of float32 sums."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if interpret is None:

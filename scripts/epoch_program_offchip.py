@@ -1,0 +1,106 @@
+#!/usr/bin/env python3
+"""A cell's whole epoch program compiled for a described v5e chip on a
+machine that has none: what the TPU compiler refuses and what the
+program's temporaries take, before any chip time is spent.
+
+    JAX_PLATFORMS=cpu python3 scripts/epoch_program_offchip.py \
+        --workload laguna-fit-seq8k [--out <file.json>]
+
+Builds the cell's model as its builder does (weights are the Keras
+initialiser's: nothing runs), hands ``MeshRunner`` a mesh of one
+described device, makes the flash and grouped kernels compile instead
+of interpreting (``backend_guard.pallas_interpret``: a process on the
+CPU would interpret them), and lowers ``MeshRunner._build_epoch_fn`` on
+``ShapeDtypeStruct``s sharded as ``fit`` shards them. Prints XLA's
+``memory_analysis()`` of the compiled program and how many of each
+Pallas kernel it holds. A compile that passes is not a chip run: the
+chip's ``hbm_peak_gb.fit`` is the allocator's peak plus these
+temporaries. The model's real weights live on the host while this runs
+(a few GB for an LM cell).
+"""
+
+import argparse
+import collections
+import json
+import os
+import re
+import sys
+import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+os.environ.setdefault("KERAS_BACKEND", "jax")
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+
+def main() -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--workload", required=True)
+    parser.add_argument("--out")
+    args = parser.parse_args()
+
+    import jax
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from benchmarks.harness import manifest as mf
+    from elephas_tpu.utils import backend_guard
+    from elephas_tpu.worker import MeshRunner
+
+    backend_guard.pallas_interpret = lambda: False
+    manifest = mf.load_manifest()
+    cell = mf.find_cell(manifest, args.workload)
+    cfg = mf.config_of(manifest, cell)
+    traffic = mf.load_json("traffic", cell["traffic"])
+    builder = mf.load_module("builders", cfg["builder"])
+    t0 = time.monotonic()
+    model = builder._build(cfg, cfg["optimizer"])
+    built = time.monotonic()
+
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    mesh = Mesh(np.array(topo.devices[:1]), ("workers",))
+    runner = MeshRunner(model, cfg["spark_model"]["mode"], "epoch", mesh)
+    sharded = NamedSharding(mesh, P("workers"))
+
+    def like(variables):
+        return [jax.ShapeDtypeStruct((1,) + tuple(v.shape), v.dtype,
+                                     sharding=sharded) for v in variables]
+
+    steps, batch = int(traffic["steps_per_epoch"]), int(traffic["batch_size"])
+    rows = jax.ShapeDtypeStruct(
+        (1, steps, batch, int(traffic["sequence_length"])), np.int32,
+        sharding=sharded)
+    state = (like(model.trainable_variables),
+             like(model.non_trainable_variables),
+             like(model.optimizer.variables))
+    lowered = runner._build_epoch_fn([]).lower(*state, [], rows, rows)
+    traced = time.monotonic()
+    compiled = lowered.compile()
+    done = time.monotonic()
+    memory = compiled.memory_analysis()
+    kernels = collections.Counter(
+        re.findall(r'kernel_name = "(\w+)"', lowered.as_text()))
+    result = {
+        "cell": args.workload,
+        "parameters": int(sum(np.prod(v.shape) for v in model.variables)),
+        "temp_size_in_bytes": int(memory.temp_size_in_bytes),
+        "argument_size_in_bytes": int(memory.argument_size_in_bytes),
+        "output_size_in_bytes": int(memory.output_size_in_bytes),
+        "alias_size_in_bytes": int(memory.alias_size_in_bytes),
+        "generated_code_size_in_bytes": int(
+            memory.generated_code_size_in_bytes),
+        "kernels_in_the_lowered_program": dict(kernels),
+        "build_s": round(built - t0, 1),
+        "trace_and_lower_s": round(traced - built, 1),
+        "compile_s": round(done - traced, 1),
+    }
+    print(json.dumps(result, indent=1))
+    if args.out:
+        with open(args.out, "w") as f:
+            json.dump(result, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

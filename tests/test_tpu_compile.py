@@ -339,6 +339,27 @@ def test_flash_kernels_under_a_window_at_16384_positions(window):
     assert compiled.memory_analysis().temp_size_in_bytes < 28 * 16384 * 128 * 4
 
 
+@pytest.mark.parametrize("heads,window", [(72, 512), (48, None)])
+def test_flash_kernels_at_head_counts_that_differ_by_layer(heads, window):
+    """The Laguna cell's attention of one sequence (ISSUE 44): 72 query
+    heads behind a 512-key band and 48 behind full attention, over 8
+    key/value heads of 128 at 8192 positions (9 and 6 query heads a
+    key/value head). The band takes the rule's shorter blocks, and all
+    three kernels compile with them; the temporaries stay under one
+    float32 copy of the queries."""
+    q = on_chip((heads, 8192, 128), jnp.bfloat16)
+    k = on_chip((8, 8192, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = flash_attention(
+            q, k, v, causal=True, window=window, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, k).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert compiled.memory_analysis().temp_size_in_bytes < heads * 8192 * 128 * 4
+
+
 def test_grouped_matmul_over_held_experts_forward_and_backward():
     """20480 slot rows over 32 held experts at hidden 2048 and twice
     the expert width: the grouped product and both of its gradients
