@@ -12,7 +12,7 @@ What the block adds to the zoo, beside the layers it shares with
   keys before it; None is full causal attention) and ``rotary`` (the
   rotary embedding over the whole head, pairs ``(i, i + head_dim /
   2)``; without it the layer has no position term at all). The flash
-  kernels skip the block pairs below the band
+  kernels' grids hold the band's block pairs alone
   (:func:`elephas_tpu.ops.flash_attention.flash_attention`,
   ``window``), under the scope ``attn.window``; a full layer runs under
   ``attn.full``.

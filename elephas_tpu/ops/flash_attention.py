@@ -21,8 +21,10 @@ dtype, causally empty block pairs are skipped (their copies too, in
 all three kernels: the index maps stay on the last visible block), and a
 key/value head that several query heads share is read in place. A
 ``window`` narrows the causal mask to a band (a query sees itself and
-the ``window - 1`` keys before it): the block pairs below the band are
-skipped as those above the diagonal are, products and copies both. Where a
+the ``window - 1`` keys before it), and the grid to the band: the inner
+axis of each kernel's grid is as long as the most blocks a band holds
+and counts from the first of them (``band_grid``), so the pairs below
+the band are not stepped over at all. Where a
 caller names no block each kernel takes the largest measured blocks that
 divide the sequences and fit VMEM, a query block no longer than the band
 first (``_BLOCK_TABLE``, ``_resolve_blocks``). The packed qkv
@@ -52,6 +54,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from elephas_tpu import telemetry
 from elephas_tpu.utils import backend_guard
 
 NEG_INF = -1e30
@@ -68,14 +71,14 @@ LSE_NAME = "flash_lse"
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                 *, scale: float, causal: bool, block_q: int, block_k: int,
-                window: int | None = None):
+                window: int | None = None, nk: int | None = None):
     # refs arrive squeezed to [BQ, D] / [BK, D] / [BQ, D] / [1, BQ]
     # (BlockSpec ``None`` dims), so one kernel serves both the separate
     # [BH, S, D] layout and the packed [B, S, 3, H, D] qkv layout
-    j = pl.program_id(2)
-    last_j = pl.num_programs(2) - 1
+    step = pl.program_id(2)
+    last_step = pl.num_programs(2) - 1
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
@@ -84,6 +87,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     # read outside the conditional body below: the interpreter has no
     # program_id inside one
     i = pl.program_id(1)
+    j = step
+    if window is not None:
+        # the grid holds a band's key blocks alone (of ``nk``): a query
+        # block counts them from the first it sees
+        j += _band_ends("fwd", i, block_q, block_k, window, nk)[0]
 
     def _accumulate():
         q = q_ref[:]  # [BQ, D]
@@ -128,11 +136,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         # its window, adds nothing (its scores are all masked, so the
         # branch above leaves the accumulators as they are): skip its
         # two products
-        pl.when(_pair_seen(i, j, block_q, block_k, window))(_accumulate)
+        seen = _pair_seen(i, j, block_q, block_k, window)
+        if window is not None:
+            # past the last block there is the index map stays on it
+            seen &= j < nk
+        pl.when(seen)(_accumulate)
     else:
         _accumulate()
 
-    @pl.when(j == last_j)
+    @pl.when(step == last_step)
     def _finalize():
         l = l_ref[:]
         # fully-masked rows kept l == 0 via the p guard above; they output
@@ -151,7 +163,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 # .json): a grid step costs about a microsecond whatever it multiplies,
 # so the largest blocks won in all three kernels (forward 54 ms at
 # blocks of 256, 26 at 512, 16 at 1024), and a long key block more than
-# a long query block in the forward kernel.
+# a long query block in the forward kernel. Under a window the grid
+# holds the band's pairs alone and the row's order is read again from
+# the band (_resolve_blocks; benchmarks/results/flash-band-micro-PR45
+# .json): a step that computes costs 0.5 (128-blocks) to 6 (1024-blocks)
+# microseconds there, and few are empty.
 _BLOCK_TABLE = {
     "fwd": ((1024, 1024), (512, 1024), (512, 512), (256, 256), (128, 128)),
     "bwd": ((1024, 1024), (512, 512), (256, 256), (128, 128)),
@@ -162,7 +178,8 @@ _BLOCK_TABLE = {
     "blockwise": ((128, 128),),
 }
 # under a band the rule prefers no query block shorter than this: the
-# shortest the chip measured ahead of the row's order
+# shortest the chip measured ahead of the row's order, on the grid over
+# every pair (PR 44) and on the grid of the band alone (PR 45)
 _SHORTEST_BAND_BLOCK = 512
 # the scoped VMEM each call asks for; the rule fills half of it and
 # leaves the rest to what it does not count (masks, the exponent's
@@ -192,16 +209,21 @@ def _resolve_blocks(block_q, block_k, s_q, s_k, d, dv, itemsize, kernel,
     query block reaches past the band come after those whose does not,
     the shortest overreach first, and a band shorter than 512 keys
     counts as 512: no band puts a query block under 512 ahead of the
-    row's order. Measured on the chip (72 heads over 8 at 8192
-    positions under a 512-key band, ``benchmarks/results/
-    flash-band-micro-PR44.json``): the grid steps over every pair of
-    blocks and a skipped step costs a fifth of a microsecond, so small
-    blocks lose what they save (256-blocks 98 ms for the three kernels
-    and 128-blocks 296 against 45 at 1024), while a query block of the
-    band's 512 wins in each (forward ``(512, 1024)`` 13.1 ms against
-    13.8 at 1024-blocks, dK/dV and dQ at 512-blocks 15.0 and 13.3
-    against 16.1 and 15.0). A band of 1024 keys or more leaves the row
-    in its order; no band under 512 was measured."""
+    row's order. Measured on the chip on the grid of the band alone
+    (72 heads over 8 at 8192 positions under a 512-key band,
+    ``benchmarks/results/flash-band-micro-PR45.json``): a query block
+    of the band's 512 wins in each kernel (forward ``(512, 1024)``
+    11.3 ms against 12.8 at 1024-blocks and 13.1 at 512-blocks; dK/dV
+    and dQ at 512-blocks 9.6 and 8.4 against 14.7 and 13.1 at
+    1024-blocks), and small blocks still lose with the empty steps
+    gone, by their count of computing steps (256-blocks 18.1, 13.0 and
+    10.8 ms, 128-blocks 30.5, 23.6 and 22.5). A band of 1024 keys or
+    more leaves the row in its order (4096 keys at 16384 positions:
+    1024-blocks 34.7 ms for the three kernels, every pair with a 512 in
+    it 36.6 to 41.9); no band under 512 was measured. On the grid over
+    every pair of blocks, which a band had until PR 45, a skipped step
+    cost 0.10 to 0.17 microseconds (``flash-band-micro-PR44.json``:
+    256-blocks 98 ms for the three kernels, 128-blocks 296)."""
     named = bool(block_q and block_k)
     row = _BLOCK_TABLE[kernel]
     if window is not None:
@@ -238,32 +260,93 @@ def _pair_seen(i, j, block_q, block_k, window):
     return seen
 
 
-def _visible_maps(causal, block_q, block_k, nq, window=None, nk=None):
-    """``(first_i, last_j)``: for a grid step ``(i, j)`` the nearest
-    query block that sees key block ``j`` and the nearest key block that
-    query block ``i`` sees. An index map that goes through them stays
-    where it is over the steps the causal mask empties, and a block that
-    does not change is not fetched again: a skipped pair's copies are
-    skipped too. With a ``window`` the steps below the band are emptied
-    as well, and the maps stay inside it from both sides (``nk`` key
-    blocks in all)."""
+def _visible_maps(causal, block_q, block_k, nq):
+    """``(first_i, last_j)``: for a step ``(i, j)`` of the grid over
+    every pair of blocks (no ``window``) the nearest query block that
+    sees key block ``j`` and the nearest key block that query block
+    ``i`` sees. An index map that goes through them stays where it is
+    over the steps the causal mask empties, and a block that does not
+    change is not fetched again: a skipped pair's copies are skipped
+    too."""
     if not causal:
         return (lambda i, j: i), (lambda i, j: j)
 
     def first_i(i, j):
-        if window is not None:
-            # the last query that sees the block's last key
-            i = jnp.minimum(i, ((j + 1) * block_k + window - 2) // block_q)
         return jnp.minimum(jnp.maximum(i, j * block_k // block_q), nq - 1)
 
     def last_j(i, j):
-        if window is not None:
-            # the first key that the block's first query sees, if any
-            first = jnp.maximum(i * block_q - window + 1, 0) // block_k
-            j = jnp.minimum(jnp.maximum(j, first), nk - 1)
         return jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
 
     return first_i, last_j
+
+
+def _band_ends(kernel, outer, block_q, block_k, window, n_inner):
+    """``(first, last)``: the inner blocks of a kernel's grid that the
+    band of ``window`` keys leaves something of under its ``outer``
+    block, every block between them too: the key blocks a query block
+    sees (``"fwd"``, ``"dq"``), the query blocks that see a key block
+    (``"dkv"``), of ``n_inner``; ``last < first`` where there is none.
+    Python integers give integers (the grid's extent), traced ones the
+    index maps' and the kernels' arithmetic."""
+    least, most = ((max, min) if isinstance(outer, int)
+                   else (jnp.maximum, jnp.minimum))
+    if kernel == "dkv":
+        first = outer * block_k // block_q
+        # the last query that sees the block's last key
+        last = ((outer + 1) * block_k + window - 2) // block_q
+    else:
+        # the first key that the block's first query sees, if any
+        first = least(outer * block_q - window + 1, 0) // block_k
+        last = ((outer + 1) * block_q - 1) // block_k
+    return first, most(last, n_inner - 1)
+
+
+def _grid_blocks(kernel, s_q, s_k, block_q, block_k):
+    """``(n_outer, n_inner)``: the blocks a kernel's grid walks, the
+    key blocks outermost in dK/dV and the query blocks in the others."""
+    nq, nk = s_q // block_q, s_k // block_k
+    return (nk, nq) if kernel == "dkv" else (nq, nk)
+
+
+def band_grid(kernel, s_q, s_k, block_q, block_k, window):
+    """``(steps, computing)`` of one head and sequence under the causal
+    mask: the steps of ``kernel``'s grid (``"fwd"``, ``"dkv"``,
+    ``"dq"``) and those among them whose block pair holds something.
+    With no ``window`` the grid steps over every pair of blocks. With
+    one its inner axis is as long as the most blocks the band holds
+    under any outer block, and a step counts from the first of them
+    (``_band_ends``): the three ``pallas_call`` s take their inner
+    extent from here, ``steps`` over the outer blocks."""
+    n_outer, n_inner = _grid_blocks(kernel, s_q, s_k, block_q, block_k)
+    # no window counts as one that reaches every key
+    reach = s_q + s_k if window is None else window
+    held = [
+        max(last - first + 1, 0) for first, last in (
+            _band_ends(kernel, outer, block_q, block_k, reach, n_inner)
+            for outer in range(n_outer))]
+    span = n_inner if window is None else max(held)
+    return n_outer * max(span, 1), sum(held)
+
+
+def _band_maps(kernel, s_q, s_k, block_q, block_k, window):
+    """``(span, inner)`` of a windowed kernel's grid: its inner extent
+    and the index map ``inner(outer, step)`` onto the blocks the band
+    holds, which stays on the last of them over the steps a shorter
+    row leaves (nothing is fetched for a step that computes nothing).
+    Emits the ``flash.grid`` event, once a ``pallas_call`` that is
+    traced."""
+    n_outer, n_inner = _grid_blocks(kernel, s_q, s_k, block_q, block_k)
+    steps, computing = band_grid(kernel, s_q, s_k, block_q, block_k, window)
+    telemetry.emit(
+        "flash.grid", kernel=kernel, window=window, block_q=block_q,
+        block_k=block_k, steps=steps, computing=computing)
+
+    def inner(outer, step):
+        first, last = _band_ends(
+            kernel, outer, block_q, block_k, window, n_inner)
+        return jnp.minimum(first + step, last)
+
+    return steps // n_outer, inner
 
 
 def _cost(bh, s_q, s_k, d, itemsize, dv=None):
@@ -293,7 +376,13 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret,
     group = bh // k.shape[0]
     block_q, block_k = _resolve_blocks(
         block_q, block_k, s_q, s_k, d, dv, q.dtype.itemsize, "fwd", window)
-    grid = (bh, s_q // block_q, s_k // block_k)
+    nq, nk = s_q // block_q, s_k // block_k
+    if window is None:
+        span = nk
+        _, last_j = _visible_maps(causal, block_q, block_k, nq)
+    else:
+        span, last_j = _band_maps("fwd", s_q, s_k, block_q, block_k, window)
+    grid = (bh, nq, span)
     kernel = functools.partial(
         _fwd_kernel,
         scale=scale,
@@ -301,9 +390,8 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret,
         block_q=block_q,
         block_k=block_k,
         window=window,
+        nk=nk,
     )
-    _, last_j = _visible_maps(
-        causal, block_q, block_k, grid[1], window, grid[2])
     kv_side = lambda b, i, j: (b // group, last_j(i, j), 0)  # noqa: E731
     out, lse = pl.pallas_call(
         kernel,
@@ -684,20 +772,25 @@ def _flash_backward_packed(scale, causal, block_q, block_k, residuals, g):
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
                     causal: bool, block_q: int, block_k: int,
-                    window: int | None = None):
+                    window: int | None = None, nq: int | None = None):
     """dK and dV of one key block of one key/value head, summed over the
     query heads that share it (grid axis 2) and the query blocks (axis
-    3). Scores are held transposed, ``[BK, BQ]``: ``lse`` and ``delta``
-    then broadcast from the ``[1, BQ]`` rows they arrive as, and both
-    accumulating products contract the leading query axis of ``do`` and
-    ``q`` as they lie."""
-    j, g, i = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-    last_g, last_i = pl.num_programs(2) - 1, pl.num_programs(3) - 1
+    3; under a ``window`` those of ``nq`` that the band holds, counted
+    from the first that sees the key block). Scores are held
+    transposed, ``[BK, BQ]``: ``lse`` and ``delta`` then broadcast from
+    the ``[1, BQ]`` rows they arrive as, and both accumulating products
+    contract the leading query axis of ``do`` and ``q`` as they lie."""
+    j, g, step = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    last_g, last_step = pl.num_programs(2) - 1, pl.num_programs(3) - 1
 
-    @pl.when((g == 0) & (i == 0))
+    @pl.when((g == 0) & (step == 0))
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    i = step
+    if window is not None:
+        i += _band_ends("dkv", j, block_q, block_k, window, nq)[0]
 
     def _accumulate():
         q, do = q_ref[:], do_ref[:]  # [BQ, D]
@@ -726,11 +819,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     if causal:
         # a query block wholly behind the key block, or wholly past its
         # window, sees none of it
-        pl.when(_pair_seen(i, j, block_q, block_k, window))(_accumulate)
+        seen = _pair_seen(i, j, block_q, block_k, window)
+        if window is not None:
+            seen &= i < nq
+        pl.when(seen)(_accumulate)
     else:
         _accumulate()
 
-    @pl.when((g == last_g) & (i == last_i))
+    @pl.when((g == last_g) & (step == last_step))
     def _finalize():
         dk_ref[:] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
@@ -739,18 +835,23 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_acc, lse_col, delta_col, *, scale: float,
                    causal: bool, block_q: int, block_k: int,
-                   window: int | None = None):
-    """dQ of one query block, summed over the key blocks (grid axis 2).
-    ``lse`` and ``delta`` arrive as ``[1, BQ]`` rows and are turned to
-    ``[BQ, 1]`` columns once a query block."""
-    i, j = pl.program_id(1), pl.program_id(2)
-    last_j = pl.num_programs(2) - 1
+                   window: int | None = None, nk: int | None = None):
+    """dQ of one query block, summed over the key blocks (grid axis 2;
+    under a ``window`` those of ``nk`` that the band holds, as in the
+    forward kernel). ``lse`` and ``delta`` arrive as ``[1, BQ]`` rows
+    and are turned to ``[BQ, 1]`` columns once a query block."""
+    i, step = pl.program_id(1), pl.program_id(2)
+    last_step = pl.num_programs(2) - 1
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
         lse_col[:] = jnp.transpose(lse_ref[:])
         delta_col[:] = jnp.transpose(delta_ref[:])
+
+    j = step
+    if window is not None:
+        j += _band_ends("dq", i, block_q, block_k, window, nk)[0]
 
     def _accumulate():
         q, do = q_ref[:], do_ref[:]  # [BQ, D]
@@ -771,11 +872,15 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32)
 
     if causal:
-        pl.when(_pair_seen(i, j, block_q, block_k, window))(_accumulate)
+        seen = _pair_seen(i, j, block_q, block_k, window)
+        if window is not None:
+            # past the last block there is the index map stays on it
+            seen &= j < nk
+        pl.when(seen)(_accumulate)
     else:
         _accumulate()
 
-    @pl.when(j == last_j)
+    @pl.when(step == last_step)
     def _finalize():
         dq_ref[:] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
@@ -785,9 +890,9 @@ def _flash_backward_kernels(scale, causal, block_q, block_k, interpret,
     """The flash recurrences of :func:`_flash_backward` as two Pallas
     kernels. Operands reach the MXU in the dtype the inputs came in and
     every product accumulates in float32; a block pair the causal mask
-    (or the ``window``'s band) empties is skipped; a key/value head
-    shared by ``group`` query heads is read in place and its gradient
-    summed in the kernel."""
+    empties is skipped, and under a ``window`` the grids hold the band's
+    pairs alone; a key/value head shared by ``group`` query heads is
+    read in place and its gradient summed in the kernel."""
     q, k, v, out, lse = residuals
     bh, s_q, d = q.shape
     bkv, s_k, _ = k.shape
@@ -803,6 +908,14 @@ def _flash_backward_kernels(scale, causal, block_q, block_k, interpret,
     lse, delta = lse[:, None, :], delta[:, None, :]  # [BH, 1, S] rows
     params = dict(scale=scale, causal=causal, block_q=block_q,
                   block_k=block_k, window=window)
+    if window is None:
+        span_q, span_k = nq, nk
+        first_i, last_j = _visible_maps(causal, block_q, block_k, nq)
+    else:
+        band = (s_q, s_k, block_q, block_k, window)
+        span_q, inner_q = _band_maps("dkv", *band)
+        span_k, last_j = _band_maps("dq", *band)
+        first_i = lambda i, j: inner_q(j, i)  # noqa: E731
     concrete = all(type(t) is int for t in (bh, s_q, s_k, d, dv))
 
     def cost(score_wide, value_wide):
@@ -818,14 +931,12 @@ def _flash_backward_kernels(scale, causal, block_q, block_k, interpret,
             transcendentals=bh * s_q * s_k,
         )
 
-    first_i, last_j = _visible_maps(
-        causal, block_q, block_k, nq, window, nk)
     q_side = lambda b, j, g_, i: (b * group + g_, first_i(i, j), 0)  # noqa: E731
     kv_side = lambda b, j, g_, i: (b, j, 0)  # noqa: E731
     row = lambda b, j, g_, i: (b * group + g_, 0, first_i(i, j))  # noqa: E731
     d_k, d_v = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **params),
-        grid=(bkv, nk, group, nq),
+        functools.partial(_bwd_dkv_kernel, nq=nq, **params),
+        grid=(bkv, nk, group, span_q),
         in_specs=[
             pl.BlockSpec((None, block_q, d), q_side),
             pl.BlockSpec((None, block_k, d), kv_side),
@@ -855,8 +966,8 @@ def _flash_backward_kernels(scale, causal, block_q, block_k, interpret,
     kv_side = lambda b, i, j: (b // group, last_j(i, j), 0)  # noqa: E731
     row = lambda b, i, j: (b, 0, i)  # noqa: E731
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **params),
-        grid=(bh, nq, nk),
+        functools.partial(_bwd_dq_kernel, nk=nk, **params),
+        grid=(bh, nq, span_k),
         in_specs=[
             pl.BlockSpec((None, block_q, d), q_side),
             pl.BlockSpec((None, block_k, d), kv_side),
@@ -1008,9 +1119,10 @@ def flash_attention(
     (latent attention scores with wider heads than it sums): the
     result then has ``v``'s. ``window`` (causal attention only) is a
     sliding window: query ``i`` sees the keys ``j <= i`` with ``i - j <
-    window``, so ``window`` keys with its own; the block pairs that
-    the band leaves nothing of are skipped, forward and backward. None
-    is plain causal attention, on the grid it has always had.
+    window``, so ``window`` keys with its own; the kernels' grids hold
+    the block pairs of the band alone, forward and backward
+    (``band_grid``). None is plain causal attention, on the grid over
+    every pair of blocks that it has always had.
 
     A ``block_q``/``block_k`` the caller names rules the forward
     kernel and both backward kernels. Where none is named each kernel

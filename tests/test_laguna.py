@@ -286,21 +286,27 @@ def test_the_block_rule_reads_the_band():
     assert scores(512, 512) < 2 * 8192 * 512
 
 
-# recorded at this PR's parent (5e30c99) under jax 0.9.0: sha256 of the
+# recorded at PR 44's parent (5e30c99) under jax 0.9.0: sha256 of the
 # text of the jaxpr and of its gradient's (addresses blanked), of the
 # attention layers as the other two cells' models build them and of the
-# flash op under SmallThinker's band, at those cells' shapes
+# flash op under SmallThinker's band, at those cells' shapes. PR 45 put
+# the windowed kernels on a grid of the band alone, which had to move
+# the two digests with a window in them and no other: they were
+# recorded again at its tree ("smallthinker-window" 0a7a0c2ec877d225
+# before, FLASH_4096_AT_16384 d809d767066f0883); the two full-attention
+# digests are PR 44's parent's still, the proof that no window traces as
+# it did
 PARENT_JAXPRS = {
     "smallthinker-window": (
         (28, 4, 128, 4096, True, 1500000), (1, 16384, 2560),
-        "0a7a0c2ec877d225"),
+        "c57378832976a18c"),
     "smallthinker-full": (
         (28, 4, 128, None, False, 1500000), (1, 16384, 2560),
         "cdfae97de721dad7"),
     "nemotron-full": (
         (32, 2, 128, None, False), (2, 8192, 2688), "2125495016be3b34"),
 }
-FLASH_4096_AT_16384 = "d809d767066f0883"
+FLASH_4096_AT_16384 = "ed6ffb2287124f22"
 
 
 def _digest(fn, *args):
@@ -341,8 +347,9 @@ def test_the_other_cells_attention_layers_trace_as_before(model):
 
 def test_smallthinkers_band_keeps_its_flash_program():
     """``flash_attention(window=4096)`` at 16384 positions, 28 heads
-    over 4: the program the SmallThinker cell ran before the rule read
-    the band, and the one that names 1024-blocks."""
+    over 4: the program the SmallThinker cell runs (recorded at PR 45,
+    on the band's grid), the one that names 1024-blocks: the rule that
+    reads the band left its blocks alone."""
     from elephas_tpu.ops.flash_attention import flash_attention
 
     q = jnp.zeros((1, 28, 16384, 128), jnp.bfloat16)
@@ -352,6 +359,10 @@ def test_smallthinkers_band_keeps_its_flash_program():
     got = _digest(ruled, q, k, k)
     if jax.__version__ == "0.9.0":
         assert got == FLASH_4096_AT_16384
+    named = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, causal=True, window=4096, interpret=False, block_q=1024,
+        block_k=1024)
+    assert _digest(named, q, k, k) == got
     short = lambda q, k, v: flash_attention(  # noqa: E731
         q, k, v, causal=True, window=512, interpret=False)
     assert _digest(short, q, k, k) != got

@@ -433,41 +433,175 @@ def test_flash_window_forward_and_backward_match_reference(case):
                 err_msg="blockwise d" + leaf)
 
 
+def _band_walk(kernel, s_q, s_k, bq, bk, window):
+    """The grid of ``kernel`` under a window, walked in Python as the
+    ``pallas_call`` walks it: ``(outer, step, inner block the kernel
+    computes on or None, block the index map fetches)`` a step."""
+    from elephas_tpu.ops.flash_attention import (
+        _band_ends, _band_maps, _grid_blocks, _pair_seen)
+
+    n_outer, n_inner = _grid_blocks(kernel, s_q, s_k, bq, bk)
+    span, fetched = _band_maps(kernel, s_q, s_k, bq, bk, window)
+    for outer in range(n_outer):
+        first, _ = _band_ends(kernel, outer, bq, bk, window, n_inner)
+        for step in range(span):
+            # what the kernel bodies do with their block index
+            inner = first + step
+            i, j = (inner, outer) if kernel == "dkv" else (outer, inner)
+            computes = inner < n_inner and bool(
+                _pair_seen(i, j, bq, bk, window))
+            yield outer, step, inner if computes else None, fetched(
+                outer, step)
+
+
 @pytest.mark.parametrize("blocks", [(8, 16), (16, 8), (16, 16), (8, 8)])
 @pytest.mark.parametrize("window", [None, 1, 5, 13, 16, 100])
 def test_flash_window_maps_skip_what_the_band_empties(blocks, window):
     """With no window the index maps are the causal grid's as they
-    were; with one, a step whose pair the band empties stays on a block
-    of a pair it leaves something of (so that nothing is fetched for
-    it), and a pair it leaves something of is read where it is."""
-    from elephas_tpu.ops.flash_attention import _pair_seen, _visible_maps
+    were. With one the grid holds a band's blocks alone: every pair the
+    band leaves something of is computed exactly once and on the block
+    the map fetched, no empty pair computes, a step that computes
+    nothing stays on the block before it (so that nothing is fetched
+    for it), and ``band_grid`` counts the walk's steps."""
+    from elephas_tpu.ops.flash_attention import (
+        _pair_seen, _visible_maps, band_grid)
 
     bq, bk = blocks
     nq, nk = 48 // bq, 48 // bk
-    first_i, last_j = _visible_maps(True, bq, bk, nq, window, nk)
     rows, cols = np.arange(48)[:, None], np.arange(48)[None, :]
     mask = cols <= rows
     if window is not None:
         mask &= rows - cols < window
-    seen = 0
-    for i in range(nq):
-        for j in range(nk):
-            want = mask[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk].any()
-            assert bool(_pair_seen(i, j, bq, bk, window)) == want
-            fi, lj = int(first_i(i, j)), int(last_j(i, j))
-            assert 0 <= fi < nq and 0 <= lj < nk
-            assert bool(_pair_seen(fi, j, bq, bk, window))
-            assert bool(_pair_seen(i, lj, bq, bk, window))
-            if want:
-                assert (fi, lj) == (i, j)
-                seen += 1
-            if window is None:  # the maps of the causal grid, as they were
-                assert fi == min(max(i, j * bk // bq), nq - 1)
-                assert lj == min(j, ((i + 1) * bq - 1) // bk)
-    if window is not None and window <= 16:
-        assert seen < sum(
-            bool(_pair_seen(i, j, bq, bk, None))
-            for i in range(nq) for j in range(nk))
+    seen = {(i, j) for i in range(nq) for j in range(nk)
+            if mask[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk].any()}
+    assert seen == {(i, j) for i in range(nq) for j in range(nk)
+                    if _pair_seen(i, j, bq, bk, window)}
+    if window is None:  # the maps of the causal grid, as they were
+        first_i, last_j = _visible_maps(True, bq, bk, nq)
+        for i in range(nq):
+            for j in range(nk):
+                assert int(first_i(i, j)) == min(max(i, j * bk // bq), nq - 1)
+                assert int(last_j(i, j)) == min(j, ((i + 1) * bq - 1) // bk)
+        for kernel in ("fwd", "dkv", "dq"):
+            assert band_grid(kernel, 48, 48, bq, bk, None) == (
+                nq * nk, len(seen))
+        return
+    for kernel in ("fwd", "dkv", "dq"):
+        computed, n_inner = [], nq if kernel == "dkv" else nk
+        walk = list(_band_walk(kernel, 48, 48, bq, bk, window))
+        for outer, step, inner, fetched in walk:
+            assert 0 <= fetched < n_inner
+            if inner is not None:
+                assert fetched == inner
+                computed.append(
+                    (inner, outer) if kernel == "dkv" else (outer, inner))
+            elif step:
+                assert fetched == before
+            before = fetched
+        assert sorted(computed) == sorted(seen), kernel
+        assert band_grid(kernel, 48, 48, bq, bk, window) == (
+            len(walk), len(seen))
+        if window <= 16:
+            assert len(walk) < nq * nk
+
+
+def _band_cases():
+    """Sliding windows at a few thousand positions: under a block, a
+    block's length, between two, SmallThinker's 4096 under a longer
+    sequence, and past the sequence; equal blocks, each side longer,
+    and the Laguna forward kernel's pair; one and nine query heads a
+    key/value head."""
+    cases = {}
+    for window, s in ((100, 2048), (512, 2048), (640, 2048), (4096, 5120),
+                      (3000, 2048)):
+        for bq, bk in ((128, 128), (128, 256), (256, 128), (512, 1024)):
+            for group in (1, 9):
+                cases[f"w{window}-s{s}-q{bq}-k{bk}-g{group}"] = (
+                    window, s, bq, bk, group)
+    return cases
+
+
+@pytest.mark.parametrize("case", list(_band_cases()))
+def test_flash_band_grid_matches_reference(case):
+    """The three kernels on the band's grid (interpret mode) against
+    the plain attention under the same band: out, dQ, dK and dV."""
+    window, s, bq, bk, group = _band_cases()[case]
+    ks = jax.random.split(jax.random.key(window + group), 4)
+    q = jax.random.normal(ks[0], (group, s, 16))
+    k, v = (jax.random.normal(key, (1, s, 16)) for key in ks[1:3])
+    g = jax.random.normal(ks[3], q.shape)
+    got, vjp = jax.vjp(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, block_q=bq, block_k=bk),
+        q, k, v)
+    # a head at a time: nine heads' [S, S] scores at once are a gigabyte
+    plain = lambda q, k, v: jax.lax.map(  # noqa: E731
+        lambda head: attention_reference(
+            head, k[0], v[0], causal=True, window=window), q)
+    with jax.default_matmul_precision("highest"):
+        want, want_vjp = jax.vjp(plain, q, k, v)
+        wants = (want,) + want_vjp(g)
+    for a, w, leaf in zip((got,) + vjp(g), wants, ("out", "dq", "dk", "dv")):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(w), atol=2e-5, rtol=2e-5, err_msg=leaf)
+
+
+@pytest.mark.parametrize("cell,shape,blocks,want", [
+    # a head and sequence of the cell, at the blocks its rule resolves
+    ("laguna-fwd", (8192, 512), (512, 1024), {"fwd": (32, 23)}),
+    ("laguna-bwd", (8192, 512), (512, 512),
+     {"dkv": (32, 31), "dq": (32, 31)}),
+    ("smallthinker", (16384, 4096), (1024, 1024),
+     {"fwd": (80, 70), "dkv": (80, 70), "dq": (80, 70)}),
+    # the grid over every pair of blocks, which no window keeps
+    ("laguna-no-window", (8192, None), (512, 512),
+     {"fwd": (256, 136), "dkv": (256, 136), "dq": (256, 136)}),
+    ("whole-sequence-window", (8192, 8192), (512, 512),
+     {"fwd": (256, 136), "dkv": (256, 136), "dq": (256, 136)}),
+])
+def test_flash_band_grid_extents_of_the_cells(cell, shape, blocks, want):
+    """``band_grid`` at the banded cells' shapes: 32 steps a kernel
+    where the grid over every pair takes 128 and 256 (Laguna), 80 where
+    it takes 256 (SmallThinker); every pair that holds something is
+    computed exactly once on the walk; no window, or one of the whole
+    sequence, keeps the grid it had."""
+    from elephas_tpu.ops.flash_attention import _pair_seen, band_grid
+
+    (s, window), (bq, bk) = shape, blocks
+    for kernel, extents in want.items():
+        assert band_grid(kernel, s, s, bq, bk, window) == extents
+        if window is not None:
+            computed = [
+                (inner, outer) if kernel == "dkv" else (outer, inner)
+                for outer, _, inner, _ in _band_walk(
+                    kernel, s, s, bq, bk, window) if inner is not None]
+            assert sorted(computed) == sorted(
+                (i, j) for i in range(s // bq) for j in range(s // bk)
+                if _pair_seen(i, j, bq, bk, window))
+
+
+def test_flash_window_emits_its_grids_as_it_is_traced():
+    """Tracing the op under a window emits one ``flash.grid`` event a
+    windowed ``pallas_call``, with the blocks the rule resolved and
+    ``band_grid``'s counts (Laguna's: 32 steps a kernel, 23, 31 and 31
+    of them computing); without a window it emits none."""
+    from elephas_tpu import telemetry
+
+    q = jnp.zeros((1, 9, 8192, 128), jnp.bfloat16)
+    k = jnp.zeros((1, 1, 8192, 128), jnp.bfloat16)
+    tracer = telemetry.default_tracer()
+    for window, want in (
+            (None, {}),
+            (512, {"fwd": ((512, 1024), 32, 23), "dkv": ((512, 512), 32, 31),
+                   "dq": ((512, 512), 32, 31)})):
+        since = tracer.seq
+        jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, window=window, interpret=False)
+            .astype(jnp.float32)), (0, 1, 2)))(q, k, k)
+        got = [e["args"] for e in tracer.events(since, name="flash.grid")]
+        assert {a["kernel"]: ((a["block_q"], a["block_k"]), a["steps"],
+                              a["computing"]) for a in got} == want
+        assert all(a["window"] == window for a in got)
+        assert len(got) == len(want)
 
 
 def test_flash_window_is_a_positive_count_of_keys_under_the_causal_mask():
