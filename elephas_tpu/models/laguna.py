@@ -104,7 +104,8 @@ def laguna_lm(
     :func:`elephas_tpu.models.qwen3_next.qwen3_next_lm`: this chip's
     share of the routed experts, and every attention layer, dense
     feed-forward and sparse block keeping its input for the backward
-    pass (an attention layer also the flash kernel's result and
+    pass (an attention layer also what the flash kernels read and give:
+    q, k and v as projected and rotated, the result and its
     log-sum-exp). Compiled with SGD (``lr``, ``momentum``) and
     next-token cross-entropy over float32 logits."""
     lists = (layer_types, mlp_layer_types, num_attention_heads_per_layer)

@@ -234,8 +234,9 @@ def nemotron_h_lm(
     ``experts_held = (first, stop)`` and ``remat`` as for
     :func:`elephas_tpu.models.qwen3_next.qwen3_next_lm`: this chip's
     share of the routed experts, and every layer keeping its input for
-    the backward pass (an attention layer also the flash kernel's
-    result and log-sum-exp). Compiled with SGD (``lr``, ``momentum``)
+    the backward pass (an attention layer also what the flash kernels
+    read and give: q, k and v, the result and its log-sum-exp).
+    Compiled with SGD (``lr``, ``momentum``)
     and next-token cross-entropy over float32 logits."""
     n = len(hybrid_override_pattern) if num_hidden_layers is None else (
         num_hidden_layers)

@@ -130,8 +130,10 @@ def _layers():
         (``jax.ad_checkpoint.checkpoint_name``), and computes the rest
         again. The attention layers keep the flash forward kernel's
         result and log-sum-exp (its backward kernels' residuals beside
-        q, k and v, which are projected again), so that kernel runs once
-        a layer; a Gated DeltaNet layer its chunks' triangular
+        q, k and v), so that kernel runs once a layer;
+        ``smallthinker.BandedAttention`` names and keeps q, k and v too
+        and projects once, the other two project again; a Gated
+        DeltaNet layer its chunks' triangular
         inverses; a sparse block what its routing decided (the chosen
         experts and the slot buffer's plan, a few integers a token
         slot), so that top-k and the ordering run once a layer;

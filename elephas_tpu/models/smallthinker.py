@@ -47,6 +47,11 @@ LAYER_NAMES = ("BandedAttention",)
 # the order ``transformer._rope_tables`` takes them
 YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast",
              "beta_slow", "attention_factor")
+# the names (``jax.ad_checkpoint.checkpoint_name``) of what the layer
+# hands the flash kernels, heads first: ``[B, H, S, D]`` queries (rotated
+# where the layer rotates) and ``[B, Hk, S, D]`` keys and values at
+# their own head count
+Q_NAME, K_NAME, V_NAME = "attn_q", "attn_k", "attn_v"
 
 
 def _layers():
@@ -58,7 +63,9 @@ def _layers():
     import jax
     import jax.numpy as jnp
     import keras
+    from jax.ad_checkpoint import checkpoint_name
 
+    from elephas_tpu import telemetry
     from elephas_tpu.ops.flash_attention import LSE_NAME, OUT_NAME
 
     _Remat = qwen3_next._layers()["_Remat"]
@@ -69,12 +76,18 @@ def _layers():
     class BandedAttention(_Remat):
         """Grouped-query causal attention, banded (``window``) or full,
         rotated or not: the module's docstring has the settings. Under
-        ``remat`` the backward pass projects and rotates again and keeps
-        the flash kernel's result and log-sum-exp (a head's ``[S, D]``
-        in the compute dtype and ``[S]`` in float32), whatever the
-        band."""
+        ``remat`` the backward pass keeps, beside the layer's input,
+        everything the flash kernels read: q, k and v as the layer hands
+        them over (projected, rotated, heads first; k and v at their own
+        head count) and the forward kernel's result and log-sum-exp (a
+        head's ``[S, D]`` in the compute dtype and ``[S]`` in float32),
+        whatever the band. So it projects, rotates and transposes once
+        a step; only the gate's small product runs again. Tracing such
+        a layer emits one ``remat.kept`` event: the layer's name, the
+        names kept and the bytes that q, k and v hold, from their
+        shapes."""
 
-        kept = (OUT_NAME, LSE_NAME)
+        kept = (Q_NAME, K_NAME, V_NAME, OUT_NAME, LSE_NAME)
 
         def __init__(self, num_heads: int, num_kv_heads: int, head_dim: int,
                      window: int | None = None, rotary: bool = True,
@@ -147,10 +160,18 @@ def _layers():
             with jax.named_scope(
                     "attn.full" if self.window is None else "attn.window"):
                 heads_first = lambda t: jnp.transpose(t, (0, 2, 1, 3))  # noqa: E731
+                q, k, v = (
+                    checkpoint_name(heads_first(t), name)
+                    for t, name in ((q, Q_NAME), (k, K_NAME), (v, V_NAME)))
                 out = flash_attention(
-                    heads_first(q), heads_first(k), heads_first(v),
-                    causal=True, scale=hd ** -0.5, window=self.window,
+                    q, k, v, causal=True, scale=hd ** -0.5,
+                    window=self.window,
                 )
+                if self.remat:
+                    telemetry.emit(
+                        "remat.kept", layer=self.name, kept=list(self.kept),
+                        bytes={name: t.size * t.dtype.itemsize for name, t
+                               in ((Q_NAME, q), (K_NAME, k), (V_NAME, v))})
                 out = heads_first(out)
                 if not self.gating:
                     out = out.reshape(b, s, h * hd)
@@ -219,7 +240,8 @@ def smallthinker_lm(
     :func:`elephas_tpu.models.qwen3_next.qwen3_next_lm`: this chip's
     share of the routed experts, and every attention layer and sparse
     block keeping its inputs for the backward pass (an attention layer
-    also the flash kernel's result and log-sum-exp). Compiled with
+    also what the flash kernels read and give: q, k and v as projected
+    and rotated, the result and its log-sum-exp). Compiled with
     SGD (``lr``, ``momentum``) and next-token cross-entropy over float32
     logits."""
     if min(len(sliding_window_layout), len(rope_layout)) < num_hidden_layers:

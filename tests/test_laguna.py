@@ -294,17 +294,24 @@ def test_the_block_rule_reads_the_band():
 # the two digests with a window in them and no other: they were
 # recorded again at its tree ("smallthinker-window" 0a7a0c2ec877d225
 # before, FLASH_4096_AT_16384 d809d767066f0883); the two full-attention
-# digests are PR 44's parent's still, the proof that no window traces as
-# it did
+# digests were PR 44's parent's still, the proof that no window traces as
+# it did. PR 46 made the layer name and keep q, k and v
+# (``BandedAttention.kept``), which had to move the three layers' digests
+# (the forward text gains three ``name`` equations, the gradient's loses
+# the recomputed projections) and not the flash op's: the three were
+# recorded again at its tree ("smallthinker-window" c57378832976a18c,
+# "smallthinker-full" cdfae97de721dad7, "nemotron-full" 2125495016be3b34
+# before); FLASH_4096_AT_16384 is PR 45's still, the proof that the op
+# every other caller shares traces as it did
 PARENT_JAXPRS = {
     "smallthinker-window": (
         (28, 4, 128, 4096, True, 1500000), (1, 16384, 2560),
-        "c57378832976a18c"),
+        "6aa3e7c2d5a8bd20"),
     "smallthinker-full": (
         (28, 4, 128, None, False, 1500000), (1, 16384, 2560),
-        "cdfae97de721dad7"),
+        "6bfc33aff6e3158a"),
     "nemotron-full": (
-        (32, 2, 128, None, False), (2, 8192, 2688), "2125495016be3b34"),
+        (32, 2, 128, None, False), (2, 8192, 2688), "4694e49773c83f64"),
 }
 FLASH_4096_AT_16384 = "ed6ffb2287124f22"
 
@@ -366,6 +373,114 @@ def test_smallthinkers_band_keeps_its_flash_program():
     short = lambda q, k, v: flash_attention(  # noqa: E731
         q, k, v, causal=True, window=512, interpret=False)
     assert _digest(short, q, k, k) != got
+
+
+# -- what a recomputed layer keeps -------------------------------------------
+
+KEPT_KINDS = {  # (layer of CFG, the gate)
+    "banded-gated": (1, "per-head"), "banded": (1, None),
+    "full-gated": (2, "per-head"), "full": (2, None)}
+
+
+def _kept_layer(kind, remat=True):
+    """``(layer, loss(params, x), params, x)``: a layer of its own for
+    each trace."""
+    i, gating = KEPT_KINDS[kind]
+    x = _inputs()
+    layer = _attn_layer(i, remat, gating=gating)
+    layer.build(x.shape)
+    params = [0.2 * jax.random.normal(jax.random.key(n), v.shape)
+              for n, v in enumerate(layer.trainable_variables)]
+    loss = lambda p, x: jnp.sum(  # noqa: E731
+        jnp.sin(3.0 * layer.stateless_call(p, [], x)[0]))
+    return layer, loss, params, x
+
+
+@pytest.mark.parametrize("kind", sorted(KEPT_KINDS))
+def test_a_recomputed_layer_projects_once(kind, monkeypatch, capsys):
+    """Under ``remat`` the layer keeps q, k and v as it hands them to the
+    flash kernels (heads first, k and v at their own head count) beside
+    the forward kernel's two results: the gradient's program holds the
+    products of x by ``q_proj``, ``k_proj`` and ``v_proj`` once, three
+    products fewer than with the two results alone kept (PR 45's
+    ``kept``, under which the backward pass projected again); the gate's
+    small product still runs twice."""
+    from elephas_tpu.models import smallthinker as zoo
+    from elephas_tpu.ops.flash_attention import LSE_NAME, OUT_NAME
+
+    def products():
+        _layer, loss, params, x = _kept_layer(kind)
+        text = str(jax.make_jaxpr(jax.grad(loss, (0, 1)))(params, x))
+        return text.count("dot_general"), text
+
+    layer, loss, params, x = _kept_layer(kind)
+    assert layer.kept == (
+        zoo.Q_NAME, zoo.K_NAME, zoo.V_NAME, OUT_NAME, LSE_NAME)
+    now, text = products()
+    for name in layer.kept:
+        assert f"name={name}" in text
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(loss, params, x)
+    saved = capsys.readouterr().out
+    b, s = x.shape[:2]
+    h, hk, d = layer.num_heads, layer.num_kv_heads, layer.head_dim
+    for shape, times in (((b, h, s, d), 1), ((b, hk, s, d), 2)):
+        assert saved.count("f32[" + ",".join(map(str, shape)) + "]") == times
+    assert f"f32[{b * h},{s},{d}]" in saved  # the forward kernel's result
+    assert f"named '{LSE_NAME}'" in saved
+    monkeypatch.setattr(type(layer), "kept", (OUT_NAME, LSE_NAME))
+    before, _ = products()
+    assert before - now == 3
+
+
+@pytest.mark.parametrize("kind", sorted(KEPT_KINDS))
+def test_a_recomputed_layers_gradients_are_the_plain_layers(kind):
+    _layer, loss, params, x = _kept_layer(kind, remat=True)
+    got = jax.jit(jax.grad(loss, (0, 1)))(params, x)
+    _layer, plain, _params, _x = _kept_layer(kind, remat=False)
+    want = jax.jit(jax.grad(plain, (0, 1)))(params, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        _close(a, b, 5e-4)
+
+
+@pytest.mark.parametrize("shape,heads", [
+    ((2, 24, 32), (18, 2, 16)), ((1, 64, 48), (28, 4, 8))])
+def test_the_kept_event_counts_bytes_from_the_shapes(shape, heads):
+    """Tracing a recomputed layer emits one ``remat.kept`` event: the
+    names it keeps, and the bytes of the three the layer itself names:
+    q, k and v in bfloat16 hold ``B x S x (H + 2 Hk) x D x 2``; a layer
+    that recomputes nothing emits none."""
+    from elephas_tpu import telemetry
+    from elephas_tpu.models import smallthinker as zoo
+
+    (b, s, _), (h, hk, d) = shape, heads
+    x = jnp.zeros(shape, jnp.bfloat16)
+
+    def events(remat):
+        layer = zoo.BandedAttention(
+            h, hk, d, window=8, dtype="bfloat16", remat=remat,
+            name=f"kept{b}x{s}")
+        layer.build(shape)
+        tv = [v.value.astype(jnp.bfloat16)
+              for v in layer.trainable_variables]
+        since = telemetry.default_tracer().seq
+        jax.make_jaxpr(lambda tv, x: layer.stateless_call(tv, [], x)[0])(
+            tv, x)
+        return telemetry.default_tracer().events(
+            since_seq=since, name="remat.kept")
+
+    assert events(remat=False) == []
+    (event,) = events(remat=True)
+    args = event["args"]
+    assert args["layer"] == f"kept{b}x{s}"
+    assert args["kept"] == [
+        zoo.Q_NAME, zoo.K_NAME, zoo.V_NAME, "flash_out", "flash_lse"]
+    held = args["bytes"]
+    assert sum(held[n] for n in (zoo.Q_NAME, zoo.K_NAME, zoo.V_NAME)) == (
+        b * s * (h + 2 * hk) * d * 2)
+    assert held == {zoo.Q_NAME: b * s * h * d * 2,
+                    zoo.K_NAME: b * s * hk * d * 2,
+                    zoo.V_NAME: b * s * hk * d * 2}
 
 
 # -- each layer kind against the reference ----------------------------------
