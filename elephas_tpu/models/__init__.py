@@ -9,14 +9,23 @@ BASELINE.json) and the examples share one definition. All builders return *compi
 models ready to wrap in ``SparkModel``.
 
 The five sparse LMs (``qwen3_next_lm``, ``deepseek_v3_lm``,
-``smallthinker_lm``, ``nemotron_h_lm``, ``laguna_lm``) are built from
-blocks that this package also exports: the norms ``ZeroCentredRMSNorm`` and ``RMSNorm``;
-the feed-forwards ``SwiGLU``, ``DenseMLP`` and ``UngatedMLP``; the
-mixers ``GatedAttention``, ``LatentAttention``, ``BandedAttention``,
-``GatedDeltaNet`` (the gated delta rule) and ``Mamba2Mixer`` (the
-state-space scan); and ``SparseMoeBlock``, the one sparse block of all
-five (gated or ungated experts, with or without a shared expert).
+``smallthinker_lm``, ``nemotron_h_lm``, ``laguna_lm``) each have a file
+that turns the published config's argument names into a list of layers
+and imports no other model's; the layers, which this package also
+exports (``LM_NAMES``), live in two modules that import keras and are
+therefore loaded on first use, not with the package:
+``lm_blocks`` has the norms ``ZeroCentredRMSNorm`` and ``RMSNorm``, the
+feed-forwards ``SwiGLU``, ``DenseMLP`` and ``UngatedMLP``,
+``SparseMoeBlock``, the one sparse block of all five (gated or ungated
+experts, with or without a shared expert), ``LMHead`` and the loop that
+stacks a list of layers into a compiled model (``decoder_lm``);
+``lm_mixers`` has the attention layers ``GatedAttention``,
+``LatentAttention`` and ``BandedAttention`` with their one path into the
+flash kernels, ``GatedDeltaNet`` (the gated delta rule) and
+``Mamba2Mixer`` (the state-space scan).
 """
+
+import importlib
 
 from elephas_tpu.models.mlp import mnist_mlp
 from elephas_tpu.models.convnet import cifar10_cnn
@@ -37,6 +46,16 @@ from elephas_tpu.models.smallthinker import smallthinker_lm
 from elephas_tpu.models.nemotron_h import nemotron_h_lm
 from elephas_tpu.models.laguna import laguna_lm
 
+# the sparse LMs' layer classes, and the loss they are compiled with, by
+# the module that defines them
+LM_NAMES = {
+    "lm_blocks": ("ZeroCentredRMSNorm", "RMSNorm", "SwiGLU", "DenseMLP",
+                  "UngatedMLP", "SparseMoeBlock", "LMHead",
+                  "next_token_loss"),
+    "lm_mixers": ("BandedAttention", "GatedAttention", "LatentAttention",
+                  "GatedDeltaNet", "Mamba2Mixer"),
+}
+
 __all__ = [
     "mnist_mlp",
     "cifar10_cnn",
@@ -56,17 +75,8 @@ __all__ = [
     "MoeFFN",
     "FlashMHA",
     "FusedLayerNorm",
-    "ZeroCentredRMSNorm",
-    "SwiGLU",
-    "UngatedMLP",
-    "GatedAttention",
-    "GatedDeltaNet",
-    "SparseMoeBlock",
-    "RMSNorm",
-    "LatentAttention",
-    "DenseMLP",
-    "BandedAttention",
-    "Mamba2Mixer",
+    *LM_NAMES["lm_blocks"],
+    *LM_NAMES["lm_mixers"],
 ]
 
 
@@ -84,10 +94,8 @@ def __getattr__(name):
         from elephas_tpu.models.switch import MoeFFN
 
         return MoeFFN
-    from elephas_tpu.models import (
-        deepseek_v3, nemotron_h, qwen3_next, smallthinker)
-
-    for module in (qwen3_next, deepseek_v3, smallthinker, nemotron_h):
-        if name in module.LAYER_NAMES:
-            return getattr(module, name)
+    for module, names in LM_NAMES.items():
+        if name in names:
+            return getattr(
+                importlib.import_module(f"{__name__}.{module}"), name)
     raise AttributeError(name)

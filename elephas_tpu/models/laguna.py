@@ -2,22 +2,21 @@
 in their head counts (the Laguna block, as ``Laguna-S-2.1`` publishes
 it under ``model_type: laguna``).
 
-The block is made of layers the zoo has, set the published way:
+The block is made of layers of :mod:`elephas_tpu.models.lm_blocks` and
+:mod:`elephas_tpu.models.lm_mixers`, set the published way:
 
-- :class:`elephas_tpu.models.smallthinker.BandedAttention`, a layer at
-  a time: ``num_attention_heads_per_layer[l]`` query heads (more behind
-  a ``sliding_window`` than behind full attention) over the same
+- ``BandedAttention``, a layer at a time:
+  ``num_attention_heads_per_layer[l]`` query heads (more behind a
+  ``sliding_window`` than behind full attention) over the same
   ``num_key_value_heads``; a sigmoid output gate, one scalar a head and
   token from a projection of the layer's normed input
   (``gating: "per-head"``); and a rotation by the layer's kind
   (``rope_parameters[layer_types[l]]``): plain rotary embedding over
   the whole head in a sliding layer, YaRN-scaled frequencies over the
   first ``partial_rotary_factor`` of the head in a full one.
-- :class:`elephas_tpu.models.deepseek_v3.DenseMLP` where
-  ``mlp_layer_types[l]`` is ``dense``, else the shared
-  :class:`elephas_tpu.models.qwen3_next.SparseMoeBlock` with the rule
-  this family states: sigmoid scores (:data:`SCORING_FUNC`) over all
-  ``num_experts``, the ``num_experts_per_tok`` largest renormalised
+- ``DenseMLP`` where ``mlp_layer_types[l]`` is ``dense``, else
+  ``SparseMoeBlock`` with the rule this family states: sigmoid scores
+  (:data:`SCORING_FUNC`) over all ``num_experts``, the ``num_experts_per_tok`` largest renormalised
   and scaled by ``moe_routed_scaling_factor``, SwiGLU experts, one
   ungated SwiGLU shared expert.
 
@@ -29,10 +28,7 @@ here.
 
 from __future__ import annotations
 
-from elephas_tpu.models import deepseek_v3, qwen3_next, smallthinker
-from elephas_tpu.models.qwen3_next import next_token_loss
-from elephas_tpu.models.smallthinker import YARN_KEYS
-from elephas_tpu.models.transformer import _dtype_policy_scope, _keras
+from functools import partial
 
 LAYER_TYPES = ("full_attention", "sliding_attention")
 MLP_LAYER_TYPES = ("dense", "sparse")
@@ -47,6 +43,8 @@ def rotation_of(rope_parameters: dict, head_dim: int) -> dict:
     arguments: the base, the rotated width, and YaRN's settings where
     ``rope_type`` says ``yarn`` (``attention_factor`` None where the
     group leaves it to the standard rule)."""
+    from elephas_tpu.models.lm_mixers import YARN_KEYS
+
     kind = rope_parameters.get("rope_type", "default")
     if kind not in ("default", "yarn"):
         raise ValueError(f"rope_type {kind!r} is neither default nor yarn")
@@ -120,51 +118,36 @@ def laguna_lm(
         raise ValueError(f"layer types {sorted(unknown)}")
     rope_parameters = rope_parameters or {
         kind: {"rope_theta": 10000.0} for kind in LAYER_TYPES}
-    keras = _keras()
-    keras.utils.set_random_seed(seed)
-    with _dtype_policy_scope(keras, dtype_policy):
-        shared = qwen3_next._layers()
-        dense_layers = deepseek_v3._layers()
-        Norm, Attention = (dense_layers["RMSNorm"],
-                           smallthinker._layers()["BandedAttention"])
-        inputs = keras.Input((maxlen,), dtype="int32")
-        x = keras.layers.Embedding(
-            vocab_size, hidden_size, name="embed_tokens",
-            embeddings_initializer=keras.initializers.RandomNormal(
-                stddev=init_std),
-        )(inputs)
-        for i in range(num_hidden_layers):
-            kind = layer_types[i]
-            h = Norm(rms_norm_eps, name=f"layer{i}_input_norm")(x)
-            x = x + Attention(
-                num_attention_heads_per_layer[i], num_key_value_heads,
-                head_dim,
-                sliding_window if kind == "sliding_attention" else None,
-                init_std=init_std, gating=gating, remat=remat,
-                name=f"layer{i}_attn",
-                **rotation_of(rope_parameters[kind], head_dim),
-            )(h)
-            h = Norm(rms_norm_eps, name=f"layer{i}_post_norm")(x)
-            if mlp_layer_types[i] == "dense":
-                h = dense_layers["DenseMLP"](
-                    intermediate_size, init_std, remat=remat,
-                    name=f"layer{i}_mlp",
-                )(h)
-            else:
-                h = shared["SparseMoeBlock"](
-                    num_experts, num_experts_per_tok, moe_intermediate_size,
-                    shared_expert_intermediate_size, experts_held, init_std,
-                    scoring_func=SCORING_FUNC,
-                    routed_scaling_factor=moe_routed_scaling_factor,
-                    gated_shared_expert=False, remat=remat,
-                    name=f"layer{i}_moe",
-                )(h)
-            x = x + h
-        x = Norm(rms_norm_eps, name="final_norm")(x)
-        outputs = shared["LMHead"](vocab_size, init_std, name="lm_head")(x)
-        model = keras.Model(inputs, outputs, name="laguna_lm")
-    model.compile(
-        optimizer=keras.optimizers.SGD(lr, momentum=momentum),
-        loss=next_token_loss,
-    )
-    return model
+    from elephas_tpu.models import lm_blocks, lm_mixers
+
+    def attention(i):
+        kind = layer_types[i]
+        return partial(
+            lm_mixers.BandedAttention, num_attention_heads_per_layer[i],
+            num_key_value_heads, head_dim,
+            sliding_window if kind == "sliding_attention" else None,
+            init_std=init_std, gating=gating, remat=remat,
+            name=f"layer{i}_attn",
+            **rotation_of(rope_parameters[kind], head_dim))
+
+    def feed_forward(i):
+        if mlp_layer_types[i] == "dense":
+            return partial(
+                lm_blocks.DenseMLP, intermediate_size, init_std, remat=remat,
+                name=f"layer{i}_mlp")
+        return partial(
+            lm_blocks.SparseMoeBlock, num_experts, num_experts_per_tok,
+            moe_intermediate_size, shared_expert_intermediate_size,
+            experts_held, init_std, scoring_func=SCORING_FUNC,
+            routed_scaling_factor=moe_routed_scaling_factor,
+            gated_shared_expert=False, remat=remat, name=f"layer{i}_moe")
+
+    return lm_blocks.decoder_lm(
+        "laguna_lm",
+        [[lm_blocks.SubLayer("input_norm", attention(i)),
+          lm_blocks.SubLayer("post_norm", feed_forward(i))]
+         for i in range(num_hidden_layers)],
+        partial(lm_blocks.RMSNorm, rms_norm_eps),
+        vocab_size=vocab_size, maxlen=maxlen, hidden_size=hidden_size,
+        init_std=init_std, lr=lr, momentum=momentum, seed=seed,
+        dtype_policy=dtype_policy)

@@ -12,15 +12,18 @@ described device, makes the flash and grouped kernels compile instead
 of interpreting (``backend_guard.pallas_interpret``: a process on the
 CPU would interpret them), and lowers ``MeshRunner._build_epoch_fn`` on
 ``ShapeDtypeStruct``s sharded as ``fit`` shards them. Prints XLA's
-``memory_analysis()`` of the compiled program and how many of each
-Pallas kernel it holds. A compile that passes is not a chip run: the
-chip's ``hbm_peak_gb.fit`` is the allocator's peak plus these
-temporaries. The model's real weights live on the host while this runs
+``memory_analysis()`` of the compiled program, how many of each
+Pallas kernel it holds and a sha256 of the lowered text (two trees that
+trace a cell to the same program print the same hash). A compile that
+passes is not a chip run: the chip's ``hbm_peak_gb.fit`` is the
+allocator's peak plus these temporaries. The model's real weights live on the host while this runs
 (a few GB for an LM cell).
 """
 
 import argparse
 import collections
+import hashlib
+import inspect
 import json
 import os
 import re
@@ -31,6 +34,16 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 os.environ.setdefault("KERAS_BACKEND", "jax")
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
+
+
+def _more_arguments(build, cfg):
+    """What a builder's ``_build`` takes beside the configuration and
+    its optimizer (the first builder's takes the dtype policy and the
+    first held expert, which the later ones read from the file)."""
+    given = {"policy": None if cfg["dtype"] == "float32" else cfg["dtype"],
+             "first": cfg.get("experts_held_first")}
+    names = list(inspect.signature(build).parameters)[2:]
+    return {name: given[name] for name in names}
 
 
 def main() -> int:
@@ -49,13 +62,18 @@ def main() -> int:
     from elephas_tpu.worker import MeshRunner
 
     backend_guard.pallas_interpret = lambda: False
+    # the lowered text then holds no Python call stack (JAX writes the
+    # callers' files, functions and lines into each kernel's locations):
+    # its hash names the program, wherever the code that traced it stands
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     manifest = mf.load_manifest()
     cell = mf.find_cell(manifest, args.workload)
     cfg = mf.config_of(manifest, cell)
     traffic = mf.load_json("traffic", cell["traffic"])
     builder = mf.load_module("builders", cfg["builder"])
     t0 = time.monotonic()
-    model = builder._build(cfg, cfg["optimizer"])
+    model = builder._build(cfg, cfg["optimizer"], **_more_arguments(
+        builder._build, cfg))
     built = time.monotonic()
 
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
@@ -79,8 +97,8 @@ def main() -> int:
     compiled = lowered.compile()
     done = time.monotonic()
     memory = compiled.memory_analysis()
-    kernels = collections.Counter(
-        re.findall(r'kernel_name = "(\w+)"', lowered.as_text()))
+    text = lowered.as_text()
+    kernels = collections.Counter(re.findall(r'kernel_name = "(\w+)"', text))
     result = {
         "cell": args.workload,
         "parameters": int(sum(np.prod(v.shape) for v in model.variables)),
@@ -91,6 +109,7 @@ def main() -> int:
         "generated_code_size_in_bytes": int(
             memory.generated_code_size_in_bytes),
         "kernels_in_the_lowered_program": dict(kernels),
+        "lowered_text_sha256": hashlib.sha256(text.encode()).hexdigest(),
         "build_s": round(built - t0, 1),
         "trace_and_lower_s": round(traced - built, 1),
         "compile_s": round(done - traced, 1),

@@ -75,7 +75,7 @@ def _layer_params(ref, prefix, seed=0, cfg=CFG):
 
 
 def _moe_layer(held=(4, 8), remat=False):
-    from elephas_tpu.models import qwen3_next as zoo
+    from elephas_tpu.models import lm_blocks as zoo
 
     return zoo.SparseMoeBlock(
         CFG["n_routed_experts"], CFG["num_experts_per_tok"],
@@ -87,7 +87,7 @@ def _moe_layer(held=(4, 8), remat=False):
 
 
 def _keras_layer(kind, remat=False):
-    from elephas_tpu.models import deepseek_v3 as zoo
+    from elephas_tpu import models as zoo
 
     if kind == "attn":
         return zoo.LatentAttention(
@@ -170,7 +170,7 @@ def test_latent_attention_sums_values_narrower_than_its_scores(
 
 
 def test_rms_norm(ref):
-    from elephas_tpu.models import deepseek_v3 as zoo
+    from elephas_tpu.models import lm_blocks as zoo
 
     x = jax.random.normal(jax.random.key(6), (2, 5, 32))
     norm = zoo.RMSNorm(name="n")
@@ -227,7 +227,7 @@ def test_the_softmax_rule_is_the_hybrid_lms_bit_for_bit():
 
 
 def test_sparse_block_refuses_an_unknown_score():
-    from elephas_tpu.models import qwen3_next as zoo
+    from elephas_tpu.models import lm_blocks as zoo
 
     with pytest.raises(ValueError, match="scoring_func"):
         zoo.SparseMoeBlock(16, 2, 16, 16, scoring_func="tanh")

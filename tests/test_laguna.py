@@ -104,7 +104,7 @@ def _layer_params(ref, prefix, seed=0, cfg=CFG):
 
 def _attn_layer(i, remat=False, **more):
     from elephas_tpu.models import laguna
-    from elephas_tpu.models import smallthinker as zoo
+    from elephas_tpu.models import lm_mixers as zoo
 
     kind = CFG["layer_types"][i]
     args = dict(
@@ -119,7 +119,7 @@ def _attn_layer(i, remat=False, **more):
 
 
 def _moe_layer(held=HELD, remat=False, name="layer1_moe"):
-    from elephas_tpu.models import qwen3_next as zoo
+    from elephas_tpu.models import lm_blocks as zoo
 
     return zoo.SparseMoeBlock(
         CFG["num_experts"], CFG["num_experts_per_tok"],
@@ -152,7 +152,7 @@ def test_the_yarn_table_is_the_closed_form(ref):
     pairs that turn 32 times and once), and against the reference's
     float64 table; the stated ``attention_factor`` is the standard
     rule's ``0.1 ln 128 + 1``."""
-    from elephas_tpu.models.smallthinker import YARN_KEYS
+    from elephas_tpu.models.lm_mixers import YARN_KEYS
     from elephas_tpu.models.transformer import _rope_tables
 
     group = ROPE["full_attention"]
@@ -332,7 +332,7 @@ def test_the_other_cells_attention_layers_trace_as_before(model):
     they traced to before the layer had a gate, a rotated width or
     YaRN: naming the defaults changes nothing, and (under the jax the
     digest was recorded with) the text is the parent's."""
-    from elephas_tpu.models import smallthinker as zoo
+    from elephas_tpu.models import lm_mixers as zoo
 
     args, shape, digest = PARENT_JAXPRS[model]
     x = jnp.zeros(shape, jnp.bfloat16)
@@ -405,7 +405,7 @@ def test_a_recomputed_layer_projects_once(kind, monkeypatch, capsys):
     products fewer than with the two results alone kept (PR 45's
     ``kept``, under which the backward pass projected again); the gate's
     small product still runs twice."""
-    from elephas_tpu.models import smallthinker as zoo
+    from elephas_tpu.models import lm_mixers as zoo
     from elephas_tpu.ops.flash_attention import LSE_NAME, OUT_NAME
 
     def products():
@@ -451,7 +451,7 @@ def test_the_kept_event_counts_bytes_from_the_shapes(shape, heads):
     q, k and v in bfloat16 hold ``B x S x (H + 2 Hk) x D x 2``; a layer
     that recomputes nothing emits none."""
     from elephas_tpu import telemetry
-    from elephas_tpu.models import smallthinker as zoo
+    from elephas_tpu.models import lm_mixers as zoo
 
     (b, s, _), (h, hk, d) = shape, heads
     x = jnp.zeros(shape, jnp.bfloat16)
@@ -496,7 +496,7 @@ def test_layer_forward_and_gradients(ref, kind, remat):
         want_fn = lambda p, x: ref._sparse_block(  # noqa: E731
             p, prefix, x, CFG, _ident, _mm(ref))
     elif kind == "dense":
-        from elephas_tpu.models import deepseek_v3 as zoo
+        from elephas_tpu.models import lm_blocks as zoo
 
         layer = zoo.DenseMLP(CFG["intermediate_size"], 0.2, remat=remat,
                              name="layer0_mlp")

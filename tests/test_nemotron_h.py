@@ -189,23 +189,23 @@ def test_scan_keeps_its_decays_and_state_in_float32():
 
 
 def _mixer(kind, remat=False):
-    from elephas_tpu.models import nemotron_h, qwen3_next, smallthinker
+    from elephas_tpu.models import lm_mixers
 
     if kind == "mamba":
-        return nemotron_h.Mamba2Mixer(
+        return lm_mixers.Mamba2Mixer(
             CFG["mamba_num_heads"], CFG["mamba_head_dim"],
             CFG["ssm_state_size"], CFG["n_groups"], CFG["conv_kernel"],
             CFG["chunk_size"], CFG["layer_norm_epsilon"], remat=remat,
             name="layer0_mamba")
     if kind == "moe":
         return _moe_layer(remat=remat)
-    return smallthinker.BandedAttention(
+    return lm_mixers.BandedAttention(
         CFG["num_attention_heads"], CFG["num_key_value_heads"],
         CFG["head_dim"], None, False, remat=remat, name="layer3_attn")
 
 
 def _moe_layer(held=HELD, remat=False, name="layer1_moe"):
-    from elephas_tpu.models import qwen3_next as zoo
+    from elephas_tpu.models import lm_blocks as zoo
 
     return zoo.SparseMoeBlock(
         CFG["n_routed_experts"], CFG["num_experts_per_tok"],

@@ -175,7 +175,7 @@ def _layer_params(ref, prefix, seed=0):
 
 
 def _keras_layer(kind, remat=False):
-    from elephas_tpu.models import qwen3_next as zoo
+    from elephas_tpu import models as zoo
 
     if kind == "attn":
         return zoo.GatedAttention(
@@ -262,15 +262,15 @@ def test_rematerialised_mixer_keeps_its_triangular_inverses(ref, monkeypatch):
 def _attention_layer(kind):
     """One rematerialised attention layer of each model's kind, at this
     file's widths."""
-    from elephas_tpu.models import deepseek_v3, qwen3_next, smallthinker
+    from elephas_tpu.models import lm_mixers
 
     if kind == "gated":
-        return qwen3_next.GatedAttention(4, 2, 16, 4, remat=True, name="attn")
+        return lm_mixers.GatedAttention(4, 2, 16, 4, remat=True, name="attn")
     if kind == "latent":
-        return deepseek_v3.LatentAttention(
+        return lm_mixers.LatentAttention(
             4, 16, 8, 16, 16, remat=True, name="attn")
     windowed = kind == "banded-window"
-    return smallthinker.BandedAttention(
+    return lm_mixers.BandedAttention(
         4, 2, 16, 6 if windowed else None, windowed, remat=True, name="attn")
 
 
@@ -323,7 +323,7 @@ def test_rematerialised_attention_runs_the_flash_forward_kernel_once(
 
 
 def test_norm_and_swiglu(ref):
-    from elephas_tpu.models import qwen3_next as zoo
+    from elephas_tpu.models import lm_blocks as zoo
 
     x = jax.random.normal(jax.random.key(6), (2, 5, 32))
     norm = zoo.ZeroCentredRMSNorm(name="n")
@@ -347,7 +347,7 @@ def test_shares_add_up_to_the_uncut_layer(ref):
     """The routed parts of all four shares (4 of the 16 experts each),
     with the shared expert counted once, add up to what the uncut
     reference (all 16 held) gives for the layer."""
-    from elephas_tpu.models import qwen3_next as zoo
+    from elephas_tpu.models import lm_blocks as zoo
 
     whole_cfg = dict(CFG, num_experts_held=16, experts_held_first=0)
     params = {k: v for k, v in ref.init_params(whole_cfg, 3).items()
@@ -567,7 +567,7 @@ def test_rematerialised_sparse_block_routes_once():
     numbers."""
     import re
 
-    from elephas_tpu.models import qwen3_next as zoo
+    from elephas_tpu.models import lm_blocks as zoo
 
     tokens, d, experts, k = 256, 32, 64, 6  # a buffer of 384 rows
     x = jax.random.normal(jax.random.key(3), (1, tokens, d))
@@ -613,7 +613,7 @@ def test_sparse_block_counts_its_calls_and_the_blocked_ones(bias, blocked):
     slots were routed to the held experts than the one buffer holds:
     never under a uniform router, every call where all tokens choose
     one held expert (the router of the test above)."""
-    from elephas_tpu.models import qwen3_next as zoo
+    from elephas_tpu.models import lm_blocks as zoo
 
     layer = _keras_layer("moe")
     x = jax.random.normal(jax.random.key(11), (2, SEQ, 32)).at[..., 0].set(1.0)
@@ -635,7 +635,7 @@ def test_sparse_block_counts_its_calls_and_the_blocked_ones(bias, blocked):
 
 
 def test_sparse_block_refuses_a_range_outside_the_experts():
-    from elephas_tpu.models import qwen3_next as zoo
+    from elephas_tpu.models import lm_blocks as zoo
 
     with pytest.raises(ValueError, match="experts_held"):
         zoo.SparseMoeBlock(16, 2, 16, 16, (12, 20))
@@ -719,7 +719,7 @@ def test_fit_with_a_metric_never_runs_the_master_model_op_by_op(
     import keras
 
     from elephas_tpu import SparkModel
-    from elephas_tpu.models import qwen3_next as zoo
+    from elephas_tpu import models as zoo
 
     far = jax.devices()[-1]
     original = jax.local_devices

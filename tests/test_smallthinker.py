@@ -76,7 +76,7 @@ def _layer_params(ref, prefix, seed=0, cfg=CFG):
 
 def _attn_layer(windowed, remat=False, window=WINDOW, rotary=None,
                 name=None):
-    from elephas_tpu.models import smallthinker as zoo
+    from elephas_tpu.models import lm_mixers as zoo
 
     return zoo.BandedAttention(
         CFG["num_attention_heads"], CFG["num_key_value_heads"],
@@ -86,7 +86,7 @@ def _attn_layer(windowed, remat=False, window=WINDOW, rotary=None,
 
 
 def _moe_layer(held=HELD, remat=False, name="layer1_moe"):
-    from elephas_tpu.models import qwen3_next as zoo
+    from elephas_tpu.models import lm_blocks as zoo
 
     return zoo.SparseMoeBlock(
         CFG["moe_num_primary_experts"],

@@ -3,9 +3,11 @@ suite when a rule of the code base is broken at its source: no
 swallowed exception on a fault path, every registered metric family
 in the docs' catalog, no ad-hoc wall clock on a control path and
 telemetry captured at construction, no materialized attention score
-matrix in the serving modules.
+matrix in the serving modules, no sparse-LM model file that reaches into
+another.
 """
 
+import ast
 import glob
 import os
 import re
@@ -616,3 +618,53 @@ class TestFlashAttentionLint:
             "ops/flash_serving (or tag the line with 'flash-lint: "
             "allow <reason>'):\n" + "\n".join(offences)
         )
+
+
+class TestLmModelFilesLint:
+    """ISSUE 47: a sparse LM's model file lists its layers and imports no
+    other model's, and every layer class is defined at module scope of
+    ``lm_blocks`` or ``lm_mixers``. Before, the shared layers were classes
+    made inside a function of the first model's file, the later models
+    fetched them from it by string, and each ``model_config`` PR edited
+    an earlier model's file: an import of one model module by another,
+    or a class statement inside a function, in any of these files is
+    that shape coming back. (``models/transformer.py`` and
+    ``models/switch.py`` still build three served layers inside
+    functions; they are outside this lint, a debt ``ROADMAP.md`` names
+    under D1.)"""
+
+    MODEL_MODULES = ("qwen3_next", "deepseek_v3", "smallthinker",
+                     "nemotron_h", "laguna")
+    BLOCK_MODULES = ("lm_blocks", "lm_mixers")
+
+    def test_no_model_file_imports_another_or_nests_a_class(self):
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        offences, classes = [], 0
+        for module in self.MODEL_MODULES + self.BLOCK_MODULES:
+            rel = os.path.join("elephas_tpu", "models", module + ".py")
+            with open(os.path.join(root, rel)) as f:
+                tree = ast.parse(f.read())
+            for node in ast.walk(tree):
+                imported = []
+                if isinstance(node, ast.Import):
+                    imported = [a.name for a in node.names]
+                elif isinstance(node, ast.ImportFrom):
+                    imported = [f"{node.module}.{a.name}" for a in node.names]
+                for name in imported:
+                    if set(name.split(".")) & set(self.MODEL_MODULES):
+                        offences.append(f"{rel}:{node.lineno}: imports {name}")
+                if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
+                    offences.extend(
+                        f"{rel}:{inner.lineno}: class {inner.name} inside "
+                        f"{node.name}()" for inner in ast.walk(node)
+                        if isinstance(inner, ast.ClassDef))
+            if module in self.MODEL_MODULES:
+                offences.extend(
+                    f"{rel}:{node.lineno}: class {node.name} in a model file"
+                    for node in tree.body if isinstance(node, ast.ClassDef))
+            classes += sum(isinstance(n, ast.ClassDef) for n in tree.body)
+        assert classes >= 15  # the parse found the block modules' layers
+        assert not offences, (
+            "a sparse LM's model file imports another model's, or a layer "
+            "class is not at module scope of lm_blocks / lm_mixers:\n"
+            + "\n".join(offences))
