@@ -9,7 +9,8 @@ BASELINE.json) and the examples share one definition. All builders return *compi
 models ready to wrap in ``SparkModel``.
 
 The five sparse LMs (``qwen3_next_lm``, ``deepseek_v3_lm``,
-``smallthinker_lm``, ``nemotron_h_lm``, ``laguna_lm``) each have a file
+``smallthinker_lm``, ``nemotron_h_lm``, ``laguna_lm``) and the dense
+state-space hybrid ``granite_hybrid_lm`` each have a file
 that turns the published config's argument names into a list of layers
 and imports no other model's; the layers, which this package also
 exports (``LM_NAMES``), live in two modules that import keras and are
@@ -17,7 +18,8 @@ therefore loaded on first use, not with the package:
 ``lm_blocks`` has the norms ``ZeroCentredRMSNorm`` and ``RMSNorm``, the
 feed-forwards ``SwiGLU``, ``DenseMLP`` and ``UngatedMLP``,
 ``SparseMoeBlock``, the one sparse block of all five (gated or ungated
-experts, with or without a shared expert), ``LMHead`` and the loop that
+experts, with or without a shared expert), ``LMHead``,
+``TiedEmbedding`` (an embedding whose table is the head) and the loop that
 stacks a list of layers into a compiled model (``decoder_lm``);
 ``lm_mixers`` has the attention layers ``GatedAttention``,
 ``LatentAttention`` and ``BandedAttention`` with their one path into the
@@ -45,12 +47,13 @@ from elephas_tpu.models.deepseek_v3 import deepseek_v3_lm
 from elephas_tpu.models.smallthinker import smallthinker_lm
 from elephas_tpu.models.nemotron_h import nemotron_h_lm
 from elephas_tpu.models.laguna import laguna_lm
+from elephas_tpu.models.granite_hybrid import granite_hybrid_lm
 
 # the sparse LMs' layer classes, and the loss they are compiled with, by
 # the module that defines them
 LM_NAMES = {
     "lm_blocks": ("ZeroCentredRMSNorm", "RMSNorm", "SwiGLU", "DenseMLP",
-                  "UngatedMLP", "SparseMoeBlock", "LMHead",
+                  "UngatedMLP", "SparseMoeBlock", "LMHead", "TiedEmbedding",
                   "next_token_loss"),
     "lm_mixers": ("BandedAttention", "GatedAttention", "LatentAttention",
                   "GatedDeltaNet", "Mamba2Mixer"),
@@ -72,6 +75,7 @@ __all__ = [
     "smallthinker_lm",
     "nemotron_h_lm",
     "laguna_lm",
+    "granite_hybrid_lm",
     "MoeFFN",
     "FlashMHA",
     "FusedLayerNorm",

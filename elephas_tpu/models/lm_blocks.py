@@ -1,8 +1,8 @@
 """What every sparse LM of the zoo is made of beside its sequence mixer,
 and the one loop that stacks them into a model.
 
-The five model files (``qwen3_next``, ``deepseek_v3``, ``smallthinker``,
-``nemotron_h``, ``laguna``) each turn a published config's argument
+The model files (``qwen3_next``, ``deepseek_v3``, ``smallthinker``,
+``nemotron_h``, ``laguna``, ``granite_hybrid``) each turn a published config's argument
 names into a list of layers from this module and from
 :mod:`elephas_tpu.models.lm_mixers`, and hand it to :func:`decoder_lm`;
 none of them defines a layer, and none imports another. This module
@@ -29,12 +29,15 @@ loads neither keras nor jax.
   ``route_counts`` variable (:data:`COUNTER_NAMES`) that the epoch
   runner reads with the loss.
 - :class:`LMHead` and :func:`next_token_loss`: the untied float32 head
-  and the per-token cross-entropy, both under ``lm.head_loss``.
+  and the per-token cross-entropy, both under ``lm.head_loss``;
+  :class:`TiedEmbedding`: an embedding whose table is the head too.
 - :func:`decoder_lm`: embedding, ``x = x + layer(norm(x))`` for every
   :class:`SubLayer` of every decoder layer, final norm, head, compiled
-  with SGD. A new architecture is a new model file that lists its
-  layers; where it needs a layer the zoo lacks, the layer goes here or
-  into ``lm_mixers``, under its own name.
+  with SGD; a family's constant multipliers on the embedding, the
+  sub-layers' results and the logits where the builder names them. A
+  new architecture is a new model file that lists its layers; where it
+  needs a layer the zoo lacks, the layer goes here or into
+  ``lm_mixers``, under its own name.
 """
 
 from __future__ import annotations
@@ -408,6 +411,45 @@ class LMHead(keras.layers.Layer):
                 "init_std": self.init_std}
 
 
+@register
+class TiedEmbedding(keras.layers.Embedding):
+    """A token embedding whose table is the output projection too
+    (``tie_word_embeddings``): ``layer(ids)`` looks rows up, in the
+    compute dtype; ``layer(x, reverse=True)`` is the float32 head,
+    ``x E^T`` (over ``logits_scaling`` where given) under
+    ``lm.head_loss``, as :class:`LMHead` multiplies. One float32
+    variable, ``<name>/embeddings``, with both paths' gradients and
+    one momentum; the model has no head of its own. Built without
+    autocast, so that the head reads the table as it is stored."""
+
+    def __init__(self, input_dim: int, output_dim: int,
+                 logits_scaling: float | None = None, **kwargs):
+        kwargs["autocast"] = False
+        super().__init__(input_dim, output_dim, **kwargs)
+        self.logits_scaling = logits_scaling
+
+    def compute_output_spec(self, x, reverse=False):
+        if reverse:
+            return keras.KerasTensor(
+                x.shape[:-1] + (self.input_dim,), dtype="float32")
+        return keras.KerasTensor(
+            x.shape + (self.output_dim,), dtype=self.compute_dtype)
+
+    def call(self, x, reverse=False):
+        table = self.embeddings.value
+        if not reverse:
+            return jnp.take(table, x, axis=0).astype(self.compute_dtype)
+        with jax.named_scope("lm.head_loss"):
+            logits = jnp.matmul(x.astype(f32), table.T)
+            if self.logits_scaling is not None:
+                logits = logits / self.logits_scaling
+            return logits
+
+    def get_config(self):
+        return {**super().get_config(),
+                "logits_scaling": self.logits_scaling}
+
+
 class SubLayer(NamedTuple):
     """One ``x = x + layer(norm(x))`` of a decoder layer."""
 
@@ -420,7 +462,11 @@ class SubLayer(NamedTuple):
 
 def decoder_lm(name: str, layers, norm: Callable, *, vocab_size: int,
                maxlen: int, hidden_size: int, init_std: float, lr: float,
-               momentum: float, seed: int, dtype_policy: str | None):
+               momentum: float, seed: int, dtype_policy: str | None,
+               embedding_multiplier: float | None = None,
+               residual_multiplier: float | None = None,
+               logits_scaling: float | None = None,
+               tie_word_embeddings: bool = False):
     """The compiled decoder-only LM ``name``: token embedding; for
     decoder layer ``i`` each :class:`SubLayer` of ``layers[i]`` in turn as
     ``x = x + layer(norm(x))``; ``final_norm``; an untied float32 head.
@@ -428,22 +474,43 @@ def decoder_lm(name: str, layers, norm: Callable, *, vocab_size: int,
     turn in the stack, under ``dtype_policy`` and after the seed is set,
     so a model's variables and their seeded initial values do not depend
     on how its builder arrived at the list. Compiled with SGD (``lr``,
-    ``momentum``) and :func:`next_token_loss`."""
+    ``momentum``) and :func:`next_token_loss`.
+
+    A family's constants, each left out of the program where None: the
+    embedding times ``embedding_multiplier``, ``x = x +
+    residual_multiplier * layer(norm(x))`` and the logits over
+    ``logits_scaling``. With ``tie_word_embeddings`` the head is the
+    embedding's table (:class:`TiedEmbedding`) and the model has no
+    ``lm_head``; only that head divides its logits, so
+    ``logits_scaling`` without it is refused."""
+    if logits_scaling is not None and not tie_word_embeddings:
+        raise ValueError(
+            "logits_scaling is the tied head's (tie_word_embeddings): "
+            "the untied LMHead divides nothing")
     keras.utils.set_random_seed(seed)
     with _dtype_policy_scope(keras, dtype_policy):
         inputs = keras.Input((maxlen,), dtype="int32")
-        x = keras.layers.Embedding(
-            vocab_size, hidden_size, name="embed_tokens",
-            embeddings_initializer=normal(init_std),
-        )(inputs)
+        named = dict(name="embed_tokens",
+                     embeddings_initializer=normal(init_std))
+        embed = TiedEmbedding(
+            vocab_size, hidden_size, logits_scaling, **named
+        ) if tie_word_embeddings else keras.layers.Embedding(
+            vocab_size, hidden_size, **named)
+        x = embed(inputs)
+        if embedding_multiplier is not None:
+            x = x * embedding_multiplier
         for i, sub_layers in enumerate(layers):
             stream = x
             for sub in sub_layers:
                 h = norm(name=f"layer{i}_{sub.norm}")(x)
                 layer = sub.make()
-                x = x + (layer(h, stream) if sub.takes_stream else layer(h))
+                y = layer(h, stream) if sub.takes_stream else layer(h)
+                if residual_multiplier is not None:
+                    y = y * residual_multiplier
+                x = x + y
         x = norm(name="final_norm")(x)
-        outputs = LMHead(vocab_size, init_std, name="lm_head")(x)
+        outputs = embed(x, reverse=True) if tie_word_embeddings else LMHead(
+            vocab_size, init_std, name="lm_head")(x)
         model = keras.Model(inputs, outputs, name=name)
     model.compile(
         optimizer=keras.optimizers.SGD(lr, momentum=momentum),

@@ -16,8 +16,9 @@ their ``*_lm`` functions.
   attention), ``rotary`` (the rotary embedding, pairs ``(i, i +
   rotary_dim / 2)``, over ``rotary_dim`` of each head, with ``yarn``
   scaled frequencies; without it the layer has no position term at
-  all) and ``gating`` (a sigmoid output gate, one scalar a head and
-  token). The flash kernels' grids hold the band's block pairs alone,
+  all), ``gating`` (a sigmoid output gate, one scalar a head and
+  token) and ``scale`` (the scores' multiplier; None is ``head_dim **
+  -0.5``). The flash kernels' grids hold the band's block pairs alone,
   under the scope ``attn.window``; a full layer runs under
   ``attn.full``.
 - :class:`GatedAttention`: grouped-query causal attention with per-head
@@ -120,7 +121,8 @@ class BandedAttention(Remat):
                  rope_theta: float = 10000.0, init_std: float = 0.02,
                  gating: str | None = None,
                  rotary_dim: int | None = None,
-                 yarn: dict | None = None, **kwargs):
+                 yarn: dict | None = None, scale: float | None = None,
+                 **kwargs):
         super().__init__(**kwargs)
         rotary_dim = head_dim if rotary_dim is None else rotary_dim
         if num_heads % num_kv_heads or rotary_dim % 2 or not (
@@ -143,6 +145,7 @@ class BandedAttention(Remat):
         self.init_std, self.gating = init_std, gating
         self.rotary_dim = rotary_dim
         self.yarn = None if yarn is None else dict(yarn)
+        self.scale = scale
 
     def build(self, input_shape):
         d, hd = int(input_shape[-1]), self.head_dim
@@ -184,7 +187,9 @@ class BandedAttention(Remat):
         with jax.named_scope(
                 "attn.full" if self.window is None else "attn.window"):
             out = causal_flash_attention(
-                self, q, k, v, hd ** -0.5, self.window)
+                self, q, k, v,
+                hd ** -0.5 if self.scale is None else self.scale,
+                self.window)
             if not self.gating:
                 out = out.reshape(b, s, h * hd)
         if self.gating:
@@ -202,7 +207,7 @@ class BandedAttention(Remat):
                 "rotary": self.rotary, "rope_theta": self.rope_theta,
                 "init_std": self.init_std, "gating": self.gating,
                 "rotary_dim": self.rotary_dim, "yarn": self.yarn,
-                "remat": self.remat}
+                "scale": self.scale, "remat": self.remat}
 
 
 @register
@@ -572,6 +577,13 @@ class Mamba2Mixer(Remat):
             mixed = jax.nn.silu(mixed).astype(x.dtype)
             u, b_in, c_in = jnp.split(
                 mixed, (inner, inner + g * n), axis=-1)
+        chunks = -(-s // self.chunk_size)
+        # once a trace: the chunk the program runs, and the bytes of one
+        # float32 [B, H, S / Q, Q, Q] factor of the masked product
+        telemetry.emit(
+            "ssd.chunks", layer=self.name, chunk=self.chunk_size,
+            chunks=chunks, heads=h, groups=g,
+            bytes=4 * b * h * chunks * self.chunk_size ** 2)
         with jax.named_scope("ssm.scan"):
             step = jax.nn.softplus(
                 dt.astype(f32) + self.dt_bias.value.astype(f32))

@@ -4,7 +4,7 @@ machine that has none: what the TPU compiler refuses and what the
 program's temporaries take, before any chip time is spent.
 
     JAX_PLATFORMS=cpu python3 scripts/epoch_program_offchip.py \
-        --workload laguna-fit-seq8k [--out <file.json>]
+        --workload laguna-fit-seq8k [--set KEY JSON] [--out <file.json>]
 
 Builds the cell's model as its builder does (weights are the Keras
 initialiser's: nothing runs), hands ``MeshRunner`` a mesh of one
@@ -50,6 +50,10 @@ def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--workload", required=True)
     parser.add_argument("--out")
+    parser.add_argument("--set", nargs=2, action="append", default=[],
+                        metavar=("KEY", "JSON"),
+                        help="a (dotted) key of the configuration, set "
+                             "for this compile alone")
     args = parser.parse_args()
 
     import jax
@@ -68,7 +72,11 @@ def main() -> int:
     jax.config.update("jax_traceback_in_locations_limit", 0)
     manifest = mf.load_manifest()
     cell = mf.find_cell(manifest, args.workload)
-    cfg = mf.config_of(manifest, cell)
+    # scripts/ is this process's sys.path[0]
+    from prove_reference_faults import with_keys
+
+    cfg = with_keys(mf.config_of(manifest, cell), [
+        (key, json.loads(value)) for key, value in args.set])
     traffic = mf.load_json("traffic", cell["traffic"])
     builder = mf.load_module("builders", cfg["builder"])
     t0 = time.monotonic()
@@ -101,6 +109,7 @@ def main() -> int:
     kernels = collections.Counter(re.findall(r'kernel_name = "(\w+)"', text))
     result = {
         "cell": args.workload,
+        "set": args.set,
         "parameters": int(sum(np.prod(v.shape) for v in model.variables)),
         "temp_size_in_bytes": int(memory.temp_size_in_bytes),
         "argument_size_in_bytes": int(memory.argument_size_in_bytes),

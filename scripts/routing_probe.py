@@ -1,5 +1,6 @@
 #!/usr/bin/env python3
-"""What a sparse LM cell's routers do inside a window, by learning rate.
+"""What an LM cell's loss, rate and (where it is sparse) routers do inside
+a window, by learning rate.
 
     chiprun --timeout 1800 -- python3 scripts/routing_probe.py \
         --workload nemotron3nano-fit-seq8k --seeds 2 --epochs 14 \
@@ -37,7 +38,9 @@ def probe(args, prove_py) -> dict:
     driver = mf.load_module("drivers", ctx.traffic["kind"])
     job = driver.prepare(ctx)
     batch = int(ctx.traffic["batch_size"])
-    held = ctx.config["num_experts_held"]
+    # a dense cell has neither experts nor counters: its probe is of the
+    # loss and the rate alone
+    held = ctx.config.get("num_experts_held", 0)
     tracer = telemetry.default_tracer()
     runs = []
     for i, seed in enumerate(prove_py.seeds_of(args)):

@@ -2,13 +2,17 @@
 ``deepseek_v3_lm``, ``smallthinker_lm``, ``nemotron_h_lm`` and
 ``laguna_lm`` trace to, which variables they hold and which named scopes
 their programs carry, held to what they were at ``f37516c`` (PR 46), the
-last tree whose layer classes lived inside the model files' functions.
+last tree whose layer classes lived inside the model files' functions;
+and ``granite_hybrid_lm``, held to what it was in the tree that added it
+(``tests/lm_blocks_at_pr48.json``, PR 48).
 
 Each model is built as its benchmark builder builds it, at its own test
 file's small configuration with ``remat`` on and ``mixed_bfloat16``.
 ``tests/lm_blocks_at_f37516c.json`` is the record (``python
-tests/test_lm_blocks.py <file>`` writes one from the tree it runs in:
-made once, at that commit, under jax 0.9.0): a change that moves a
+tests/test_lm_blocks.py <file> [model ...]`` writes one from the tree it
+runs in, of the models named or of all: made once, at that commit, under
+jax 0.9.0; a later model's entry lies in a file of its own beside it, so
+that the first stays byte for byte): a change that moves a
 model's layers about, or the code they share, leaves every entry as it
 is; one that means to change a program says which entry moved and why.
 The benchmark reads its per-layer times by the scopes' names and its
@@ -30,7 +34,8 @@ import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-RECORD = os.path.join(ROOT, "tests", "lm_blocks_at_f37516c.json")
+RECORDS = tuple(os.path.join(ROOT, "tests", name) for name in (
+    "lm_blocks_at_f37516c.json", "lm_blocks_at_pr48.json"))
 # model -> (its test file, whose CFG is the small configuration, its
 # benchmark builder, and the entries of the configuration that the
 # builder's ``_build`` takes after the configuration and its optimizer)
@@ -41,6 +46,7 @@ MODELS = {
     "smallthinker": ("test_smallthinker", "keras_smallthinker", ()),
     "nemotron_h": ("test_nemotron_h", "keras_nemotron_h", ()),
     "laguna": ("test_laguna", "keras_laguna", ()),
+    "granite_hybrid": ("test_granite_hybrid", "keras_granite_hybrid", ()),
 }
 # a name the program gave with ``jax.named_scope``, as the benchmark's
 # trace reader takes them (``benchmarks/harness/xplane_ops.py``)
@@ -132,8 +138,17 @@ def observe(name):
 
 @functools.lru_cache(maxsize=None)
 def _recorded():
-    with open(RECORD) as f:
-        return json.load(f)
+    """The records as one: every file's models under the first's jax."""
+    merged = None
+    for path in RECORDS:
+        with open(path) as f:
+            record = json.load(f)
+        if merged is None:
+            merged = record
+        else:
+            assert record["jax"] == merged["jax"], path
+            merged["models"].update(record["models"])
+    return merged
 
 
 @pytest.mark.parametrize("model", sorted(MODELS))
@@ -191,6 +206,7 @@ def test_the_recorded_scopes_are_the_ones_the_benchmark_reads():
                 f.read()))
     assert len(read) >= 10, read
     assert read <= held, read - held
+    assert {"ssm.conv", "ssm.norm", "mlp.dense"} <= read
     assert models.SparseMoeBlock.epoch_counters == {
         "route_counts": ("held_slots", "slots", "max_expert_tokens", "calls",
                          "blocked_calls")}
@@ -235,14 +251,15 @@ def test_attention_classes_share_one_path_to_the_kernel(monkeypatch):
 
 
 def test_importing_the_zoo_imports_no_keras():
-    """``import elephas_tpu.models`` and each of the five model modules
+    """``import elephas_tpu.models`` and each of the six model modules
     in a fresh interpreter import neither keras nor jax: the benchmark's
     builders and metric readers import them as they are loaded."""
     code = (
         "import sys\n"
         "import elephas_tpu.models\n"
         "from elephas_tpu.models import (\n"
-        "    deepseek_v3, laguna, nemotron_h, qwen3_next, smallthinker)\n"
+        "    deepseek_v3, granite_hybrid, laguna, nemotron_h, qwen3_next,\n"
+        "    smallthinker)\n"
         "print('keras' not in sys.modules and 'jax' not in sys.modules)\n"
     )
     out = subprocess.run(
@@ -264,6 +281,7 @@ if __name__ == "__main__":
             ["git", "rev-parse", "--short", "HEAD"], cwd=ROOT,
             capture_output=True, text=True).stdout.strip(),
         "jax": jax.__version__,
-        "models": {name: observe(name) for name in sorted(MODELS)}}, indent=1)
+        "models": {name: observe(name)
+                   for name in sorted(sys.argv[2:] or MODELS)}}, indent=1)
     with open(sys.argv[1], "w") as f:  # a list a line
         f.write(re.sub(r"\n {4,}|\n {3}(?=\])", " ", record) + "\n")
